@@ -3,7 +3,7 @@
 VERDICT r3 item 7 asked for the stable checkpoint format to be "timed at
 XL": the S-scale numbers (1.45 GB: save 11.8 s / load 10.0 s vs 26.8 s
 pickle) say nothing about how the format behaves at the 13 GB-HBM XL
-scale (dv3_xl_step_r3.json), where a whole-state pickle is the difference
+scale (round-3 chip step timing), where a whole-state pickle is the difference
 between a tolerable and an unusable checkpoint cadence.
 
 Builds the REAL XL agent (algo=dreamer_v3_XL shapes, reference

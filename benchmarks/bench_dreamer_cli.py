@@ -3,7 +3,7 @@ protocol (reference benchmarks/benchmark.py + configs/exp/dreamer_v*_benchmarks.
 tiny model, 16384 total steps, replay_ratio 0.0625, 1 env, checkpoints on).
 
 The reference protocol runs Atari MsPacman; this image has no ale_py
-(zero egress — see ROUND4_NOTES item 2), so the runs substitute
+(no network egress), so the runs substitute
 ``env=dummy`` with identical 64x64x3 pixel shapes. Disclosure: a dummy
 step is cheaper than an ALE step, which flatters the env-interaction
 share of the wall-clock — but at replay_ratio 0.0625 with the tiny model

@@ -172,7 +172,6 @@ def main(argv=None) -> int:
     ap.add_argument("--requests", type=int, default=256)
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     result = run_grid(n_requests=args.requests)
     text = json.dumps(result, indent=2)
     print(text)

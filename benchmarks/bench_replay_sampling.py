@@ -1,24 +1,17 @@
-"""Replay-sampling ladder: uniform vs prioritized draws, lax vs pallas.
+"""Replay-sampling ladder: uniform vs prioritized draws.
 
 Times the per-batch cost of the on-device samplers at several cache
 sizes (1e4 → 1e6 transitions) so the sum-tree's O(log n) descent can be
-compared against the O(1) uniform gather it rides next to — and, since
-ISSUE 14, the ``buffer.per_kernel=lax`` gather-chain path against the
-fused ``pallas`` kernels (ops/pallas_per.py + ops/pallas_gather.py,
-interpret mode on non-TPU backends).  Also times the write-side costs
-prioritization adds (max-priority seeding per append, TD-driven
-``update_priorities``) per kernel, and the params-broadcast digest cost
-ladder (host ``content_digest`` vs the one-dispatch device
+compared against the O(1) uniform gather it rides next to.  Also times
+the write-side costs prioritization adds (max-priority seeding per
+append, TD-driven ``update_priorities``), and the params-broadcast digest
+cost ladder (host ``content_digest`` vs the one-dispatch device
 ``stream_digest_batched`` — ISSUE 14 tentpole c).
 
 Each mode runs ``repeats`` rounds INTERLEAVED and the minimum feeds the
 ratios (the PR-10 pattern: single runs swing 20-30% on a shared host).
-Numbers are wall-clock per dispatched op with ``block_until_ready`` —
-on the CPU backend of a 1-core container they are upper bounds; the
-pallas numbers additionally run the kernels in INTERPRET mode (traced
-jax ops), so the pallas-vs-lax delta here measures the algorithmic
-difference (fused exclusion descent = no functional tree copy), not
-Mosaic codegen.
+Numbers are wall-clock per dispatched op with ``block_until_ready``, on
+whatever device JAX finds: they name no device and are no device metric.
 
     python benchmarks/bench_replay_sampling.py [--out results/replay_sampling.json]
 """
@@ -48,12 +41,10 @@ def _bench(fn, n_iters: int, warmup: int = 3) -> float:
     return (time.perf_counter() - t0) / n_iters
 
 
-def _make_cache(cap, n_envs, feat, prioritized, kernel):
+def _make_cache(cap, n_envs, feat, prioritized):
     from sheeprl_tpu.data.device_buffer import DeviceReplayCache
 
-    cache = DeviceReplayCache(
-        cap, n_envs, prioritized=prioritized, per_alpha=0.6, kernel=kernel
-    )
+    cache = DeviceReplayCache(cap, n_envs, prioritized=prioritized, per_alpha=0.6)
     rng = np.random.default_rng(0)
     block = 4096
     t = 0
@@ -79,43 +70,38 @@ def run_ladder(sizes=(10_000, 100_000, 1_000_000), batch=256, n_iters=20, feat=8
     for cap in sizes:
         n_envs = 1
         caches = {
-            "uniform": _make_cache(cap, n_envs, feat, False, "lax"),
-            "lax": _make_cache(cap, n_envs, feat, True, "lax"),
-            "pallas": _make_cache(cap, n_envs, feat, True, "pallas"),
+            "uniform": _make_cache(cap, n_envs, feat, False),
+            "per": _make_cache(cap, n_envs, feat, True),
         }
         keys = iter(jax.random.split(jax.random.PRNGKey(0), 100_000))
 
         # two draw shapes per mode: the r07-comparable plain draw (no
         # next-obs, no sampling exclusion) and the SAC-shaped draw
-        # (sample_next_obs=True: the lax path pays a FULL functional tree
-        # copy to zero the stale head row; the pallas path folds the
-        # exclusion into the descent — the fused kernels' main win)
+        # (sample_next_obs=True: the prioritized path pays a FULL
+        # functional tree copy to zero the stale head row)
         def uni(nobs):
             kw = dict(sample_next_obs=True, obs_keys=("observations",)) if nobs else {}
             return caches["uniform"].sample_transitions(1, batch, next(keys), **kw)["rewards"]
 
-        def per(kernel, nobs):
+        def per(nobs):
             kw = dict(sample_next_obs=True, obs_keys=("observations",)) if nobs else {}
-            return caches[kernel].sample_transitions_per(1, batch, next(keys), beta=0.4, **kw)[
+            return caches["per"].sample_transitions_per(1, batch, next(keys), beta=0.4, **kw)[
                 0
             ]["rewards"]
 
         idx = np.arange(batch, dtype=np.int32)
         td = np.abs(np.random.default_rng(1).standard_normal(batch)).astype(np.float32)
 
-        def upd(kernel):
-            caches[kernel].update_priorities(idx, td)
-            return caches[kernel]._tree.tree
+        def upd():
+            caches["per"].update_priorities(idx, td)
+            return caches["per"]._tree.tree
 
         modes = {
             "uniform": lambda: uni(False),
-            "lax": lambda: per("lax", False),
-            "pallas": lambda: per("pallas", False),
+            "per": lambda: per(False),
             "uniform_nobs": lambda: uni(True),
-            "lax_nobs": lambda: per("lax", True),
-            "pallas_nobs": lambda: per("pallas", True),
-            "upd_lax": lambda: upd("lax"),
-            "upd_pallas": lambda: upd("pallas"),
+            "per_nobs": lambda: per(True),
+            "upd": upd,
         }
         # interleaved min-of-N over every mode (the PR-10 pattern)
         best = {m: float("inf") for m in modes}
@@ -130,20 +116,14 @@ def run_ladder(sizes=(10_000, 100_000, 1_000_000), batch=256, n_iters=20, feat=8
                 "repeats": repeats,
                 # r07-comparable legs (same shapes bench'd at r07)
                 "uniform_sample_ms": round(best["uniform"] * 1e3, 4),
-                "prioritized_sample_ms": round(best["lax"] * 1e3, 4),
-                "prioritized_pallas_ms": round(best["pallas"] * 1e3, 4),
-                "prioritized_over_uniform": round(best["lax"] / best["uniform"], 3),
-                "pallas_over_uniform": round(best["pallas"] / best["uniform"], 3),
+                "prioritized_sample_ms": round(best["per"] * 1e3, 4),
+                "prioritized_over_uniform": round(best["per"] / best["uniform"], 3),
                 # SAC-shaped legs (next-obs gathered; exclusion-bearing)
                 "uniform_nobs_ms": round(best["uniform_nobs"] * 1e3, 4),
-                "prioritized_nobs_ms": round(best["lax_nobs"] * 1e3, 4),
-                "prioritized_nobs_pallas_ms": round(best["pallas_nobs"] * 1e3, 4),
-                "nobs_prioritized_over_uniform": round(best["lax_nobs"] / best["uniform_nobs"], 3),
-                "nobs_pallas_over_uniform": round(best["pallas_nobs"] / best["uniform_nobs"], 3),
-                "nobs_pallas_over_lax": round(best["pallas_nobs"] / best["lax_nobs"], 3),
-                "update_priorities_ms": round(best["upd_lax"] * 1e3, 4),
-                "update_priorities_pallas_ms": round(best["upd_pallas"] * 1e3, 4),
-                "tree_depth": caches["lax"]._tree.depth,
+                "prioritized_nobs_ms": round(best["per_nobs"] * 1e3, 4),
+                "nobs_prioritized_over_uniform": round(best["per_nobs"] / best["uniform_nobs"], 3),
+                "update_priorities_ms": round(best["upd"] * 1e3, 4),
+                "tree_depth": caches["per"]._tree.depth,
             }
         )
         print(json.dumps(rows[-1]), flush=True)
@@ -239,14 +219,11 @@ def main():
     result = {
         "metric": "replay_sampling_ladder",
         "backend": jax.default_backend(),
-        "pallas_interpret": jax.default_backend() != "tpu",
         "rows": rows,
         "digest_rows": digest_rows,
         "notes": (
-            "1-core CPU container: pallas kernels run in INTERPRET mode (traced jax "
-            "ops) — deltas measure the fused-exclusion algorithm (no functional tree "
-            "copy), not Mosaic codegen; digest device numbers split dispatch-only / "
-            "synced / host-staged because jnp staging dominates for host leaves here"
+            "digest device numbers split dispatch-only / synced / host-staged "
+            "because jnp staging dominates for host leaves on a CPU backend"
         ),
     }
     if args.out:

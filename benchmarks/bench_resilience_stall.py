@@ -28,8 +28,6 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 from sheeprl_tpu.cli import run  # noqa: E402
 from sheeprl_tpu.obs import read_records  # noqa: E402
 

@@ -87,9 +87,8 @@ def _obs_space():
 
 def _compiled_flops(runtime, train_fn, args):
     from sheeprl_tpu.obs import compiled_flops
-    from sheeprl_tpu.utils.jax_compat import set_mesh
 
-    with set_mesh(runtime.mesh):
+    with jax.set_mesh(runtime.mesh):
         compiled = train_fn._jitted.lower(*args).compile()
     return compiled_flops(compiled) or 0.0
 

@@ -89,12 +89,22 @@ if __name__ == "__main__":
     ctx = mp.get_context("spawn")
     data_q: mp.Queue = ctx.Queue()
     resp_qs = [ctx.Queue() for _ in range(N_PLAYERS)]
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     procs = [
         ctx.Process(target=player_loop, args=(i, CFG, data_q, resp_qs[i])) for i in range(N_PLAYERS)
     ]
-    for p in procs:
-        p.start()
+    # a chip belongs to one process: the players are pinned to the CPU by
+    # exporting JAX_PLATFORMS around the spawn (a child copies the parent's
+    # environ at start), while the learner keeps whatever JAX finds
+    saved_platform = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        for p in procs:
+            p.start()
+    finally:
+        if saved_platform is None:
+            os.environ.pop("JAX_PLATFORMS", None)
+        else:
+            os.environ["JAX_PLATFORMS"] = saved_platform
     learner_loop(N_PLAYERS, CFG, data_q, resp_qs)
     for p in procs:
         p.join()

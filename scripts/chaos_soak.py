@@ -1239,7 +1239,6 @@ def main(argv=None) -> int:
     ap.add_argument("--keep", action="store_true", help="keep the run dir for inspection")
     args = ap.parse_args(argv)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     if args.mode == "health":
         if args.root_dir == "/tmp/sheeprl_chaos_soak":
             args.root_dir = "/tmp/sheeprl_chaos_health"
@@ -1287,7 +1286,6 @@ def main(argv=None) -> int:
 
     shutil.rmtree(args.root_dir, ignore_errors=True)
     os.environ["SHEEPRL_FAULTS"] = faults
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     from sheeprl_tpu.cli import run
 
     try:

@@ -104,7 +104,9 @@ def build_server(
             f"serve_policy supports the PPO/SAC/recurrent-PPO/Dreamer-v3 families, got algo={algo!r}"
         )
 
-    runtime = MeshRuntime(devices=1, accelerator="cpu", precision=cfg.fabric.get("precision", "32-true"))
+    # serves on what JAX finds (the chip when there is one); launch() also
+    # places the persistent compile cache
+    runtime = MeshRuntime(devices=1, precision=cfg.fabric.get("precision", "32-true"))
     runtime.launch()
     cfg.env.capture_video = False
     env = make_env(cfg, int(cfg.get("seed", 0)), 0, None, "serve", vector_env_idx=0)()
@@ -281,7 +283,6 @@ def main(argv=None) -> int:
     ap.add_argument("--selftest-requests", type=int, default=64)
     args = ap.parse_args(argv)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     server, loader, obs_keys, obs_space = build_server(
         args.checkpoint,
         greedy=not args.sample,

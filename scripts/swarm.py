@@ -217,7 +217,7 @@ def run_checkpoint_swarm(args):
     warmup_buckets(
         server._session_policy_fn,
         server._init_state_fn,
-        server.params,
+        server._params,
         lambda r: {k: np.zeros((r,) + tuple(obs_space[k].shape), np.float32) for k in obs_keys},
         args.max_batch,
     )
@@ -264,7 +264,6 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=None, help="also write the report JSON here")
     args = ap.parse_args(argv)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     if args.checkpoint:
         report, stats = run_checkpoint_swarm(args)
     else:

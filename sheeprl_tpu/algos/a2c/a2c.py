@@ -42,7 +42,7 @@ from sheeprl_tpu.utils.utils import (
     save_configs,
 )
 from sheeprl_tpu.optim import restore_opt_states
-from sheeprl_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def make_update_fn(runtime, module, tx, cfg: Dict[str, Any], obs_keys: Sequence[str]):
@@ -203,7 +203,7 @@ def main(runtime, cfg: Dict[str, Any]):
         module,
         params,
         lambda obs: prepare_obs(obs, num_envs=total_envs),
-        device=runtime.player_device(params),
+        device=runtime.player_device(),
     )
 
     if runtime.is_global_zero:

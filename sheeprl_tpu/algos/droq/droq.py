@@ -165,7 +165,7 @@ def make_train_fn(
             from jax.sharding import PartitionSpec as SMP
 
             from sheeprl_tpu.parallel.sharding import BATCH_AXES
-            from sheeprl_tpu.utils.jax_compat import shard_map
+            from jax import shard_map
 
             critic_specs = jax.tree_util.tree_map(lambda _: SMP(None, BATCH_AXES), critic_data)
             actor_specs = jax.tree_util.tree_map(lambda _: SMP(BATCH_AXES), actor_data)
@@ -247,7 +247,7 @@ def main(runtime, cfg: Dict[str, Any]):
         actor,
         params["actor"],
         lambda obs: prepare_obs(obs, mlp_keys=mlp_keys, num_envs=total_envs),
-        device=runtime.player_device(params["actor"]),
+        device=runtime.player_device(),
     )
 
     if runtime.is_global_zero:

@@ -58,7 +58,7 @@ from sheeprl_tpu.utils.utils import (
     save_configs,
 )
 from sheeprl_tpu.optim import restore_opt_states
-from sheeprl_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def build_ppo_optimizer(
@@ -467,7 +467,7 @@ def main(runtime, cfg: Dict[str, Any]):
     def _prep(obs):
         return prepare_obs(obs, cnn_keys=cnn_keys, num_envs=total_envs)
 
-    player = PPOPlayer(module, params, _prep, device=runtime.player_device(params))
+    player = PPOPlayer(module, params, _prep, device=runtime.player_device())
 
     if runtime.is_global_zero:
         save_configs(cfg, log_dir)

@@ -58,7 +58,7 @@ from sheeprl_tpu.utils.utils import (
     start_async_host_copy,
 )
 from sheeprl_tpu.optim import restore_opt_states
-from sheeprl_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def make_update_fn(runtime, module, tx, cfg: Dict[str, Any], obs_keys: Sequence[str]):
@@ -390,7 +390,7 @@ def main(runtime, cfg: Dict[str, Any]):
     def _prep(obs):
         return prepare_obs(obs, cnn_keys=cnn_keys, num_envs=total_envs)
 
-    player = RecurrentPPOPlayer(module, params, _prep, num_envs=total_envs, device=runtime.player_device(params))
+    player = RecurrentPPOPlayer(module, params, _prep, num_envs=total_envs, device=runtime.player_device())
 
     if runtime.is_global_zero:
         save_configs(cfg, log_dir)

@@ -211,7 +211,7 @@ def make_train_fn(
             from jax.sharding import PartitionSpec as SMP
 
             from sheeprl_tpu.parallel.sharding import BATCH_AXES
-            from sheeprl_tpu.utils.jax_compat import shard_map
+            from jax import shard_map
 
             data_specs = jax.tree_util.tree_map(lambda _: SMP(None, BATCH_AXES), data)
             td_spec = (SMP(None, BATCH_AXES),) if prioritized else ()
@@ -305,7 +305,7 @@ def main(runtime, cfg: Dict[str, Any]):
         actor,
         params["actor"],
         lambda obs: prepare_obs(obs, mlp_keys=mlp_keys, num_envs=total_envs),
-        device=runtime.player_device(params["actor"]),
+        device=runtime.player_device(),
     )
 
     if runtime.is_global_zero:

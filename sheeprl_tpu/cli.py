@@ -178,15 +178,12 @@ def _build_runtime(cfg: dotdict):
 
     fabric_cfg = dict(cfg.fabric)
     if fabric_cfg.get("accelerator") == "cpu":
-        # force the host platform even when the machine env pins
-        # JAX_PLATFORMS to an accelerator (works while no backend is
-        # initialized yet, same trick as tests/conftest.py)
+        # keep the accelerator backends uninitialized in a CPU run (takes
+        # effect while no backend is initialized yet; afterwards the mesh
+        # still asks for jax.devices("cpu") explicitly)
         import jax
 
-        try:
-            jax.config.update("jax_platforms", "cpu")
-        except Exception:
-            pass
+        jax.config.update("jax_platforms", "cpu")
     runtime = instantiate(fabric_cfg)
     runtime.launch()
     return runtime

@@ -224,10 +224,14 @@ class Observability:
             self.sink.write(record)
         if self._logger is not None:
             self._mirror_to_logger(record, policy_step)
-        # retraces of the jitted steps are only suspicious once training has
-        # actually dispatched (SAC-style learning_starts delays the first
-        # train compile well past the first log boundary)
-        if self.recompile and not self.recompile.warmed_up and train_step > 0:
+        # retraces are only suspicious once every jitted step has been
+        # built.  The first training iteration compiles the update; the
+        # next ones still compile first uses (the player's policy step
+        # after learning_starts, the steady-size replay feed, the
+        # episode-end ring write — 11 such compiles on the first DV3-S chip
+        # run).  So warm-up ends with the first log interval that BEGAN
+        # with training already dispatched.
+        if self.recompile and not self.recompile.warmed_up and self._last_train > 0:
             self.recompile.mark_warmup_complete()
         self._last_step = policy_step
         self._last_train = train_step

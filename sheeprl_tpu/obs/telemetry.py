@@ -186,7 +186,7 @@ _HBM_KEYS = ("bytes_in_use", "peak_bytes_in_use", "bytes_limit", "largest_free_b
 
 def device_memory_stats(device: Any = None) -> Optional[Dict[str, int]]:
     """HBM usage of the training device via PJRT ``memory_stats()``; None
-    on backends that do not report (CPU, some tunnels)."""
+    on backends that do not report (CPU)."""
     if device is None:
         import jax
 
@@ -198,8 +198,8 @@ def device_memory_stats(device: Any = None) -> Optional[Dict[str, int]]:
         stats = device.memory_stats()
     except Exception:
         return None
-    # CPU backends (and some tunnels) return None or {} — and a plugin
-    # may report a key with a None VALUE; the record must carry the key
+    # CPU backends return None or {} — and a backend may report a key
+    # with a None VALUE; the record must carry the key
     # as ABSENT, never as a null a downstream consumer trips over
     if not stats:
         return None

@@ -25,14 +25,10 @@ plugin layout; view with ``tensorboard --logdir <profile_dir>``.
 from __future__ import annotations
 
 import os
-import warnings
-from contextlib import ExitStack, contextmanager, nullcontext
+from contextlib import ExitStack, contextmanager
 from typing import Optional
 
-try:  # profiler is part of core jax, but keep obs importable without it
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except Exception:  # pragma: no cover - only hit on broken jax installs
-    _TraceAnnotation = None
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 
 def trace_scope(name: str):
@@ -49,8 +45,6 @@ def trace_scope(name: str):
     whole cost, exactly as before."""
     if os.environ.get("SHEEPRL_SANITIZE", "").strip().lower() in ("1", "true", "yes", "on"):
         return _sanitized_scope(name)
-    if _TraceAnnotation is None:
-        return nullcontext()
     return _TraceAnnotation(name)
 
 
@@ -59,8 +53,7 @@ def _sanitized_scope(name: str):
     from sheeprl_tpu.analysis.sanitizers import transfer_sanitizer
 
     with ExitStack() as stack:
-        if _TraceAnnotation is not None:
-            stack.enter_context(_TraceAnnotation(name))
+        stack.enter_context(_TraceAnnotation(name))
         stack.enter_context(transfer_sanitizer(name))
         yield
 
@@ -71,20 +64,16 @@ _ACTIVE_TRACE_DIR: Optional[str] = None
 def start_trace(trace_dir: str) -> bool:
     """Start a jax.profiler trace into ``trace_dir`` (created if missing).
 
-    Returns False (and warns) instead of raising when a trace is already
-    active or the profiler refuses to start — observability must never
-    kill a training run."""
+    Returns False when a trace is already active (windows cannot nest).
+    A window that was asked for and cannot start is an error: the
+    profiler's exception propagates."""
     global _ACTIVE_TRACE_DIR
     if _ACTIVE_TRACE_DIR is not None:
         return False
     import jax
 
-    try:
-        os.makedirs(trace_dir, exist_ok=True)
-        jax.profiler.start_trace(trace_dir)
-    except Exception as e:
-        warnings.warn(f"could not start profiler trace in {trace_dir}: {e}")
-        return False
+    os.makedirs(trace_dir, exist_ok=True)
+    jax.profiler.start_trace(trace_dir)
     _ACTIVE_TRACE_DIR = trace_dir
     return True
 
@@ -97,11 +86,7 @@ def stop_trace() -> Optional[str]:
     import jax
 
     out, _ACTIVE_TRACE_DIR = _ACTIVE_TRACE_DIR, None
-    try:
-        jax.profiler.stop_trace()
-    except Exception as e:
-        warnings.warn(f"could not stop profiler trace: {e}")
-        return None
+    jax.profiler.stop_trace()
     return out
 
 

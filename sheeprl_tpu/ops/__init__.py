@@ -4,13 +4,3 @@ from sheeprl_tpu.ops.ring_attention import (  # noqa: F401
     ring_attention,
 )
 from sheeprl_tpu.ops.pallas_gru import fused_gru_cell, reference_gru_cell  # noqa: F401
-from sheeprl_tpu.ops.pallas_per import (  # noqa: F401
-    sum_tree_descend,
-    sum_tree_sample,
-    sum_tree_update,
-    sum_tree_write,
-)
-from sheeprl_tpu.ops.pallas_gather import (  # noqa: F401
-    gather_transitions_fused,
-    gather_windows_fused,
-)

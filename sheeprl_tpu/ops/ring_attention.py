@@ -24,7 +24,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from sheeprl_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def _block_scores(q: jax.Array, k: jax.Array) -> jax.Array:
@@ -67,9 +67,7 @@ def _mark_varying(tree, axis_name: str):
     varying-axes typing while the scan body's outputs (mixed with sharded
     inputs) are device-varying — mark the carry varying up front so the
     scan types close."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(tree, axis_name, to="varying")
-    return jax.lax.pvary(tree, axis_name)  # older jax
+    return jax.lax.pcast(tree, axis_name, to="varying")
 
 
 def _hop_block_mask(src, j, block: int, s_local: int, q_pos, scores_shape, causal: bool):

@@ -4,7 +4,8 @@ The per-step fused cell (``ops/pallas_gru.py``) removes the elementwise HBM
 round trips inside one GRU step, but a ``lax.scan`` over it still pays, per
 time step, a kernel launch plus a re-read of the (H+X, 3H) weight matrix.
 For the latency-bound RSSM train scans that launch/stream overhead is most
-of the remaining while-loop time (benchmarks/results/dv3_profile_r4.json).
+of the remaining while-loop time (round-4 chip profile of the DV3-S step:
+the three while loops were 17 % of 13.9 ms of device time).
 
 This op runs the WHOLE T-step recurrence inside one ``pallas_call``:
 
@@ -51,7 +52,14 @@ __all__ = ["gru_sequence", "gru_sequence_reference", "fits_vmem"]
 
 
 def fits_vmem(hidden: int, in_dim: int, matmul_dtype=jnp.float32, budget_mb: float = 10.0) -> bool:
-    """Can the (H+X, 3H) weight matrix stay VMEM-resident (plus working set)?"""
+    """Can the (H+X, 3H) weight matrix stay VMEM-resident (plus working set)?
+
+    The 10 MB bound is conservative for a v5e (128 MiB of VMEM): asked for a
+    described v5e, Mosaic took everything under it (9.8 MB f32 at H=X=640,
+    9.4 MB bf16 at H=1024/X=512) and gave mixed answers above it (11.8 MB
+    f32 compiled, 20.4 MB f32 ran out of VMEM, 34.6 MB bf16 compiled, XL's
+    126 MB did not).  tests/test_ops/test_tpu_compile.py holds the bound to
+    what compiles."""
     itemsize = jnp.dtype(matmul_dtype).itemsize
     return (hidden + in_dim) * 3 * hidden * itemsize <= budget_mb * 2**20
 

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from sheeprl_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def make_sequence_parallel_train_step(

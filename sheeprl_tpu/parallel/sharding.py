@@ -192,13 +192,11 @@ class ShardingLayout:
         accident of propagation).  Only call inside jit."""
         import jax
 
-        from sheeprl_tpu.utils.jax_compat import with_sharding_constraint
-
         def leaf_constraint(x):
             if not hasattr(x, "shape"):
                 return x
             s = self.param_sharding(x) if fsdp else self.replicated
-            return with_sharding_constraint(x, s)
+            return jax.lax.with_sharding_constraint(x, s)
 
         return jax.tree_util.tree_map(leaf_constraint, tree)
 
@@ -206,23 +204,19 @@ class ShardingLayout:
         """Pin a batch pytree to the flattened batch-axes layout (in-jit)."""
         import jax
 
-        from sheeprl_tpu.utils.jax_compat import with_sharding_constraint
-
         sharding = self.batch_sharding(axis)
         return jax.tree_util.tree_map(
-            lambda x: with_sharding_constraint(x, sharding) if hasattr(x, "shape") else x,
+            lambda x: jax.lax.with_sharding_constraint(x, sharding) if hasattr(x, "shape") else x,
             tree,
         )
 
     def flat_rank(self):
         """Flattened device index inside a ``shard_map`` body: the batch
         shard this device owns, row-major over (data, fsdp) — matches the
-        device order :meth:`batch_spec` splits a batch in.  Built from two
-        ``axis_index`` calls so it works on every jax in the support
-        window (tuple-axis ``axis_index`` is newer than 0.4.x)."""
-        from sheeprl_tpu.utils.jax_compat import flat_axis_index
+        device order :meth:`batch_spec` splits a batch in."""
+        import jax
 
-        return flat_axis_index(BATCH_AXES, (self.data_size, self.fsdp_size))
+        return jax.lax.axis_index(BATCH_AXES)
 
     # ------------------------------------------------------------- telemetry
     def param_shard_bytes(self, tree: Any) -> int:

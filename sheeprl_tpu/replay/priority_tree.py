@@ -7,7 +7,7 @@ implementation is a mutable array-backed segment tree; here the tree is a
 single flat ``jax.Array`` living on the training device next to the
 ``DeviceReplayCache`` rings, so sampling stays inside the jitted sample
 step — an O(log n) vectorized descent, no host round-trips — exactly the
-property that makes the device cache pay on remote-link TPU setups.
+property that keeps the replay feed off the host.
 
 Layout: 1-based heap in a ``(2·P,)`` float32 array where ``P`` is the
 leaf count padded to a power of two; index 0 is unused, the root (total
@@ -37,19 +37,8 @@ __all__ = [
     "ShardedPriorityTree",
     "per_beta_schedule",
     "priority_from_td",
-    "resolve_per_kernel",
     "shard_proportional_draw",
 ]
-
-
-def resolve_per_kernel(value) -> str:
-    """Validate ``buffer.per_kernel``: ``lax`` (default — the gather/
-    scatter-chain kernels below, bit-exact with the pre-kernel tree) or
-    ``pallas`` (ops/pallas_per.py fused kernels, interpret mode off-TPU)."""
-    s = str(value).lower()
-    if s not in ("lax", "pallas"):
-        raise ValueError(f"buffer.per_kernel must be 'lax' or 'pallas', got {value!r}")
-    return s
 
 
 def priority_from_td(td_abs, alpha: float, eps: float):
@@ -173,14 +162,12 @@ class PriorityTree:
         eps: float = 1e-6,
         device=None,
         initial_priority: float = 1.0,
-        kernel: str = "lax",
     ):
         if n_leaves <= 0:
             raise ValueError(f"n_leaves must be positive, got {n_leaves}")
         self.n_leaves = int(n_leaves)
         self.alpha = float(alpha)
         self.eps = float(eps)
-        self.kernel = resolve_per_kernel(kernel)
         self.depth = max(int(self.n_leaves - 1).bit_length(), 1)
         self._device = device
         with jax.default_device(device) if device is not None else _null():
@@ -189,13 +176,6 @@ class PriorityTree:
 
     # ------------------------------------------------------------- write
     def _write_tree(self, leaf_idx, values, active):
-        """Route one scatter-update through the configured kernel (same
-        semantics either way; pallas fuses scatter + rebuild into one
-        ops/pallas_per.py program)."""
-        if self.kernel == "pallas":
-            from sheeprl_tpu.ops.pallas_per import sum_tree_write
-
-            return sum_tree_write(self.tree, leaf_idx, values, active, depth=self.depth)
         return _tree_write(self.tree, leaf_idx, values, active, depth=self.depth)
 
     def seed_max(self, leaf_idx, active) -> None:
@@ -213,13 +193,6 @@ class PriorityTree:
         if active is None:
             active = jnp.ones(leaf_idx.shape, bool)
         pri = priority_from_td(jnp.asarray(td_abs, jnp.float32).reshape(leaf_idx.shape), self.alpha, self.eps)
-        if self.kernel == "pallas":
-            from sheeprl_tpu.ops.pallas_per import sum_tree_update
-
-            self.tree, self.max_priority = sum_tree_update(
-                self.tree, self.max_priority, leaf_idx, pri, jnp.asarray(active), depth=self.depth
-            )
-            return
         self.tree, self.max_priority = _tree_update(
             self.tree, self.max_priority, leaf_idx, pri, jnp.asarray(active), depth=self.depth
         )
@@ -249,23 +222,7 @@ class PriorityTree:
 
         ``exclude_idx``/``exclude_active`` zero those cells in a
         functional copy first — the stored priorities survive (used for
-        the stale-next-obs head row and invalid sequence starts).  The
-        pallas kernel applies the same exclusions as in-descent mass
-        corrections instead (no tree copy; excluded indices must be
-        distinct where active — true for every data-plane caller)."""
-        if self.kernel == "pallas":
-            from sheeprl_tpu.ops.pallas_per import sum_tree_sample
-
-            return sum_tree_sample(
-                self.tree,
-                key,
-                jnp.asarray(beta, jnp.float32),
-                jnp.asarray(count, jnp.float32),
-                n=int(n),
-                depth=self.depth,
-                exclude_idx=exclude_idx,
-                exclude_active=exclude_active,
-            )
+        the stale-next-obs head row and invalid sequence starts)."""
         tree = self.tree
         if exclude_idx is not None:
             ex = jnp.asarray(exclude_idx, jnp.int32)
@@ -337,9 +294,6 @@ def shard_proportional_draw(
     *,
     n,
     depth,
-    kernel: str = "lax",
-    exclude_idx=None,
-    exclude_active=None,
 ):
     """Globally-proportional draw from per-shard sub-trees, callable ONLY
     inside a ``shard_map`` body (it issues collectives over ``axes``).
@@ -358,22 +312,9 @@ def shard_proportional_draw(
     Returns ``(local_leaf, mass, own, total)``: the shard-local leaf and
     its mass for ALL n draws (garbage where ``own`` is False — mask
     before any cross-shard assembly), the ownership mask, and the global
-    total mass (replicated).
-
-    ``kernel="pallas"`` descends each shard's sub-tree through the fused
-    ops/pallas_per.py kernel and folds shard-local sampling exclusions
-    into the descent as mass corrections (``exclude_idx`` — the lax path
-    instead expects the caller to pre-zero a functional sub-tree copy,
-    the historical contract, so exclusions are pallas-only here)."""
-    if kernel == "pallas":
-        from sheeprl_tpu.ops.pallas_per import _excl_args, _excluded_mass, sum_tree_descend
-
-        excl, eact = _excl_args(n, exclude_idx, exclude_active)
-        m_local = tree[1] - jnp.sum(_excluded_mass(tree, excl, eact, depth))
-    else:
-        if exclude_idx is not None:
-            raise ValueError("exclude_idx on the lax path: pre-zero the sub-tree instead")
-        m_local = tree[1]
+    total mass (replicated).  Sampling exclusions are the caller's: it
+    pre-zeroes a functional sub-tree copy."""
+    m_local = tree[1]
     masses = jax.lax.psum(
         jnp.zeros((n_shards,), tree.dtype).at[rank].set(m_local), axes
     )
@@ -390,12 +331,7 @@ def shard_proportional_draw(
     # cumsum rounding can make (hi - lo) exceed this shard's own mass by
     # an ulp; keep the local descent strictly inside the sub-tree
     u_loc = jnp.clip(u - lo, 0.0, m_local * (1.0 - 1e-7))
-    if kernel == "pallas":
-        leaf, mass = sum_tree_descend(
-            tree, u_loc, depth=depth, exclude_idx=excl, exclude_active=eact
-        )
-    else:
-        leaf, mass = _descend(tree, u_loc, depth)
+    leaf, mass = _descend(tree, u_loc, depth)
     return leaf, mass, own, total
 
 
@@ -426,7 +362,6 @@ class ShardedPriorityTree:
         alpha: float = 0.6,
         eps: float = 1e-6,
         initial_priority: float = 1.0,
-        kernel: str = "lax",
     ):
         from sheeprl_tpu.parallel.sharding import BATCH_AXES
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -441,7 +376,6 @@ class ShardedPriorityTree:
         self.n_leaves_local = self.capacity * self.n_local_envs
         self.alpha = float(alpha)
         self.eps = float(eps)
-        self.kernel = resolve_per_kernel(kernel)
         self.depth = max(int(self.n_leaves_local - 1).bit_length(), 1)
         self._mesh = mesh
         self._axes = BATCH_AXES
@@ -465,22 +399,16 @@ class ShardedPriorityTree:
         return env // self.n_local_envs, row * self.n_local_envs + env % self.n_local_envs
 
     def _build_write(self):
-        from sheeprl_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         axes, n_shards, depth = self._axes, self.n_shards, self.depth
         fsdp = int(self._mesh.shape[self._axes[1]])
-        kernel = self.kernel
 
         def body(trees, max_p, shard_ids, local_leaf, values, active, track_max):
             r = jax.lax.axis_index(axes[0]) * fsdp + jax.lax.axis_index(axes[1])
             act = active & (shard_ids == r)
-            if kernel == "pallas":
-                from sheeprl_tpu.ops.pallas_per import sum_tree_scatter
-
-                t = sum_tree_scatter(trees[0], local_leaf, values, act, depth=depth)
-            else:
-                t = _write_impl(trees[0], local_leaf, values, act, depth)
+            t = _write_impl(trees[0], local_leaf, values, act, depth)
             # running max across every shard's accepted writes: pmax keeps
             # it replicated without a host sync (track_max=False for raw
             # set/scale writes, matching PriorityTree semantics)
@@ -543,7 +471,7 @@ class ShardedPriorityTree:
     def priorities(self, leaf_idx) -> jax.Array:
         """Per-cell priorities for GLOBAL cell ids (replicated result —
         each shard contributes its own leaves via one masked psum)."""
-        from sheeprl_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         leaf_idx = jnp.asarray(leaf_idx, jnp.int32).reshape(-1)

@@ -181,7 +181,6 @@ class ReplayServer:
         credit_window: int = 2,
         integrity: str = "off",
         ingest_max_abs: float = 1e6,
-        per_kernel: str = "lax",
     ):
         from sheeprl_tpu.data.buffers import EnvIndependentReplayBuffer
         from sheeprl_tpu.data.device_buffer import DeviceReplayCache
@@ -206,7 +205,6 @@ class ReplayServer:
                 prioritized=True,
                 per_alpha=per_alpha,
                 per_eps=per_eps,
-                kernel=per_kernel,
             )
             if self.prioritized
             else None

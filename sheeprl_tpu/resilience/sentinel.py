@@ -819,10 +819,8 @@ class GuardedUpdate:
                 # host polls it every check_every dispatches, and a sharded
                 # (or device-0-pinned) layout would turn that poll into a
                 # cross-device fetch on the hot path (asserted by tests)
-                from sheeprl_tpu.utils.jax_compat import with_sharding_constraint
-
                 new_sentinel = SentinelState(*(
-                    with_sharding_constraint(leaf, layout.replicated)
+                    jax.lax.with_sharding_constraint(leaf, layout.replicated)
                     for leaf in new_sentinel
                 ))
             return (new_sentinel, *selected, metrics, *rest)

@@ -114,3 +114,41 @@ def test_beta_schedule_and_priority_exponent():
     assert beta(100) == pytest.approx(1.0)
     assert beta(1000) == pytest.approx(1.0)  # clamped past the horizon
     assert priority_from_td(np.float32(-2.0), alpha=1.0, eps=0.5) == pytest.approx(2.5)
+
+
+# -------------------------------------------- duplicate-index semantics unit
+def test_scale_duplicate_indices_scale_once():
+    """`scale` documents gather-then-write: duplicates decay ONCE per
+    call, not once per occurrence."""
+    t = PriorityTree(8)
+    t.set_priorities(np.arange(8), np.full(8, 2.0, np.float32))
+    t.scale(np.array([3, 3, 3, 5]), 0.5)
+    pri = np.asarray(t.priorities(np.arange(8)))
+    np.testing.assert_allclose(pri, [2, 2, 2, 1, 2, 1, 2, 2])
+    assert t.total == pytest.approx(float(pri.sum()))
+
+
+def test_set_priorities_masked_duplicate_cannot_drop_active_write():
+    """The PR-12 `_write_impl` regression, at the public API: an INACTIVE
+    duplicate of an active leaf must not win the one-writer-per-duplicate
+    scatter and drop the active write."""
+    t = PriorityTree(8)
+    t.set_priorities(np.arange(8), np.ones(8, np.float32))
+    idx = np.array([4, 4], np.int32)
+    vals = np.array([9.0, 123.0], np.float32)
+    act = np.array([True, False])
+    t.set_priorities(idx, vals, act)
+    assert float(t.priorities(4)) == pytest.approx(9.0)
+    assert t.total == pytest.approx(16.0)
+    # ancestors rebuilt consistently
+    tree = np.asarray(t.tree)
+    p = 1 << t.depth
+    for node in range(1, p):
+        assert tree[node] == pytest.approx(tree[2 * node] + tree[2 * node + 1])
+
+
+def test_set_priorities_equal_duplicates_write_once():
+    t = PriorityTree(8)
+    t.set_priorities(np.array([2, 2, 2]), np.array([3.0, 3.0, 3.0], np.float32))
+    assert float(t.priorities(2)) == pytest.approx(3.0)
+    assert t.total == pytest.approx(3.0)

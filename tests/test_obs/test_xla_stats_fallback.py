@@ -1,9 +1,8 @@
-"""Cost-analysis fallbacks in obs/xla_stats.py: ``compiled_flops`` /
-``peak_flops`` / ``mfu_percent`` must degrade to None — never raise — on
-the backends that don't support cost analysis (remote PJRT plugins, CPU),
-and the RecompileMonitor must count events without a live jax backend."""
-
-import warnings
+"""Unknowns in obs/xla_stats.py: ``compiled_flops`` / ``peak_flops`` /
+``mfu_percent`` answer None for what is genuinely unknown (no flops in the
+analysis, a device kind outside the table) and RAISE on a failing analysis
+or a malformed override; the RecompileMonitor counts events without a live
+jax backend."""
 
 import pytest
 
@@ -33,13 +32,14 @@ def test_flops_from_dict_and_legacy_list_shapes():
     assert compiled_flops(_Compiled(({"flops": 7.0},))) == 7.0
 
 
-def test_missing_cost_analysis_method_is_none():
-    assert compiled_flops(object()) is None
+def test_missing_cost_analysis_method_raises():
+    with pytest.raises(AttributeError):
+        compiled_flops(object())
 
 
-def test_cost_analysis_raising_is_none():
-    # some remote PJRT plugins raise XlaRuntimeError("not supported")
-    assert compiled_flops(_Compiled(RuntimeError("cost analysis not supported"))) is None
+def test_cost_analysis_raising_propagates():
+    with pytest.raises(RuntimeError, match="not supported"):
+        compiled_flops(_Compiled(RuntimeError("cost analysis not supported")))
 
 
 def test_cost_analysis_returning_none_or_empty_is_none():
@@ -60,14 +60,15 @@ def test_peak_from_device_kind_table():
     assert peak_flops(_Device("TPU v5 lite")) == 197e12
     assert peak_flops(_Device("cpu")) is None  # CPUs have no published peak
     assert peak_flops(_Device("")) is None
+    assert peak_flops(_Device("TPU v9 future")) is None  # never another chip's peak
 
 
-def test_peak_env_override_wins_and_bad_value_warns(monkeypatch):
+def test_peak_env_override_wins_and_bad_value_raises(monkeypatch):
     monkeypatch.setenv("SHEEPRL_PEAK_FLOPS", "1e12")
     assert peak_flops(_Device("cpu")) == 1e12
     monkeypatch.setenv("SHEEPRL_PEAK_FLOPS", "not-a-number")
-    with pytest.warns(UserWarning, match="SHEEPRL_PEAK_FLOPS"):
-        assert peak_flops(_Device("TPU v4")) == 275e12  # falls back to the table
+    with pytest.raises(ValueError):
+        peak_flops(_Device("TPU v4"))
 
 
 # -------------------------------------------------------------- mfu_percent
@@ -87,7 +88,7 @@ def test_monitor_counts_without_jax_backend():
     mon = RecompileMonitor(name="t", warn=False)
     # feed the listener callbacks directly — no jax.monitoring needed
     mon._on_duration("/jax/core/compile/backend_compile_duration", 1.5)
-    mon._on_duration("/jax/core/jaxpr_trace_duration", 0.25)
+    mon._on_duration("/jax/core/compile/jaxpr_trace_duration", 0.25)
     mon._on_event("/jax/compilation_cache/cache_hits")
     mon._on_event("/jax/compilation_cache/cache_misses")
     snap = mon.snapshot()

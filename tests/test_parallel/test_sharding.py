@@ -66,6 +66,32 @@ def test_layout_specs_and_shard_bytes():
     assert d["axes"] == {"data": 4, "fsdp": 2}
 
 
+def test_flat_rank_matches_batch_split_order():
+    """The flat device index inside a shard_map body must match the order
+    the flattened batch spec splits arrays in (shard i of
+    P(("data", "fsdp")) lands on flat device i)."""
+    _need8()
+    rt = MeshRuntime(devices=8, strategy="fsdp", accelerator="cpu", mesh_shape="4x2").launch()
+    layout = rt.layout
+    fn = jax.shard_map(
+        lambda x: x * 0 + layout.flat_rank(),
+        mesh=rt.mesh,
+        in_specs=(layout.batch_spec(0),),
+        out_specs=layout.batch_spec(0),
+        check_vma=False,
+    )
+    out = np.asarray(jax.jit(fn)(jnp.zeros(8, jnp.int32)))
+    np.testing.assert_array_equal(out, np.arange(8))
+
+
+def test_constrain_batch_pins_flattened_batch_axes():
+    _need8()
+    rt = MeshRuntime(devices=8, strategy="fsdp", accelerator="cpu", mesh_shape="4x2").launch()
+    out = jax.jit(lambda x: rt.layout.constrain_batch({"x": x * 2})["x"])(jnp.arange(16.0))
+    np.testing.assert_allclose(np.asarray(out), np.arange(16.0) * 2)
+    assert out.sharding.spec == P(BATCH_AXES)
+
+
 def test_explicit_mesh_shape_fsdp_placement():
     _need8()
     rt = MeshRuntime(devices=8, strategy="fsdp", accelerator="cpu", mesh_shape=[4, 2]).launch()
@@ -171,12 +197,12 @@ def test_sentinel_state_replicated_on_mesh():
 
 
 # ------------------------------------------------- sharded prioritized replay
-def _filled_caches(cap=16, n_envs=8, steps=12, prioritized=True, kernel="lax"):
+def _filled_caches(cap=16, n_envs=8, steps=12, prioritized=True):
     from sheeprl_tpu.data.device_buffer import DeviceReplayCache, ShardedDeviceReplayCache
 
     rt = MeshRuntime(devices=8, strategy="dp", accelerator="cpu").launch()
     sharded = ShardedDeviceReplayCache(
-        cap, n_envs, rt, prioritized=prioritized, per_alpha=1.0, per_eps=0.0, kernel=kernel
+        cap, n_envs, rt, prioritized=prioritized, per_alpha=1.0, per_eps=0.0
     )
     single = DeviceReplayCache(cap, n_envs, prioritized=prioritized, per_alpha=1.0, per_eps=0.0)
     rng = np.random.default_rng(1)
@@ -230,57 +256,6 @@ def test_sharded_per_marginals_match_single_device_tree():
     pw /= pw.sum()
     assert np.abs(emp_s - pw).max() < 0.008
     assert np.abs(emp_s - emp_1).max() < 0.012
-
-
-def test_sharded_per_pallas_kernel_marginals_and_writes():
-    """ISSUE 14 acceptance: the 8-device ``ShardedPriorityTree`` with
-    ``per_kernel=pallas`` — per-shard fused descent composed with
-    ``shard_proportional_draw``, shard-local exclusions folded into the
-    descent as mass corrections — keeps the sampled marginals within the
-    PR-12 tolerance of the exact single-global-sum-tree distribution, and
-    the fused scatter kernel keeps writes in lockstep with the lax tree."""
-    _need8()
-    cap, n_envs = 16, 8
-    rt, sharded, single, rng = _filled_caches(cap, n_envs, kernel="pallas")
-    assert sharded._tree.kernel == "pallas"
-    n = cap * n_envs
-    written = np.zeros((cap, n_envs), np.float32)
-    written[:12] = 1.0
-    pri = (rng.uniform(0.1, 3.0, size=(cap, n_envs)).astype(np.float32) * written).reshape(-1)
-    idx = np.arange(n)
-    sharded._tree.set_priorities(idx, pri)  # pallas scatter kernel per shard
-    single._tree.set_priorities(idx, pri)
-    assert sharded._tree.total == pytest.approx(single._tree.total, rel=1e-5)
-    draws = []
-    for i in range(25):
-        _, lv = sharded.sample_transitions_per(
-            4, 64, jax.random.PRNGKey(100 + i), beta=0.0, sample_next_obs=True, obs_keys=("obs",)
-        )
-        draws.append(np.asarray(lv).reshape(-1))
-    emp = np.bincount(np.concatenate(draws), minlength=n).astype(np.float64)
-    emp /= emp.sum()
-    # exact single-tree marginals: priorities with head rows excluded
-    head = (sharded._pos - 1) % cap
-    pw = pri.copy().reshape(cap, n_envs)
-    pw[head, np.arange(n_envs)] = 0.0
-    pw = pw.reshape(-1)
-    pw /= pw.sum()
-    assert np.abs(emp - pw).max() < 0.008  # the PR-12 tolerance
-    # prioritized sequence windows stay contiguous through the pallas path
-    # (before the TD update below hands unwritten cells priority mass)
-    out = sharded.sample_per(2, 16, 4, jax.random.PRNGKey(9), beta=0.0)
-    rw = np.asarray(out[0]["rewards"])[:, :, 0]
-    assert set(np.unique(rw[1:] - rw[:-1])) <= {1.0}
-    # fused write kernel: TD updates land identically to the lax tree
-    upd = rng.choice(n, size=40, replace=False).astype(np.int32)
-    td = np.abs(rng.normal(size=40)).astype(np.float32)
-    sharded.update_priorities(upd, td)
-    single.update_priorities(upd, td)
-    np.testing.assert_allclose(
-        np.asarray(sharded._tree.priorities(upd)),
-        np.asarray(single._tree.priorities(upd)),
-        rtol=1e-6,
-    )
 
 
 def test_sharded_per_update_priorities_roundtrip_and_state():

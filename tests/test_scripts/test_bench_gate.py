@@ -103,3 +103,19 @@ def test_gate_runs_against_committed_rounds(bench):
     }
     gate = bench.run_perf_gate(current)
     assert [r["metric"] for r in gate["regressions"]] == ["ppo_cartpole_benchmark_wallclock"]
+
+
+def test_importing_bench_leaves_the_jax_backends_uninitialized():
+    """One process per chip: the bench parent only spawns the section
+    children and must never touch a backend itself — a parent that holds
+    the chip makes every child fail or hang."""
+    import subprocess
+    import sys
+
+    probe = (
+        "import sys; sys.path.insert(0, {repo!r}); import bench; "
+        "xb = sys.modules.get('jax._src.xla_bridge'); "
+        "assert not (xb and xb._backends), sorted(xb._backends)"
+    ).format(repo=_REPO)
+    proc = subprocess.run([sys.executable, "-c", probe], capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]

@@ -73,6 +73,48 @@ def test_warmup_requires_explicit_mark():
         mon.uninstall()
 
 
+def test_monitor_counts_the_events_this_jax_emits(tmp_path):
+    """Every counter moves on a real compile under jax 0.9.0: a renamed
+    monitoring event fails here instead of leaving a counter at 0."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    saved = {
+        k: getattr(jax.config, k)
+        for k in (
+            "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes",
+        )
+    }
+    mon = RecompileMonitor(name="test", warn=False).install()
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        cc.reset_cache()
+
+        def make():
+            # a new function object each time: the in-memory jit cache misses
+            # while the lowered program, and so the disk entry's key, is the same
+            def k(x):
+                return jnp.tanh(x) * 3.0 + 1.0
+
+            return jax.jit(k)
+
+        x = jnp.ones((7,))
+        jax.block_until_ready(make()(x))  # traced, compiled, a cache miss, written
+        assert mon.compiles >= 1 and mon.compile_time_s > 0
+        assert mon.trace_time_s > 0
+        assert mon.cache_misses >= 1 and mon.cache_hits == 0
+        jax.block_until_ready(make()(x))  # read back from the disk entry
+        assert mon.cache_hits >= 1
+    finally:
+        mon.uninstall()
+        for key, value in saved.items():
+            jax.config.update(key, value)
+        cc.reset_cache()
+
+
 def test_mfu_percent_math():
     # 50 TFLOP step in 1 s on a 100 TFLOP/s chip = 50% MFU
     assert mfu_percent(50e12, 1.0, peak=100e12) == pytest.approx(50.0)
