@@ -210,11 +210,15 @@ def make_train_fn(runtime, world_model, actor, critic, txs, cfg, is_continuous, 
     dyn_bptt = dyn_bptt_setting(cfg) and rssm_dyn_bptt_eligible(rssm)
 
     def train(params, opt_states, moments_state, data, key):
+        # one jax.named_scope per phase, names fixed and disjoint: they are
+        # HLO metadata only, and what a profile (and chipbench/scope_reduce.py)
+        # splits the update's device time by; backward ops inherit the name
         T, B = data["rewards"].shape[:2]
         k_dyn, k_img, k_actor = jax.random.split(key, 3)
 
-        batch_obs = {k: data[k] / 255.0 - 0.5 for k in cnn_keys}
-        batch_obs.update({k: data[k] for k in mlp_keys})
+        with jax.named_scope("wm_encoder"):
+            batch_obs = {k: data[k] / 255.0 - 0.5 for k in cnn_keys}
+            batch_obs.update({k: data[k] for k in mlp_keys})
         is_first = data["is_first"].at[0].set(1.0)
         # shift actions: a_t in the buffer acted AFTER o_t; the RSSM input at
         # t is the PREVIOUS action (reference dreamer_v3.py:104)
@@ -227,204 +231,212 @@ def make_train_fn(runtime, world_model, actor, critic, txs, cfg, is_continuous, 
         # two batched gumbel ops, instead of 3 threefry chains per scan
         # iteration — the scan bodies are latency-bound, so op count inside
         # the sequential loop is what sets the step time
-        noise_shape = (T, B, stochastic_size, discrete_size)
-        dyn_noise_q = jax.random.gumbel(k_dyn, noise_shape, jnp.float32)
+        with jax.named_scope("wm_dynamics"):
+            noise_shape = (T, B, stochastic_size, discrete_size)
+            dyn_noise_q = jax.random.gumbel(k_dyn, noise_shape, jnp.float32)
 
         # the CNN encoder converts to the compute dtype at its first conv
         # anyway; handing it a bf16 copy halves the biggest single input read
         # (the (T, B, 64, 64, C) pixel stack).  MLP observations stay f32:
         # their encoder applies symlog BEFORE the first Dense, so pre-rounding
         # them would change the compression.  Loss targets keep f32 originals.
-        enc_obs = {k: batch_obs[k].astype(runtime.compute_dtype) for k in cnn_keys}
-        enc_obs.update({k: batch_obs[k] for k in mlp_keys})
+        with jax.named_scope("wm_encoder"):
+            enc_obs = {k: batch_obs[k].astype(runtime.compute_dtype) for k in cnn_keys}
+            enc_obs.update({k: batch_obs[k] for k in mlp_keys})
 
         def wm_loss_fn(wm_params):
-            embedded_obs = world_model.encoder.apply(wm_params["encoder"], enc_obs)  # (T, B, E)
-            # constant wrt t: evaluate the learned initial state (which runs
-            # the transition MLP) ONCE instead of in every scan iteration
-            init_states = rssm.apply(
-                wm_params["rssm"], (B,), method=RSSM.get_initial_states
-            )
-            init_states = (init_states[0], init_states[1].reshape(B, -1))
-
-            if decoupled:
-                # posterior depends only on obs (reference DecoupledRSSM:501;
-                # dreamer_v3.py:117-131): compute all posteriors up front,
-                # roll the recurrent model with the previous-step posterior
-                posteriors_logits, posteriors = rssm.apply(
-                    wm_params["rssm"], embedded_obs, None, noise=dyn_noise_q,
-                    method=RSSM._representation,
+            with jax.named_scope("wm_encoder"):
+                embedded_obs = world_model.encoder.apply(wm_params["encoder"], enc_obs)  # (T, B, E)
+            with jax.named_scope("wm_dynamics"):
+                # constant wrt t: evaluate the learned initial state (which runs
+                # the transition MLP) ONCE instead of in every scan iteration
+                init_states = rssm.apply(
+                    wm_params["rssm"], (B,), method=RSSM.get_initial_states
                 )
-                prev_posteriors = jnp.concatenate(
-                    [jnp.zeros_like(posteriors[:1]), posteriors[:-1]], 0
-                )
+                init_states = (init_states[0], init_states[1].reshape(B, -1))
 
-                # the recurrent model's input projection sees only
-                # [z_{t-1}, a_t] — all known up front here — so it batches
-                # over the whole sequence and the scan body shrinks to the
-                # is_first-gated GRU cell (RSSM.recurrent_features_seq)
-                feats = rssm.apply(
-                    wm_params["rssm"], prev_posteriors, batch_actions,
-                    is_first, init_states[1],
-                    method=RSSM.recurrent_features_seq,
-                )
-
-                if rssm.seq_scan_eligible(int(feats.shape[-1])):
-                    # the whole recurrence in ONE Pallas kernel (weights
-                    # VMEM-resident across time, efficient-BPTT custom VJP)
-                    recurrent_states = rssm.apply(
-                        wm_params["rssm"], feats, is_first, init_states[0],
-                        method=RSSM.gru_sequence_gated,
+                if decoupled:
+                    # posterior depends only on obs (reference DecoupledRSSM:501;
+                    # dreamer_v3.py:117-131): compute all posteriors up front,
+                    # roll the recurrent model with the previous-step posterior
+                    posteriors_logits, posteriors = rssm.apply(
+                        wm_params["rssm"], embedded_obs, None, noise=dyn_noise_q,
+                        method=RSSM._representation,
                     )
+                    prev_posteriors = jnp.concatenate(
+                        [jnp.zeros_like(posteriors[:1]), posteriors[:-1]], 0
+                    )
+
+                    # the recurrent model's input projection sees only
+                    # [z_{t-1}, a_t] — all known up front here — so it batches
+                    # over the whole sequence and the scan body shrinks to the
+                    # is_first-gated GRU cell (RSSM.recurrent_features_seq)
+                    feats = rssm.apply(
+                        wm_params["rssm"], prev_posteriors, batch_actions,
+                        is_first, init_states[1],
+                        method=RSSM.recurrent_features_seq,
+                    )
+
+                    if rssm.seq_scan_eligible(int(feats.shape[-1])):
+                        # the whole recurrence in ONE Pallas kernel (weights
+                        # VMEM-resident across time, efficient-BPTT custom VJP)
+                        recurrent_states = rssm.apply(
+                            wm_params["rssm"], feats, is_first, init_states[0],
+                            method=RSSM.gru_sequence_gated,
+                        )
+                    else:
+                        @jax.named_scope("wm_dynamics")  # as img_step below
+                        def dyn_step_dec(recurrent_state, inp):
+                            feat, first = inp
+                            recurrent_state = rssm.apply(
+                                wm_params["rssm"],
+                                feat,
+                                recurrent_state,
+                                first,
+                                init_states[0],
+                                method=RSSM.gru_step_gated,
+                            )
+                            return recurrent_state, recurrent_state
+
+                        _, recurrent_states = jax.lax.scan(
+                            dyn_step_dec,
+                            jnp.zeros((B, recurrent_state_size)),
+                            (feats, is_first),
+                            unroll=scan_unroll,
+                        )
                 else:
-                    def dyn_step_dec(recurrent_state, inp):
-                        feat, first = inp
-                        recurrent_state = rssm.apply(
-                            wm_params["rssm"],
-                            feat,
-                            recurrent_state,
-                            first,
+
+                    # embed half of the representation model's first matmul,
+                    # batched over the whole sequence (see representation_embed_proj)
+                    emb_proj = rssm.apply(
+                        wm_params["rssm"], embedded_obs, method=RSSM.representation_embed_proj
+                    )
+
+                    if dyn_bptt:
+                        hs_, zst_, mixed_ = dyn_rssm_sequence(
+                            jnp.zeros((B, stochastic_size * discrete_size)),
+                            jnp.zeros((B, recurrent_state_size)),
+                            batch_actions,
+                            emb_proj,
+                            is_first,
+                            dyn_noise_q,
                             init_states[0],
-                            method=RSSM.gru_step_gated,
+                            init_states[1],
+                            extract_dyn_params(wm_params["rssm"], recurrent_state_size),
+                            eps_proj=rssm.eps,
+                            eps_rep=rssm.eps,
+                            unimix=rssm.unimix,
+                            discrete=discrete_size,
+                            matmul_dtype=rssm.dtype,
+                            unroll=scan_unroll,
                         )
-                        return recurrent_state, recurrent_state
+                        recurrent_states = hs_
+                        posteriors = zst_.reshape(T, B, stochastic_size, discrete_size)
+                        posteriors_logits = mixed_
+                    else:
+                        @jax.named_scope("wm_dynamics")  # as img_step below
+                        def dyn_step(carry, inp):
+                            posterior, recurrent_state = carry
+                            action, emb, first, nq_t = inp
+                            recurrent_state, posterior, posterior_logits = rssm.apply(
+                                wm_params["rssm"],
+                                posterior,
+                                recurrent_state,
+                                action,
+                                emb,
+                                first,
+                                init_states,
+                                noise=nq_t,
+                                method=RSSM.dynamic_posterior,
+                            )
+                            return (posterior, recurrent_state), (
+                                recurrent_state,
+                                posterior,
+                                posterior_logits,
+                            )
 
-                    _, recurrent_states = jax.lax.scan(
-                        dyn_step_dec,
-                        jnp.zeros((B, recurrent_state_size)),
-                        (feats, is_first),
-                        unroll=scan_unroll,
-                    )
-            else:
-
-                # embed half of the representation model's first matmul,
-                # batched over the whole sequence (see representation_embed_proj)
-                emb_proj = rssm.apply(
-                    wm_params["rssm"], embedded_obs, method=RSSM.representation_embed_proj
+                        init = (
+                            jnp.zeros((B, stochastic_size, discrete_size)),
+                            jnp.zeros((B, recurrent_state_size)),
+                        )
+                        _, (recurrent_states, posteriors, posteriors_logits) = jax.lax.scan(
+                            _remat(dyn_step, dyn_remat_policy), init,
+                            (batch_actions, emb_proj, is_first, dyn_noise_q),
+                            unroll=scan_unroll,
+                        )
+                # prior logits for the KL, batched over the stacked recurrent
+                # states of the whole sequence (the prior SAMPLE is unused by
+                # the world-model loss, so nothing prior-related needs to live
+                # inside the sequential scan)
+                priors_logits, _ = rssm.apply(
+                    wm_params["rssm"], recurrent_states, None, sample_state=False,
+                    method=RSSM._transition,
                 )
-
-                if dyn_bptt:
-                    hs_, zst_, mixed_ = dyn_rssm_sequence(
-                        jnp.zeros((B, stochastic_size * discrete_size)),
-                        jnp.zeros((B, recurrent_state_size)),
-                        batch_actions,
-                        emb_proj,
-                        is_first,
-                        dyn_noise_q,
-                        init_states[0],
-                        init_states[1],
-                        extract_dyn_params(wm_params["rssm"], recurrent_state_size),
-                        eps_proj=rssm.eps,
-                        eps_rep=rssm.eps,
-                        unimix=rssm.unimix,
-                        discrete=discrete_size,
-                        matmul_dtype=rssm.dtype,
-                        unroll=scan_unroll,
-                    )
-                    recurrent_states = hs_
-                    posteriors = zst_.reshape(T, B, stochastic_size, discrete_size)
-                    posteriors_logits = mixed_
-                else:
-                    def dyn_step(carry, inp):
-                        posterior, recurrent_state = carry
-                        action, emb, first, nq_t = inp
-                        recurrent_state, posterior, posterior_logits = rssm.apply(
-                            wm_params["rssm"],
-                            posterior,
-                            recurrent_state,
-                            action,
-                            emb,
-                            first,
-                            init_states,
-                            noise=nq_t,
-                            method=RSSM.dynamic_posterior,
-                        )
-                        return (posterior, recurrent_state), (
-                            recurrent_state,
-                            posterior,
-                            posterior_logits,
-                        )
-
-                    init = (
-                        jnp.zeros((B, stochastic_size, discrete_size)),
-                        jnp.zeros((B, recurrent_state_size)),
-                    )
-                    _, (recurrent_states, posteriors, posteriors_logits) = jax.lax.scan(
-                        _remat(dyn_step, dyn_remat_policy), init,
-                        (batch_actions, emb_proj, is_first, dyn_noise_q),
-                        unroll=scan_unroll,
-                    )
-            # prior logits for the KL, batched over the stacked recurrent
-            # states of the whole sequence (the prior SAMPLE is unused by
-            # the world-model loss, so nothing prior-related needs to live
-            # inside the sequential scan)
-            priors_logits, _ = rssm.apply(
-                wm_params["rssm"], recurrent_states, None, sample_state=False,
-                method=RSSM._transition,
-            )
-            latent_states = jnp.concatenate(
-                [posteriors.reshape(T, B, -1), recurrent_states], -1
-            )
-            reconstructed_obs = world_model.observation_model.apply(
-                wm_params["observation_model"], latent_states
-            )
-            po = {
-                k: MSEDistribution(reconstructed_obs[k], dims=len(reconstructed_obs[k].shape[2:]))
-                for k in cnn_keys_dec
-            }
-            po.update(
-                {
-                    k: SymlogDistribution(
-                        reconstructed_obs[k], dims=len(reconstructed_obs[k].shape[2:])
-                    )
-                    for k in mlp_keys_dec
+            with jax.named_scope("wm_heads"):
+                latent_states = jnp.concatenate(
+                    [posteriors.reshape(T, B, -1), recurrent_states], -1
+                )
+                reconstructed_obs = world_model.observation_model.apply(
+                    wm_params["observation_model"], latent_states
+                )
+                po = {
+                    k: MSEDistribution(reconstructed_obs[k], dims=len(reconstructed_obs[k].shape[2:]))
+                    for k in cnn_keys_dec
                 }
-            )
-            pr = TwoHotEncodingDistribution(
-                world_model.reward_model.apply(wm_params["reward_model"], latent_states), dims=1
-            )
-            pc = Independent(
-                BernoulliSafeMode(
-                    logits=world_model.continue_model.apply(wm_params["continue_model"], latent_states)
-                ),
-                1,
-            )
-            continue_targets = 1 - data["terminated"]
-            pl = priors_logits.reshape(T, B, stochastic_size, discrete_size)
-            psl = posteriors_logits.reshape(T, B, stochastic_size, discrete_size)
-            rec_loss, kl, state_loss, reward_loss, observation_loss, continue_loss = reconstruction_loss(
-                po,
-                batch_obs,
-                pr,
-                data["rewards"],
-                pl,
-                psl,
-                kl_dynamic,
-                kl_representation,
-                kl_free_nats,
-                kl_regularizer,
-                pc,
-                continue_targets,
-                continue_scale_factor,
-            )
-            aux = {
-                "posteriors": posteriors,
-                "recurrent_states": recurrent_states,
-                "posteriors_logits": psl,
-                "priors_logits": pl,
-                "kl": kl,
-                "state_loss": state_loss,
-                "reward_loss": reward_loss,
-                "observation_loss": observation_loss,
-                "continue_loss": continue_loss,
-            }
+                po.update(
+                    {
+                        k: SymlogDistribution(
+                            reconstructed_obs[k], dims=len(reconstructed_obs[k].shape[2:])
+                        )
+                        for k in mlp_keys_dec
+                    }
+                )
+                pr = TwoHotEncodingDistribution(
+                    world_model.reward_model.apply(wm_params["reward_model"], latent_states), dims=1
+                )
+                pc = Independent(
+                    BernoulliSafeMode(
+                        logits=world_model.continue_model.apply(wm_params["continue_model"], latent_states)
+                    ),
+                    1,
+                )
+                continue_targets = 1 - data["terminated"]
+                pl = priors_logits.reshape(T, B, stochastic_size, discrete_size)
+                psl = posteriors_logits.reshape(T, B, stochastic_size, discrete_size)
+                rec_loss, kl, state_loss, reward_loss, observation_loss, continue_loss = reconstruction_loss(
+                    po,
+                    batch_obs,
+                    pr,
+                    data["rewards"],
+                    pl,
+                    psl,
+                    kl_dynamic,
+                    kl_representation,
+                    kl_free_nats,
+                    kl_regularizer,
+                    pc,
+                    continue_targets,
+                    continue_scale_factor,
+                )
+                aux = {
+                    "posteriors": posteriors,
+                    "recurrent_states": recurrent_states,
+                    "posteriors_logits": psl,
+                    "priors_logits": pl,
+                    "kl": kl,
+                    "state_loss": state_loss,
+                    "reward_loss": reward_loss,
+                    "observation_loss": observation_loss,
+                    "continue_loss": continue_loss,
+                }
             return rec_loss, aux
 
         (rec_loss, wm_aux), wm_grads = jax.value_and_grad(wm_loss_fn, has_aux=True)(
             params["world_model"]
         )
-        updates, new_wm_opt = wm_tx.update(wm_grads, opt_states["world_model"], params["world_model"])
-        new_wm_params = optax.apply_updates(params["world_model"], updates)
+        with jax.named_scope("wm_optim"):
+            updates, new_wm_opt = wm_tx.update(wm_grads, opt_states["world_model"], params["world_model"])
+            new_wm_params = optax.apply_updates(params["world_model"], updates)
 
         # ---------------------------------------------------- imagination
         # starts from the (detached) posteriors; rollout uses the UPDATED
@@ -437,133 +449,140 @@ def make_train_fn(runtime, world_model, actor, critic, txs, cfg, is_continuous, 
         # all-gathers, replicating 80%+ of the step's FLOPs on every
         # device.  Downstream ops reduce over the merged axis, so the
         # order change is semantics-free.
-        imagined_prior0 = sg(wm_aux["posteriors"]).swapaxes(0, 1).reshape(T * B, stoch_state_size)
-        recurrent_state0 = (
-            sg(wm_aux["recurrent_states"]).swapaxes(0, 1).reshape(T * B, recurrent_state_size)
-        )
-        true_continue = (1 - data["terminated"]).swapaxes(0, 1).reshape(1, T * B, 1)
+        with jax.named_scope("bh_imagine"):
+            imagined_prior0 = sg(wm_aux["posteriors"]).swapaxes(0, 1).reshape(T * B, stoch_state_size)
+            recurrent_state0 = (
+                sg(wm_aux["recurrent_states"]).swapaxes(0, 1).reshape(T * B, recurrent_state_size)
+            )
+            true_continue = (1 - data["terminated"]).swapaxes(0, 1).reshape(1, T * B, 1)
 
-        # imagination RNG, hoisted out of the scan body like the dynamic
-        # scan's: one batched gumbel draw for every step's prior sample,
-        # pre-split keys for the actor heads
-        k_img_n, k_img_a = jax.random.split(k_img)
-        img_noise = jax.random.gumbel(
-            k_img_n, (horizon, T * B, stochastic_size, discrete_size), jnp.float32
-        )
-        act_keys = jax.random.split(k_img_a, horizon + 1)
+            # imagination RNG, hoisted out of the scan body like the dynamic
+            # scan's: one batched gumbel draw for every step's prior sample,
+            # pre-split keys for the actor heads
+            k_img_n, k_img_a = jax.random.split(k_img)
+            img_noise = jax.random.gumbel(
+                k_img_n, (horizon, T * B, stochastic_size, discrete_size), jnp.float32
+            )
+            act_keys = jax.random.split(k_img_a, horizon + 1)
 
         traj_dtype = runtime.compute_dtype
 
         def actor_loss_fn(actor_params):
-            latent0 = jnp.concatenate([imagined_prior0, recurrent_state0], -1).astype(traj_dtype)
-            acts0, _ = actor.apply(actor_params, sg(latent0), False, act_keys[0])
-            action0 = jnp.concatenate(acts0, -1)
+            with jax.named_scope("bh_imagine"):
+                latent0 = jnp.concatenate([imagined_prior0, recurrent_state0], -1).astype(traj_dtype)
+                acts0, _ = actor.apply(actor_params, sg(latent0), False, act_keys[0])
+                action0 = jnp.concatenate(acts0, -1)
 
-            def img_step(carry, inp):
-                prior, rec, action = carry
-                n_t, k_act = inp
-                imagined_prior, rec = rssm.apply(
-                    new_wm_params["rssm"], prior, rec, action, None, noise=n_t,
-                    method=RSSM.imagination,
+                # the scope again inside the body: what autodiff hoists out of
+                # the loop (the weights' bf16 casts) keeps only the body's own path
+                @jax.named_scope("bh_imagine")
+                def img_step(carry, inp):
+                    prior, rec, action = carry
+                    n_t, k_act = inp
+                    imagined_prior, rec = rssm.apply(
+                        new_wm_params["rssm"], prior, rec, action, None, noise=n_t,
+                        method=RSSM.imagination,
+                    )
+                    imagined_prior = imagined_prior.reshape(-1, stoch_state_size)
+                    latent = jnp.concatenate([imagined_prior, rec], -1)
+                    acts, _ = actor.apply(actor_params, sg(latent), False, k_act)
+                    action = jnp.concatenate(acts, -1)
+                    # stack the trajectory in the compute dtype: every consumer
+                    # (critic/reward/continue/actor heads) immediately converts
+                    # to bf16 anyway, and the (H, T*B, L) stacks are the step's
+                    # biggest activation traffic (reference trains these heads
+                    # under torch.autocast bf16, so precision semantics match)
+                    return (imagined_prior, rec, action), (latent.astype(traj_dtype), action)
+
+                # remat: the imagination while-loop is HBM-bound on the ~40
+                # stacked (H, T*B, 512) residual buffers autodiff saves for the
+                # backward pass — recomputing the body instead keeps only the
+                # carry + outputs and cuts the loop's memory traffic several-fold
+                (_, _, _), (latents, actions_seq) = jax.lax.scan(
+                    _remat(img_step), (imagined_prior0, recurrent_state0, action0),
+                    (img_noise, act_keys[1:]),
+                    unroll=img_unroll,
                 )
-                imagined_prior = imagined_prior.reshape(-1, stoch_state_size)
-                latent = jnp.concatenate([imagined_prior, rec], -1)
-                acts, _ = actor.apply(actor_params, sg(latent), False, k_act)
-                action = jnp.concatenate(acts, -1)
-                # stack the trajectory in the compute dtype: every consumer
-                # (critic/reward/continue/actor heads) immediately converts
-                # to bf16 anyway, and the (H, T*B, L) stacks are the step's
-                # biggest activation traffic (reference trains these heads
-                # under torch.autocast bf16, so precision semantics match)
-                return (imagined_prior, rec, action), (latent.astype(traj_dtype), action)
+                imagined_trajectories = jnp.concatenate([latent0[None], latents], 0)  # (H+1, TB, L)
+                imagined_actions = jnp.concatenate([action0[None], actions_seq], 0)
 
-            # remat: the imagination while-loop is HBM-bound on the ~40
-            # stacked (H, T*B, 512) residual buffers autodiff saves for the
-            # backward pass — recomputing the body instead keeps only the
-            # carry + outputs and cuts the loop's memory traffic several-fold
-            (_, _, _), (latents, actions_seq) = jax.lax.scan(
-                _remat(img_step), (imagined_prior0, recurrent_state0, action0),
-                (img_noise, act_keys[1:]),
-                unroll=img_unroll,
-            )
-            imagined_trajectories = jnp.concatenate([latent0[None], latents], 0)  # (H+1, TB, L)
-            imagined_actions = jnp.concatenate([action0[None], actions_seq], 0)
+            with jax.named_scope("bh_actor"):
+                traj_head_trees = [
+                    params["critic"],
+                    new_wm_params["reward_model"],
+                    new_wm_params["continue_model"],
+                ]
+                traj_head_modules = (critic, world_model.reward_model, world_model.continue_model)
+                if _heads_fusible(traj_head_trees, traj_head_modules):
+                    v_logits, r_logits, c_logits = fused_mlp_heads(
+                        traj_head_trees, imagined_trajectories,
+                        float(critic.eps), resolve_activation(critic.act), traj_dtype,
+                    )
+                else:
+                    v_logits = critic.apply(params["critic"], imagined_trajectories)
+                    r_logits = world_model.reward_model.apply(
+                        new_wm_params["reward_model"], imagined_trajectories
+                    )
+                    c_logits = world_model.continue_model.apply(
+                        new_wm_params["continue_model"], imagined_trajectories
+                    )
+                predicted_values = TwoHotEncodingDistribution(v_logits, dims=1).mean
+                predicted_rewards = TwoHotEncodingDistribution(r_logits, dims=1).mean
+                continues = Independent(BernoulliSafeMode(logits=c_logits), 1).mode
+                continues = jnp.concatenate([true_continue.squeeze(0)[None], continues[1:]], 0)
 
-            traj_head_trees = [
-                params["critic"],
-                new_wm_params["reward_model"],
-                new_wm_params["continue_model"],
-            ]
-            traj_head_modules = (critic, world_model.reward_model, world_model.continue_model)
-            if _heads_fusible(traj_head_trees, traj_head_modules):
-                v_logits, r_logits, c_logits = fused_mlp_heads(
-                    traj_head_trees, imagined_trajectories,
-                    float(critic.eps), resolve_activation(critic.act), traj_dtype,
+                lambda_vals = compute_lambda_values(
+                    predicted_rewards[1:], predicted_values[1:], continues[1:] * gamma, lmbda
                 )
-            else:
-                v_logits = critic.apply(params["critic"], imagined_trajectories)
-                r_logits = world_model.reward_model.apply(
-                    new_wm_params["reward_model"], imagined_trajectories
+                discount = sg(jnp.cumprod(continues * gamma, 0) / gamma)
+
+                # policies recomputed on the detached trajectories (reference
+                # dreamer_v3.py:272-304)
+                _, policies = actor.apply(actor_params, sg(imagined_trajectories), False, k_actor)
+
+                baseline = predicted_values[:-1]
+                new_moments, offset, invscale = update_moments(
+                    moments_state,
+                    lambda_vals,
+                    float(moments_cfg.decay),
+                    float(moments_cfg.max),
+                    float(moments_cfg.percentile.low),
+                    float(moments_cfg.percentile.high),
                 )
-                c_logits = world_model.continue_model.apply(
-                    new_wm_params["continue_model"], imagined_trajectories
-                )
-            predicted_values = TwoHotEncodingDistribution(v_logits, dims=1).mean
-            predicted_rewards = TwoHotEncodingDistribution(r_logits, dims=1).mean
-            continues = Independent(BernoulliSafeMode(logits=c_logits), 1).mode
-            continues = jnp.concatenate([true_continue.squeeze(0)[None], continues[1:]], 0)
-
-            lambda_vals = compute_lambda_values(
-                predicted_rewards[1:], predicted_values[1:], continues[1:] * gamma, lmbda
-            )
-            discount = sg(jnp.cumprod(continues * gamma, 0) / gamma)
-
-            # policies recomputed on the detached trajectories (reference
-            # dreamer_v3.py:272-304)
-            _, policies = actor.apply(actor_params, sg(imagined_trajectories), False, k_actor)
-
-            baseline = predicted_values[:-1]
-            new_moments, offset, invscale = update_moments(
-                moments_state,
-                lambda_vals,
-                float(moments_cfg.decay),
-                float(moments_cfg.max),
-                float(moments_cfg.percentile.low),
-                float(moments_cfg.percentile.high),
-            )
-            normed_lambda_values = (lambda_vals - offset) / invscale
-            normed_baseline = (baseline - offset) / invscale
-            advantage = normed_lambda_values - normed_baseline
-            if is_continuous:
-                objective = advantage
-            else:
-                splits = np.cumsum(actions_dim)[:-1].tolist()
-                sub_actions = jnp.split(imagined_actions, splits, -1)
-                logps = jnp.stack(
-                    [p.log_prob(sg(a))[:-1][..., None] for p, a in zip(policies, sub_actions)],
-                    -1,
-                ).sum(-1)
-                objective = logps * sg(advantage)
-            try:
-                entropy = ent_coef * jnp.stack([p.entropy() for p in policies], -1).sum(-1)
-            except NotImplementedError:
-                # must span the full trajectory (H+1 rows): the loss slices
-                # [:-1], while `objective` is already one row shorter
-                entropy = jnp.zeros(imagined_trajectories.shape[:2])
-            policy_loss = -jnp.mean(sg(discount[:-1]) * (objective + entropy[..., None][:-1]))
-            aux = {
-                "imagined_trajectories": sg(imagined_trajectories),
-                "lambda_values": sg(lambda_vals),
-                "discount": discount,
-                "moments": new_moments,
-            }
+                normed_lambda_values = (lambda_vals - offset) / invscale
+                normed_baseline = (baseline - offset) / invscale
+                advantage = normed_lambda_values - normed_baseline
+                if is_continuous:
+                    objective = advantage
+                else:
+                    splits = np.cumsum(actions_dim)[:-1].tolist()
+                    sub_actions = jnp.split(imagined_actions, splits, -1)
+                    logps = jnp.stack(
+                        [p.log_prob(sg(a))[:-1][..., None] for p, a in zip(policies, sub_actions)],
+                        -1,
+                    ).sum(-1)
+                    objective = logps * sg(advantage)
+                try:
+                    entropy = ent_coef * jnp.stack([p.entropy() for p in policies], -1).sum(-1)
+                except NotImplementedError:
+                    # must span the full trajectory (H+1 rows): the loss slices
+                    # [:-1], while `objective` is already one row shorter
+                    entropy = jnp.zeros(imagined_trajectories.shape[:2])
+                policy_loss = -jnp.mean(sg(discount[:-1]) * (objective + entropy[..., None][:-1]))
+                aux = {
+                    "imagined_trajectories": sg(imagined_trajectories),
+                    "lambda_values": sg(lambda_vals),
+                    "discount": discount,
+                    "moments": new_moments,
+                }
             return policy_loss, aux
 
         (policy_loss, actor_aux), actor_grads = jax.value_and_grad(actor_loss_fn, has_aux=True)(
             params["actor"]
         )
-        updates, new_actor_opt = actor_tx.update(actor_grads, opt_states["actor"], params["actor"])
-        new_actor_params = optax.apply_updates(params["actor"], updates)
+        with jax.named_scope("actor_optim"):
+            updates, new_actor_opt = actor_tx.update(actor_grads, opt_states["actor"], params["actor"])
+            new_actor_params = optax.apply_updates(params["actor"], updates)
 
         # ---------------------------------------------------- critic
         traj = actor_aux["imagined_trajectories"][:-1]
@@ -571,25 +590,27 @@ def make_train_fn(runtime, world_model, actor, critic, txs, cfg, is_continuous, 
         lambda_vals = actor_aux["lambda_values"]
 
         def critic_loss_fn(critic_params):
-            # _heads_fusible reads only static metadata (tree structure +
-            # leaf shapes), so this is a compile-time specialization
-            if _heads_fusible([critic_params, params["target_critic"]], (critic, critic)):  # jaxlint: disable=retrace-branch
-                q_logits, tgt_logits = fused_mlp_heads(
-                    [critic_params, params["target_critic"]], traj,
-                    float(critic.eps), resolve_activation(critic.act), traj_dtype,
-                )
-            else:
-                q_logits = critic.apply(critic_params, traj)
-                tgt_logits = critic.apply(params["target_critic"], traj)
-            qv = TwoHotEncodingDistribution(q_logits, dims=1)
-            predicted_target_values = TwoHotEncodingDistribution(tgt_logits, dims=1).mean
-            value_loss = -qv.log_prob(lambda_vals)
-            value_loss = value_loss - qv.log_prob(sg(predicted_target_values))
-            return jnp.mean(value_loss * discount[:-1].squeeze(-1))
+            with jax.named_scope("bh_critic"):
+                # _heads_fusible reads only static metadata (tree structure +
+                # leaf shapes), so this is a compile-time specialization
+                if _heads_fusible([critic_params, params["target_critic"]], (critic, critic)):  # jaxlint: disable=retrace-branch
+                    q_logits, tgt_logits = fused_mlp_heads(
+                        [critic_params, params["target_critic"]], traj,
+                        float(critic.eps), resolve_activation(critic.act), traj_dtype,
+                    )
+                else:
+                    q_logits = critic.apply(critic_params, traj)
+                    tgt_logits = critic.apply(params["target_critic"], traj)
+                qv = TwoHotEncodingDistribution(q_logits, dims=1)
+                predicted_target_values = TwoHotEncodingDistribution(tgt_logits, dims=1).mean
+                value_loss = -qv.log_prob(lambda_vals)
+                value_loss = value_loss - qv.log_prob(sg(predicted_target_values))
+                return jnp.mean(value_loss * discount[:-1].squeeze(-1))
 
         value_loss, critic_grads = jax.value_and_grad(critic_loss_fn)(params["critic"])
-        updates, new_critic_opt = critic_tx.update(critic_grads, opt_states["critic"], params["critic"])
-        new_critic_params = optax.apply_updates(params["critic"], updates)
+        with jax.named_scope("critic_optim"):
+            updates, new_critic_opt = critic_tx.update(critic_grads, opt_states["critic"], params["critic"])
+            new_critic_params = optax.apply_updates(params["critic"], updates)
 
         new_params = {
             "world_model": new_wm_params,
@@ -832,21 +853,25 @@ def main(runtime, cfg: Dict[str, Any]):
                         axis=-1,
                     )
             else:
-                prepared = prepare_obs(obs, cnn_keys=cfg.algo.cnn_keys.encoder, num_envs=total_envs)
-                mask = {k: v for k, v in prepared.items() if k.startswith("mask")} or None
-                action_list = player.get_actions(prepared, runtime.next_key(), mask=mask)
-                actions, real_actions = fetch_actions(
-                    action_list, actions_dim, is_continuous, total_envs
-                )
+                # the fetch is where the host waits for the player's program
+                with timer("Time/player_step"):
+                    prepared = prepare_obs(obs, cnn_keys=cfg.algo.cnn_keys.encoder, num_envs=total_envs)
+                    mask = {k: v for k, v in prepared.items() if k.startswith("mask")} or None
+                    action_list = player.get_actions(prepared, runtime.next_key(), mask=mask)
+                    actions, real_actions = fetch_actions(
+                        action_list, actions_dim, is_continuous, total_envs
+                    )
 
             step_data["actions"] = np.asarray(actions).reshape(1, total_envs, -1)
-            rb.add(step_data, validate_args=cfg.buffer.validate_args)
-            if device_cache is not None:
-                device_cache.add(step_data)
+            with timer("Time/replay_add"):
+                rb.add(step_data, validate_args=cfg.buffer.validate_args)
+                if device_cache is not None:
+                    device_cache.add(step_data)
 
-            next_obs, rewards, terminated, truncated, infos = envs.step(
-                np.asarray(real_actions).reshape(envs.action_space.shape)
-            )
+            with timer("Time/env_step"):
+                next_obs, rewards, terminated, truncated, infos = envs.step(
+                    np.asarray(real_actions).reshape(envs.action_space.shape)
+                )
             dones = np.logical_or(terminated, truncated).astype(np.uint8)
 
         step_data["is_first"] = np.zeros_like(step_data["terminated"])
@@ -900,9 +925,10 @@ def main(runtime, cfg: Dict[str, Any]):
             reset_data["actions"] = np.zeros((1, reset_envs, int(np.sum(actions_dim))))
             reset_data["rewards"] = step_data["rewards"][:, dones_idxes]
             reset_data["is_first"] = np.zeros_like(reset_data["terminated"])
-            rb.add(reset_data, dones_idxes, validate_args=cfg.buffer.validate_args)
-            if device_cache is not None:
-                device_cache.add(reset_data, dones_idxes)
+            with timer("Time/replay_add"):
+                rb.add(reset_data, dones_idxes, validate_args=cfg.buffer.validate_args)
+                if device_cache is not None:
+                    device_cache.add(reset_data, dones_idxes)
 
             step_data["rewards"][:, dones_idxes] = np.zeros_like(reset_data["rewards"])
             step_data["terminated"][:, dones_idxes] = np.zeros_like(step_data["terminated"][:, dones_idxes])
@@ -946,13 +972,16 @@ def main(runtime, cfg: Dict[str, Any]):
                     params = restore_like(params, rolled["agent"])
                     opt_states = restore_like(opt_states, rolled["opt_states"])
                     moments_state = restore_like(moments_state, rolled["moments"])
-                player.params = {"world_model": params["world_model"], "actor": params["actor"]}
+                # one device-to-host copy of the player's weights, which also
+                # waits for the update that produced them
+                with timer("Time/params_refresh"):
+                    player.params = {"world_model": params["world_model"], "actor": params["actor"]}
                 # metric.fetch_every amortizes the per-iteration device
                 # sync of the losses dict on high-latency links (1 =
                 # reference cadence; the aggregator still averages over the
                 # log window)
                 if aggregator and not aggregator.disabled and metric_fetch_gate():
-                    with trace_scope("block_until_ready"):
+                    with timer("Time/loss_fetch"), trace_scope("block_until_ready"):
                         fetched_metrics = device_get_metrics(train_metrics)
                     for k, v in fetched_metrics.items():
                         aggregator.update(k, v)
@@ -961,50 +990,54 @@ def main(runtime, cfg: Dict[str, Any]):
         if cfg.metric.log_level > 0 and (
             policy_step - last_log >= cfg.metric.log_every or iter_num == total_iters
         ):
-            observability.on_log(policy_step, train_step)
-            if logger:
-                if aggregator and not aggregator.disabled:
-                    logger.log_metrics(aggregator.compute(), policy_step)
-                    aggregator.reset()
-                logger.log_metrics(
-                    {"Params/replay_ratio": cumulative_per_rank_gradient_steps * world_size / policy_step},
-                    policy_step,
+            # on_log reads the sums before this span closes, and timer.reset()
+            # below drops the registry it was opened under: its time lands in
+            # the next interval's record
+            with timer("Time/log"):
+                observability.on_log(policy_step, train_step)
+                if logger:
+                    if aggregator and not aggregator.disabled:
+                        logger.log_metrics(aggregator.compute(), policy_step)
+                        aggregator.reset()
+                    logger.log_metrics(
+                        {"Params/replay_ratio": cumulative_per_rank_gradient_steps * world_size / policy_step},
+                        policy_step,
+                    )
+                    if not timer.disabled:
+                        timer_metrics = timer.compute()
+                        if timer_metrics.get("Time/train_time", 0) > 0:
+                            logger.log_metrics(
+                                {"Time/sps_train": (train_step - last_train) / timer_metrics["Time/train_time"]},
+                                policy_step,
+                            )
+                        if timer_metrics.get("Time/env_interaction_time", 0) > 0:
+                            logger.log_metrics(
+                                {
+                                    "Time/sps_env_interaction": (
+                                        (policy_step - last_log) / world_size * cfg.env.action_repeat
+                                    )
+                                    / timer_metrics["Time/env_interaction_time"]
+                                },
+                                policy_step,
+                            )
+                        timer.reset()
+                # throughput heartbeat on stdout: long runs are otherwise dark
+                # between episode-end reward lines
+                heartbeat_now = time.perf_counter()
+                split = ""
+                if logger and not timer.disabled:  # timer_metrics exists iff both hold
+                    split = (
+                        f", env_s={timer_metrics.get('Time/env_interaction_time', 0):.1f}"
+                        f", train_s={timer_metrics.get('Time/train_time', 0):.1f}"
+                    )
+                runtime.print(
+                    f"Rank-0: heartbeat policy_step={policy_step}, "
+                    f"sps={(policy_step - last_log) / max(heartbeat_now - heartbeat_t, 1e-9):.2f}, "
+                    f"gradient_steps={cumulative_per_rank_gradient_steps}" + split
                 )
-                if not timer.disabled:
-                    timer_metrics = timer.compute()
-                    if timer_metrics.get("Time/train_time", 0) > 0:
-                        logger.log_metrics(
-                            {"Time/sps_train": (train_step - last_train) / timer_metrics["Time/train_time"]},
-                            policy_step,
-                        )
-                    if timer_metrics.get("Time/env_interaction_time", 0) > 0:
-                        logger.log_metrics(
-                            {
-                                "Time/sps_env_interaction": (
-                                    (policy_step - last_log) / world_size * cfg.env.action_repeat
-                                )
-                                / timer_metrics["Time/env_interaction_time"]
-                            },
-                            policy_step,
-                        )
-                    timer.reset()
-            # throughput heartbeat on stdout: long runs are otherwise dark
-            # between episode-end reward lines
-            heartbeat_now = time.perf_counter()
-            split = ""
-            if logger and not timer.disabled:  # timer_metrics exists iff both hold
-                split = (
-                    f", env_s={timer_metrics.get('Time/env_interaction_time', 0):.1f}"
-                    f", train_s={timer_metrics.get('Time/train_time', 0):.1f}"
-                )
-            runtime.print(
-                f"Rank-0: heartbeat policy_step={policy_step}, "
-                f"sps={(policy_step - last_log) / max(heartbeat_now - heartbeat_t, 1e-9):.2f}, "
-                f"gradient_steps={cumulative_per_rank_gradient_steps}" + split
-            )
-            heartbeat_t = heartbeat_now
-            last_log = policy_step
-            last_train = train_step
+                heartbeat_t = heartbeat_now
+                last_log = policy_step
+                last_train = train_step
 
         # ------------------------------------------------------ checkpoint
         def _ckpt_state():

@@ -47,6 +47,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import shard_map
 
+from sheeprl_tpu.utils.timer import timer
+
 __all__ = [
     "DeviceReplayCache",
     "ShardedDeviceReplayCache",
@@ -314,24 +316,33 @@ def sequence_batches(rb, device_cache, runtime, n_samples, batch_size, seq_len, 
     train timer so host sampling keeps its historical accounting.
     ``sample_kwargs`` (e.g. DV2's prioritize_ends) go to the host sampler;
     the cache path only exists for plain sequential buffers, where they
-    are no-ops."""
+    are no-ops.
+
+    ``Time/feed_dispatch`` times what the host does before the first batch
+    is handed out: the sampler's dispatch on the ring path, ``rb.sample``
+    and the prefetcher's set-up on the host path (its uploads then overlap
+    the train timer)."""
     if device_cache is not None and device_cache.can_sample(seq_len):
-        if getattr(device_cache, "prioritized", False) and device_cache._tree is not None:
-            # prioritized sequence-START sampling (Dreamer family): biased
-            # by design like DV2's prioritize_ends — no IS reweighting of
-            # the world-model losses, so β is irrelevant here
-            yield device_cache.sample_per(n_samples, batch_size, seq_len, key, beta=0.0)
-        else:
-            yield device_cache.sample(n_samples, batch_size, seq_len, key)
+        with timer("Time/feed_dispatch"):
+            if getattr(device_cache, "prioritized", False) and device_cache._tree is not None:
+                # prioritized sequence-START sampling (Dreamer family): biased
+                # by design like DV2's prioritize_ends — no IS reweighting of
+                # the world-model losses, so β is irrelevant here
+                batches = device_cache.sample_per(n_samples, batch_size, seq_len, key, beta=0.0)
+            else:
+                batches = device_cache.sample(n_samples, batch_size, seq_len, key)
+        yield batches
         return
     from sheeprl_tpu.data.feed import batched_feed
 
-    local_data = rb.sample(
-        batch_size, sequence_length=seq_len, n_samples=n_samples, **sample_kwargs
-    )
-    with batched_feed(
-        local_data, n_samples, sharding=runtime.batch_sharding(axis=1)
-    ) as feed:
+    with contextlib.ExitStack() as stack:
+        with timer("Time/feed_dispatch"):
+            local_data = rb.sample(
+                batch_size, sequence_length=seq_len, n_samples=n_samples, **sample_kwargs
+            )
+            feed = stack.enter_context(
+                batched_feed(local_data, n_samples, sharding=runtime.batch_sharding(axis=1))
+            )
         yield feed
 
 

@@ -49,7 +49,15 @@ def configure_compilation_cache() -> str:
     path (the path is part of how a cache is found again, so never ``~``, a
     temp name, a pid or a time).  Every entry point that compiles calls
     this before its first jit: ``MeshRuntime.launch`` (so ``cli.run``),
-    ``serve_policy.build_server``, the bench children, ``chip_smoke.py``."""
+    ``serve_policy.build_server``, the bench children, ``chip_smoke.py``.
+
+    The key of an entry covers the program's metadata (op names with their
+    ``jax.named_scope`` path, source lines).  JAX leaves them out by default,
+    and then serves an executable compiled before a scope was added or
+    renamed: its profile carries the old names, and a reduction that splits
+    device time by scope reads nothing.  The price is a compile whenever a
+    traced line moves."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if from_env:
         return from_env

@@ -15,6 +15,15 @@ Beyond the reference's behavior, every timed region:
 - is wrapped in a ``jax.profiler`` TraceAnnotation, so whenever a
   profiler trace is active (``metric.profile`` / ``profile_every_n``)
   the phases appear as named spans on the host timeline for free.
+
+Regions nest by time on one thread (DreamerV3's loop: ``Time/player_step``,
+``Time/replay_add`` and ``Time/env_step`` inside
+``Time/env_interaction_time``); each name sums its own regions, so a parent's
+self time is its sum minus its children's.  A region that is open across
+``timer.reset()`` — the loop's ``Time/log`` holds the reset of its own
+interval, and closes after ``Observability.on_log`` has read the sums —
+registers again when it closes: its time is counted in the NEXT interval's
+record.  Right for shares over many records, one interval late in any one.
 """
 
 from __future__ import annotations
@@ -39,7 +48,6 @@ class timer(ContextDecorator):
     # raw-duration reservoir per name; at one train + one env region per
     # policy step this covers well past a log interval of history
     max_samples: int = 4096
-    annotate: bool = True
 
     def __init__(self, name: str, metric_cls: Type[Metric] = SumMetric, **metric_kwargs: Any):
         self.name = name
@@ -57,9 +65,7 @@ class timer(ContextDecorator):
             # outlives timer.reset(), which drops the metric registered in
             # __init__ — without this, __exit__ dies with a KeyError
             self._register()
-            self._annotation = (
-                _TraceAnnotation(self.name) if timer.annotate and _TraceAnnotation else None
-            )
+            self._annotation = _TraceAnnotation(self.name) if _TraceAnnotation else None
             if self._annotation is not None:
                 self._annotation.__enter__()
             self._start = time.perf_counter()
@@ -71,6 +77,10 @@ class timer(ContextDecorator):
             if self._annotation is not None:
                 self._annotation.__exit__(*exc)
                 self._annotation = None
+            # a region open across timer.reset() (the loop's Time/log holds
+            # the reset itself) finds its metric dropped: register again, so
+            # its time lands in the next interval's sums
+            self._register()
             timer.timers[self.name].update(elapsed)
             buf = timer.samples.get(self.name)
             if buf is None:

@@ -64,6 +64,17 @@ def _no_env_leaks():
 
 
 @pytest.fixture(autouse=True)
+def _restore_cache_key_setting():
+    """``MeshRuntime.launch`` makes the compile cache key on metadata
+    (``configure_compilation_cache``): a process-wide jax setting, under which
+    one program called from two lines has two keys.  One test's launch must
+    not decide what another test's cache hits."""
+    before = jax.config.jax_compilation_cache_include_metadata_in_key
+    yield
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", before)
+
+
+@pytest.fixture(autouse=True)
 def _reset_metric_globals():
     """timer/MetricAggregator disabled are CLASS-level flags the CLI sets
     per run; reset them so one test's metric.log_level=0 cannot leak into

@@ -214,3 +214,14 @@ def test_cache_dir_defaults_to_the_checkout_and_never_moves(monkeypatch, cache_c
     MeshRuntime(devices=1, accelerator="cpu").launch()
     assert first == jax.config.jax_compilation_cache_dir == expected
     assert mesh.configure_compilation_cache() == expected
+
+
+def test_cache_key_covers_the_metadata(monkeypatch, cache_config):
+    """A profile must show the ``jax.named_scope`` names of the code that
+    ran: the cache may not serve an executable compiled before a rename."""
+    from sheeprl_tpu.parallel import mesh
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/from/outside")
+    mesh.configure_compilation_cache()
+    assert jax.config.jax_compilation_cache_include_metadata_in_key is True

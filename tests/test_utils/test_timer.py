@@ -40,6 +40,20 @@ def test_timer_decorator_survives_reset():
     assert timer.compute()["Time/decorated"] > 0
 
 
+def test_timer_open_across_reset_lands_in_the_next_interval():
+    """A region that holds the reset itself (the loops' Time/log) closes on
+    a registry that no longer knows it: it registers again, and its time is
+    the first entry of the next interval instead of a KeyError."""
+    with timer("Time/outer", SumMetric):
+        with timer("Time/inner", SumMetric):
+            pass
+        assert set(timer.compute()) == {"Time/outer", "Time/inner"}
+        timer.reset()
+        assert timer.compute() == {}
+    assert set(timer.compute()) == {"Time/outer"} and timer.compute()["Time/outer"] > 0
+    assert timer.percentiles()["Time/outer"]["n"] == 1
+
+
 def test_timer_percentiles():
     t = timer("Time/pct", SumMetric)
     for _ in range(32):
