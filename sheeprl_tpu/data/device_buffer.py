@@ -9,7 +9,7 @@ host->HBM copy on the critical path of a ~15 ms train step (the driver's
 last record, BENCH_r05: 535.8 ms per gradient step from the host vs
 6.02 ms from this cache).  So the replay window lives IN HBM: each policy
 step uploads only the new frames (n_envs x ~12 KB), and sampling becomes
-an on-device gather that feeds the jitted train step with zero host
+an on-device read that feeds the jitted train step with zero host
 round-trips.
 
 Semantics mirror ``EnvIndependentReplayBuffer`` over
@@ -20,6 +20,28 @@ crossing the write head), windows contiguous within a single env.  The
 host buffer stays the source of truth for checkpointing — this cache is
 derived state, rebuilt from the host buffer on resume
 (:meth:`load_from`).
+
+How a window is read (PERF.md §6, PR 25).  On a v5e XLA's default layout
+of a ring ``u8[cap, n_envs, 64, 64, 3]`` is ``{0,3,4,2,1:T(8,128)(4,1)}``:
+**capacity is the minor-most (lane) dimension**, then W, C, H, env (the
+vector rings likewise, ``f32[cap, n_envs, 1]{0,2,1}``).  A two-index gather
+``buf[t_idx, e_idx]`` wants the indexed dims major, so XLA first copies the
+WHOLE ring to another layout: 26 ms and 4.18 GB of temporaries a draw for
+a 2.09 GB ring and a 12.6 MB batch.  So each sequence window is read as a
+contiguous ``lax.dynamic_slice`` along the capacity axis at ``(start, env)``,
+in the ring's own layout, unrolled in Python over the batch
+(:func:`_window_slice`; 0.5 ms).  The form matters — ``vmap`` of the slice
+is a gather again, a ``fori_loop`` over the ring relays it again — and needs
+no chip to check: compile for a described v5e and read the HLO, as
+``tests/test_data/test_device_buffer_tpu_layout.py`` does
+(``topologies.get_topology_desc("tpu", "v5e:2x2")``, arguments as
+``ShapeDtypeStruct(..., sharding=SingleDeviceSharding(topo.devices[0]))``,
+then ``_sample.lower(...).compile()``: ``as_text()`` must hold no copy of the
+ring's shape, ``memory_analysis().temp_size_in_bytes`` a few MB).
+A draw is one small index program (:func:`_sample_draw`) and one read
+program per gradient step (:func:`_sample`), so the unrolled program grows
+with ``batch x keys`` and never with ``n_samples``.  The flat-transition
+samplers (SAC family) still use the two-index gather (PERF.md §7).
 
 Gating: ``buffer.device_cache`` (True / False / "auto"; env override
 ``SHEEPRL_DEVICE_CACHE``).  "auto" enables on single-device accelerator
@@ -221,11 +243,15 @@ def _sample_transitions_prioritized(
     return out, leaves.reshape(n_samples, batch_size)
 
 
-def _gather_windows(bufs, key, pos, filled, *, n_samples, batch_size, seq_len, cap, n_envs):
-    """Core window gather shared by the single-device jit and the
-    per-device body of the sharded sampler (shapes are whatever the
-    caller's shard holds)."""
-    flat = n_samples * batch_size
+def _draw_windows(key, pos, filled, *, flat, seq_len, cap, n_envs):
+    """Uniform index draw shared by the single-device jit and the per-device
+    body of the sharded sampler: ``flat`` (start, env) pairs.
+
+    Valid starts per env mirror SequentialReplayBuffer.sample: the stored
+    rows span logical times [pos - filled, pos); any L-window inside that
+    span is valid, i.e. ``filled - L + 1`` starts beginning at the oldest
+    row (ring index ``pos`` when full, 0 otherwise).
+    """
     k_env, k_start = jax.random.split(key)
     envs = jax.random.randint(k_env, (flat,), 0, n_envs)
     counts = filled - seq_len + 1  # (n_envs,) — caller guarantees >= 1
@@ -234,58 +260,79 @@ def _gather_windows(bufs, key, pos, filled, *, n_samples, batch_size, seq_len, c
     u = jax.random.uniform(k_start, (flat,))
     offs = jnp.minimum((u * c_e).astype(jnp.int32), c_e - 1)
     starts = (base[envs] + offs) % cap
-    return _window_gather_out(
-        bufs, starts, envs, n_samples=n_samples, batch_size=batch_size, seq_len=seq_len,
-        cap=cap,
-    )
+    return starts, envs
 
 
-def _window_gather_out(bufs, starts, envs, *, n_samples, batch_size, seq_len, cap):
-    """(flat,) starts/envs -> {k: (n_samples, L, B, *feat)} — the shared
-    tail of the uniform and prioritized sequence samplers."""
-    t_idx = (starts[:, None] + jnp.arange(seq_len)[None, :]) % cap  # (flat, L)
-    e_idx = envs[:, None]
-    out = {}
-    for k, buf in bufs.items():
-        g = buf[t_idx, e_idx]  # (flat, L, *feat)
-        g = g.reshape(n_samples, batch_size, seq_len, *buf.shape[2:])
-        out[k] = jnp.swapaxes(g, 1, 2)  # (n_samples, L, B, *feat)
-    return out
+def _rows(flat_idx, n_samples):
+    """(n_samples * B,) -> n_samples arrays of (B,): one per gradient step,
+    so the window reader never sees ``n_samples``."""
+    return tuple(flat_idx.reshape(n_samples, -1))
+
+
+def _window_slice(buf, start, env, *, seq_len):
+    """One (L, *feat) window of env ``env`` starting at ring row ``start``,
+    read as contiguous slices along the capacity axis (module docstring)."""
+    cap = buf.shape[0]
+
+    def rows_from(row):  # (L, 1, *feat), in the ring's own layout
+        return jax.lax.dynamic_slice(buf, (row, env) + (0,) * (buf.ndim - 2), (seq_len, 1) + buf.shape[2:])
+
+    s0 = jnp.minimum(start, cap - seq_len)  # dynamic_slice would clamp anyway
+    # the ring's head follows: a window that wraps past cap - 1 continues there
+    both = jnp.concatenate([rows_from(s0), rows_from(0)], axis=0)
+    return jax.lax.dynamic_slice_in_dim(both, start - s0, seq_len, axis=0)[:, 0]
+
+
+def _read_windows(bufs, starts, envs, *, seq_len):
+    """(B,) starts/envs -> {k: (L, B, *feat)} — the shared tail of the
+    uniform and prioritized sequence samplers, one gradient step's batch.
+
+    Unrolled in Python on purpose: ``vmap`` of a dynamic slice is a gather
+    again, and a ``fori_loop`` over the ring relays it again.  Small vector
+    rings take the same path: on a v5e their 80 slices cost less than the
+    gather they replace (0.05 against 0.12 ms at 42,500 x 4)."""
+    return {
+        k: jnp.stack(
+            [_window_slice(buf, starts[i], envs[i], seq_len=seq_len) for i in range(starts.shape[0])],
+            axis=1,
+        )
+        for k, buf in bufs.items()
+    }
 
 
 @functools.partial(
     jax.jit, static_argnames=("n_samples", "batch_size", "seq_len", "cap", "n_envs")
 )
-def _sample(bufs, key, pos, filled, *, n_samples, batch_size, seq_len, cap, n_envs):
-    """Gather (n_samples, seq_len, batch, *feat) sequence windows.
-
-    Valid starts per env mirror SequentialReplayBuffer.sample: the stored
-    rows span logical times [pos - filled, pos); any L-window inside that
-    span is valid, i.e. ``filled - L + 1`` starts beginning at the oldest
-    row (ring index ``pos`` when full, 0 otherwise).
-    """
-    return _gather_windows(
-        bufs, key, pos, filled,
-        n_samples=n_samples, batch_size=batch_size, seq_len=seq_len,
-        cap=cap, n_envs=n_envs,
+def _sample_draw(key, pos, filled, *, n_samples, batch_size, seq_len, cap, n_envs):
+    """The whole index draw of one ``sample`` call, cut per gradient step."""
+    starts, envs = _draw_windows(
+        key, pos, filled, flat=n_samples * batch_size, seq_len=seq_len, cap=cap, n_envs=n_envs
     )
+    return _rows(starts, n_samples), _rows(envs, n_samples)
+
+
+@functools.partial(jax.jit, static_argnames=("seq_len",))
+def _sample(bufs, starts, envs, *, seq_len):
+    """Read one gradient step's (seq_len, batch, *feat) sequence windows."""
+    return _read_windows(bufs, starts, envs, seq_len=seq_len)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("n_samples", "batch_size", "seq_len", "cap", "n_envs", "depth"),
 )
-def _sample_prioritized(
-    bufs, tree, key, pos, filled, beta, *, n_samples, batch_size, seq_len, cap, n_envs, depth
+def _sample_draw_prioritized(
+    tree, key, pos, filled, beta, *, n_samples, batch_size, seq_len, cap, n_envs, depth
 ):
     """Prioritized sequence-START sampling (Dreamer family, behind
     ``buffer.prioritized``): window starts drawn proportional to their
     cell's priority instead of uniformly.  Validity matches
-    :func:`_gather_windows` exactly — the L-1 rows immediately preceding
+    :func:`_draw_windows` exactly — the L-1 rows immediately preceding
     each env's write head cannot start a full window (zeroed in a
     functional tree copy).
-    Returns the window batch + the sampled start leaves (the caller may
-    decay them — recency-biased replay without a TD signal)."""
+    Returns the per-step (starts, envs) rows for :func:`_sample` + the
+    sampled start leaves (the caller may decay them — recency-biased
+    replay without a TD signal)."""
     from sheeprl_tpu.replay.priority_tree import _tree_sample, _tree_zeroed
 
     flat = n_samples * batch_size
@@ -299,13 +346,7 @@ def _sample_prioritized(
     if inv_leaves is not None:
         t = _tree_zeroed(t, inv_leaves, jnp.ones(inv_leaves.shape, bool), depth=depth)
     leaves, _w = _tree_sample(t, key, beta, n_live, n=flat, depth=depth)
-    starts = leaves // n_envs
-    envs = leaves % n_envs
-    out = _window_gather_out(
-        bufs, starts, envs, n_samples=n_samples, batch_size=batch_size, seq_len=seq_len,
-        cap=cap,
-    )
-    return out, leaves
+    return _rows(leaves // n_envs, n_samples), _rows(leaves % n_envs, n_samples), leaves
 
 
 @contextlib.contextmanager
@@ -743,8 +784,7 @@ class DeviceReplayCache:
                 f"Cannot sample a sequence of length {seq_len}. "
                 f"Data added so far: {int(self._filled.min())}"
             )
-        out = _sample(
-            self._bufs,
+        starts, envs = _sample_draw(
             jnp.asarray(key),
             jnp.asarray(self._pos),
             jnp.asarray(self._filled),
@@ -754,7 +794,7 @@ class DeviceReplayCache:
             cap=self.capacity,
             n_envs=self.n_envs,
         )
-        return [{k: v[i] for k, v in out.items()} for i in range(n_samples)]
+        return [_sample(self._bufs, s, e, seq_len=int(seq_len)) for s, e in zip(starts, envs)]
 
     def sample_transitions(
         self,
@@ -866,8 +906,7 @@ class DeviceReplayCache:
             )
         if self._tree is None:
             raise RuntimeError("prioritized sampling requested on a cache built without prioritized=True")
-        out, leaves = _sample_prioritized(
-            self._bufs,
+        starts, envs, leaves = _sample_draw_prioritized(
             self._tree.tree,
             jnp.asarray(key),
             jnp.asarray(self._pos),
@@ -882,7 +921,7 @@ class DeviceReplayCache:
         )
         if self.per_decay is not None:
             self._tree.scale(leaves, self.per_decay)
-        return [{k: v[i] for k, v in out.items()} for i in range(n_samples)]
+        return [_sample(self._bufs, s, e, seq_len=int(seq_len)) for s, e in zip(starts, envs)]
 
     def priority_state(self) -> Optional[Dict[str, Any]]:
         """Checkpoint payload for the tree (None when not prioritized) —
@@ -1092,36 +1131,61 @@ class ShardedDeviceReplayCache(DeviceReplayCache):
                 f"Cannot sample a sequence of length {seq_len}. "
                 f"Data added so far: {int(self._filled.min())}"
             )
-        geom = (int(n_samples), int(batch_size), int(seq_len), tuple(sorted(self._bufs)))
+        n_samples, batch_size, seq_len = int(n_samples), int(batch_size), int(seq_len)
+        draw = self._sharded_fn(
+            ("draw", n_samples, batch_size, seq_len), self._build_sharded_draw, n_samples, batch_size, seq_len
+        )
+        read = self._sharded_fn(("read", seq_len, tuple(sorted(self._bufs))), self._build_sharded_read, seq_len)
+        starts, envs = draw(jnp.asarray(key), jnp.asarray(self._pos), jnp.asarray(self._filled))
+        return [read(self._bufs, s, e) for s, e in zip(starts, envs)]
+
+    def _sharded_fn(self, geom, build, *args):
+        """The jitted shard_map program of one geometry, built once."""
         fn = self._sharded_sample_fns.get(geom)
         if fn is None:
-            fn = self._build_sharded_sample(*geom[:3])
-            self._sharded_sample_fns[geom] = fn
-        out = fn(self._bufs, jnp.asarray(key), jnp.asarray(self._pos), jnp.asarray(self._filled))
-        return [{k: v[i] for k, v in out.items()} for i in range(n_samples)]
+            fn = self._sharded_sample_fns[geom] = build(*args)
+        return fn
 
-    def _build_sharded_sample(self, n_samples, batch_size, seq_len):
+    def _build_sharded_draw(self, n_samples, batch_size, seq_len):
+        """Each device's index draw over its own envs, cut per gradient step."""
         from jax.sharding import PartitionSpec as P
 
-        mesh = self._runtime.mesh
         axes = self._axes
-        cap, n_envs, n_dev = self.capacity, self.n_envs, self._n_dev
+        cap, n_dev = self.capacity, self._n_dev
+        n_local = self.n_envs // n_dev
 
-        def body(bufs_l, key, pos_l, filled_l):
+        def body_draw(key, pos_l, filled_l):
             # per-device independent stream; each device samples its own envs
             k = jax.random.fold_in(key, self._flat_rank())
-            return _gather_windows(
-                bufs_l, k, pos_l, filled_l,
-                n_samples=n_samples, batch_size=batch_size // n_dev,
-                seq_len=seq_len, cap=cap, n_envs=n_envs // n_dev,
+            starts, envs = _draw_windows(
+                k, pos_l, filled_l,
+                flat=n_samples * (batch_size // n_dev), seq_len=seq_len, cap=cap, n_envs=n_local,
             )
+            return _rows(starts, n_samples), _rows(envs, n_samples)
 
-        buf_specs = {k: P(None, axes) for k in self._bufs}
-        out_specs = {k: P(None, None, axes) for k in self._bufs}
+        rows = (P(axes),) * n_samples
         sharded = shard_map(
-            body, mesh=mesh,
-            in_specs=(buf_specs, P(), P(axes), P(axes)),
-            out_specs=out_specs,
+            body_draw, mesh=self._runtime.mesh,
+            in_specs=(P(), P(axes), P(axes)),
+            out_specs=(rows, rows),
+            check_vma=False,
+        )
+        return jax.jit(sharded)
+
+    def _build_sharded_read(self, seq_len):
+        """One gradient step's windows, each device reading its own draws
+        out of its own rings: the batch axis comes out sharded."""
+        from jax.sharding import PartitionSpec as P
+
+        axes = self._axes
+
+        def body(bufs_l, starts_l, envs_l):
+            return _read_windows(bufs_l, starts_l, envs_l, seq_len=seq_len)
+
+        sharded = shard_map(
+            body, mesh=self._runtime.mesh,
+            in_specs=({k: P(None, axes) for k in self._bufs}, P(axes), P(axes)),
+            out_specs={k: P(None, axes) for k in self._bufs},
             check_vma=False,
         )
         return jax.jit(sharded)
@@ -1234,12 +1298,15 @@ class ShardedDeviceReplayCache(DeviceReplayCache):
             )
         if self._tree is None:
             raise RuntimeError("prioritized sampling requested on a cache built without prioritized=True")
-        geom = ("per_windows", int(n_samples), int(batch_size), int(seq_len), tuple(sorted(self._bufs)))
-        fn = self._sharded_sample_fns.get(geom)
-        if fn is None:
-            fn = self._build_sharded_per(int(n_samples), int(batch_size), int(seq_len), ())
-            self._sharded_sample_fns[geom] = fn
-        out, leaves = fn(
+        n_samples, batch_size, seq_len = int(n_samples), int(batch_size), int(seq_len)
+        draw = self._sharded_fn(
+            ("per_windows", n_samples, batch_size, seq_len),
+            self._build_sharded_per, n_samples, batch_size, seq_len, (),
+        )
+        read = self._sharded_fn(
+            ("per_read", seq_len, tuple(sorted(self._bufs))), self._build_sharded_per_read, seq_len
+        )
+        (rows, envs, own), leaves = draw(
             self._bufs,
             self._tree.trees,
             jnp.asarray(key),
@@ -1249,7 +1316,30 @@ class ShardedDeviceReplayCache(DeviceReplayCache):
         )
         if self.per_decay is not None:
             self._tree.scale(leaves, self.per_decay)
-        return [{k: v[i] for k, v in out.items()} for i in range(n_samples)]
+        return [read(self._bufs, r, e, o) for r, e, o in zip(rows, envs, own)]
+
+    def _build_sharded_per_read(self, seq_len):
+        """One gradient step's prioritized windows: every shard reads the
+        draws it owns out of its own rings and the masked psum assembles
+        the (replicated) batch."""
+        from jax.sharding import PartitionSpec as P
+
+        axes = self._axes
+
+        def body(bufs_l, rows_l, envs_l, own_l):
+            out = {}
+            for k, g in _read_windows(bufs_l, rows_l, envs_l, seq_len=seq_len).items():
+                m = own_l.reshape((1, -1) + (1,) * (g.ndim - 2))  # g is (L, B, *feat)
+                out[k] = jax.lax.psum(jnp.where(m, g, jnp.zeros((), g.dtype)), axes)
+            return out
+
+        sharded = shard_map(
+            body, mesh=self._runtime.mesh,
+            in_specs=({k: P(None, axes) for k in self._bufs}, P(axes), P(axes), P(axes)),
+            out_specs={k: P() for k in self._bufs},
+            check_vma=False,
+        )
+        return jax.jit(sharded)
 
     def _build_sharded_per(self, n_samples, batch_size, seq_len, next_keys):
         """One builder for both prioritized shapes: ``seq_len=None`` gives
@@ -1261,7 +1351,9 @@ class ShardedDeviceReplayCache(DeviceReplayCache):
         :func:`~sheeprl_tpu.replay.priority_tree.shard_proportional_draw`
         (ONE psum'd total-mass reduction), gather rows for the draws this
         shard owns, and masked-psum the batch together — exact global
-        proportional marginals, replicated output."""
+        proportional marginals, replicated output.  The sequence sampler
+        stops after the draw: :meth:`_build_sharded_per_read` reads and
+        assembles each gradient step's windows."""
         from jax.sharding import PartitionSpec as P
 
         from sheeprl_tpu.replay.priority_tree import (
@@ -1303,14 +1395,9 @@ class ShardedDeviceReplayCache(DeviceReplayCache):
 
             out = {}
             if windows:
-                t_idx = (rows[:, None] + jnp.arange(seq_len)[None, :]) % cap  # (flat, L)
-                e_idx = env_l[:, None]
-                for k, buf in bufs_l.items():
-                    g = buf[t_idx, e_idx]  # (flat, L, *feat)
-                    m = own.reshape((flat,) + (1,) * (g.ndim - 1))
-                    g = jax.lax.psum(jnp.where(m, g, jnp.zeros((), g.dtype)), axes)
-                    g = g.reshape(n_samples, batch_size, seq_len, *buf.shape[2:])
-                    out[k] = jnp.swapaxes(g, 1, 2)  # (n_samples, L, B, *feat)
+                # the draw alone, cut per gradient step: each shard's rows,
+                # envs and ownership go to _build_sharded_per_read
+                out = tuple(_rows(v, n_samples) for v in (rows, env_l, own))
             else:
                 gathered = _gather_transitions(
                     bufs_l, rows, env_l,
@@ -1337,7 +1424,7 @@ class ShardedDeviceReplayCache(DeviceReplayCache):
         out_keys = list(self._bufs) + [f"next_{k}" for k in next_keys]
         if not windows:
             out_keys.append("is_weights")
-        out_specs = ({k: P() for k in out_keys}, P())
+        out_specs = (((P(axes),) * n_samples,) * 3 if windows else {k: P() for k in out_keys}, P())
         sharded = shard_map(
             body, mesh=mesh,
             in_specs=(buf_specs, P(axes, None), P(), P(axes), P(axes), P()),

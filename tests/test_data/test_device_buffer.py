@@ -186,7 +186,7 @@ def test_sharded_cache_multi_device():
     be contiguous/valid per env, the batch axis must come out sharded on
     'data' (matching runtime.batch_sharding(axis=1)), and env choice is
     stratified — each device contributes batch/n rows from its own envs."""
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
     from sheeprl_tpu.data.device_buffer import ShardedDeviceReplayCache
     from sheeprl_tpu.parallel.mesh import MeshRuntime
 
@@ -206,7 +206,9 @@ def test_sharded_cache_multi_device():
     batches = cache.sample(n_samples=2, batch_size=16, seq_len=5, key=jax.random.PRNGKey(0))
     lo, hi = total - CAP, total - 1
     for b in batches:
-        assert b["clock"].sharding.spec == P(None, "data")
+        # the sampler's spec names both batch axes; 'fsdp' has size 1 here
+        assert b["clock"].sharding.is_equivalent_to(rt.batch_sharding(axis=1), 3)
+        assert b["clock"].sharding.is_equivalent_to(NamedSharding(rt.mesh, P(None, "data")), 3)
         clock = np.asarray(b["clock"])  # (L, B, 1)
         env_id = np.asarray(b["env_id"])
         assert clock.shape == (5, 16, 1)
@@ -388,3 +390,118 @@ def test_windowed_add_partial_env_indices():
     assert np.array_equal(np.asarray(a._filled), np.asarray(b._filled))
     for k in a._bufs:
         assert np.array_equal(np.asarray(a._bufs[k]), np.asarray(b._bufs[k])), k
+
+
+# ------------------------------------------- window read vs the plain index form
+# The samplers read each window as contiguous slices along the capacity
+# axis; what they return must be ``ring[(start + arange(L)) % cap, env]``.
+READ_CAP, READ_L = 24, 6
+
+
+def _plain_windows(ring, starts, envs, seq_len):
+    """(cap, n_envs, *feat) numpy ring -> (L, B, *feat)."""
+    t_idx = (np.asarray(starts)[None, :] + np.arange(seq_len)[:, None]) % ring.shape[0]
+    return ring[t_idx, np.asarray(envs)[None, :]]
+
+
+def _random_ring(cap, n_envs, seed=0):
+    rng = np.random.default_rng(seed)
+    return {
+        "rgb": rng.integers(0, 256, (cap, n_envs, 4, 4, 3), dtype=np.uint8),
+        "actions": rng.normal(size=(cap, n_envs, 5)).astype(np.float32),
+        "rewards": rng.normal(size=(cap, n_envs, 1)).astype(np.float32),
+    }
+
+
+@pytest.mark.parametrize("n_envs", [1, 4])
+def test_window_read_equals_plain_index_form_at_every_wrap_split(n_envs):
+    """Starts 0, cap - L (the last that does not wrap) and cap - L + 1 ..
+    cap - 1 (every split of a window between the ring's end and its head)."""
+    from sheeprl_tpu.data.device_buffer import _sample
+
+    ring = _random_ring(READ_CAP, n_envs)
+    starts = np.asarray([0] + list(range(READ_CAP - READ_L, READ_CAP)), np.int32)
+    envs = (np.arange(len(starts)) % n_envs).astype(np.int32)
+    got = _sample({k: jax.numpy.asarray(v) for k, v in ring.items()}, starts, envs, seq_len=READ_L)
+    for k, v in ring.items():
+        assert got[k].dtype == v.dtype and got[k].shape == (READ_L, len(starts)) + v.shape[2:]
+        np.testing.assert_array_equal(np.asarray(got[k]), _plain_windows(v, starts, envs, READ_L), err_msg=k)
+
+
+def _filled_cache(kind, n_envs, total):
+    """A cache of ``kind`` holding ``total`` random rows per env (the ring
+    wraps when total > READ_CAP), and the runtime of a sharded one."""
+    from sheeprl_tpu.data.device_buffer import ShardedDeviceReplayCache
+    from sheeprl_tpu.parallel.mesh import MeshRuntime
+
+    prioritized = kind.endswith("prioritized")
+    if kind.startswith("sharded"):
+        if len(jax.devices()) < 8:
+            pytest.skip("needs the 8-virtual-device mesh")
+        rt = MeshRuntime(devices=8, strategy="dp", accelerator="cpu").launch()
+        cache = ShardedDeviceReplayCache(READ_CAP, n_envs, rt, prioritized=prioritized)
+    else:
+        cache = DeviceReplayCache(READ_CAP, n_envs, prioritized=prioritized)
+    rows = _random_ring(total, n_envs, seed=total)
+    for t in range(total):
+        cache.add({k: v[t : t + 1] for k, v in rows.items()})
+    return cache
+
+
+def _own_draw(cache, kind, n_samples, batch, key):
+    """Per gradient step the (starts, GLOBAL envs) that ``sample`` /
+    ``sample_per`` read for ``key``, from the module's own draw programs."""
+    import jax.numpy as jnp
+
+    from sheeprl_tpu.data import device_buffer as db
+
+    geom = dict(n_samples=n_samples, batch_size=batch, seq_len=READ_L, cap=cache.capacity, n_envs=cache.n_envs)
+    heads = (jnp.asarray(cache._pos), jnp.asarray(cache._filled))
+    if kind == "plain":
+        starts, envs = db._sample_draw(key, *heads, **geom)
+    elif kind == "prioritized":
+        starts, envs, _ = db._sample_draw_prioritized(
+            cache._tree.tree, key, *heads, jnp.float32(0.0), depth=cache._tree.depth, **geom
+        )
+    else:
+        n_dev = cache._n_dev
+        n_local = cache.n_envs // n_dev
+        if kind == "sharded":
+            starts, envs = cache._build_sharded_draw(n_samples, batch, READ_L)(key, *heads)
+            # column c of the global batch was drawn by device c // (batch / n_dev) among its own envs
+            owner = np.arange(batch) // (batch // n_dev)
+            envs = [owner * n_local + np.asarray(e) for e in envs]
+        else:  # every shard proposes all `batch` draws; the owner's is the one read
+            draw = cache._build_sharded_per(n_samples, batch, READ_L, ())
+            (rows, envs_l, own), _ = draw(cache._bufs, cache._tree.trees, key, *heads, jnp.float32(0.0))
+            starts, envs = [], []
+            for r, e, o in zip(rows, envs_l, own):
+                o = np.asarray(o).reshape(n_dev, batch)
+                assert (o.sum(0) == 1).all()  # one owner a draw
+                d = o.argmax(0)
+                col = np.arange(batch)
+                starts.append(np.asarray(r).reshape(n_dev, batch)[d, col])
+                envs.append(d * n_local + np.asarray(e).reshape(n_dev, batch)[d, col])
+    return [np.asarray(s) for s in starts], [np.asarray(e) for e in envs]
+
+
+@pytest.mark.parametrize("n_samples", [1, 3])
+@pytest.mark.parametrize("kind", ["plain", "prioritized", "sharded", "sharded_prioritized"])
+def test_sample_equals_plain_index_form_of_its_own_draw(kind, n_samples):
+    """Same key, same ring: ``sample`` / ``sample_per`` return the windows
+    that the plain index form reads at the module's own (start, env) draw."""
+    n_envs, batch = (8, 16) if kind.startswith("sharded") else (4, 8)
+    cache = _filled_cache(kind, n_envs, total=2 * READ_CAP + 5)  # full ring, write head at 5
+    ring = {k: np.asarray(v) for k, v in cache._bufs.items()}
+    key = jax.random.PRNGKey(7 + n_samples)
+    starts, envs = _own_draw(cache, kind, n_samples, batch, key)
+    if kind.endswith("prioritized"):
+        batches = cache.sample_per(n_samples, batch, READ_L, key, beta=0.0)
+    else:
+        batches = cache.sample(n_samples, batch, READ_L, key)
+    assert len(batches) == n_samples
+    assert any(s.max() > READ_CAP - READ_L for s in starts)  # some window wraps
+    for b, s, e in zip(batches, starts, envs):
+        assert set(b) == set(ring)
+        for k, v in ring.items():
+            np.testing.assert_array_equal(np.asarray(b[k]), _plain_windows(v, s, e, READ_L), err_msg=k)
