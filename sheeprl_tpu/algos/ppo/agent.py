@@ -248,7 +248,12 @@ def build_agent(
     obs_space,
     agent_state: Optional[Any] = None,
 ) -> Tuple[PPOAgentModule, Any]:
-    """Create module + init params (optionally from a checkpoint state)."""
+    """Create module + init params (optionally from a checkpoint state).
+    ``algo.policy=sdar_moe`` builds the language-model policy instead."""
+    from sheeprl_tpu.algos.ppo.sdar_policy import build_sdar_agent, is_language_model_policy
+
+    if is_language_model_policy(cfg):
+        return build_sdar_agent(runtime, cfg, agent_state)
     distribution = cfg.distribution.get("type", "auto").lower()
     if distribution not in ("auto", "normal", "tanh_normal", "discrete"):
         raise ValueError(f"Unknown distribution: {distribution}")

@@ -33,6 +33,7 @@ import optax
 
 from sheeprl_tpu.algos.ppo.agent import build_agent, evaluate_actions, get_values, PPOPlayer, sample_actions
 from sheeprl_tpu.algos.ppo.loss import entropy_loss, policy_loss, value_loss
+from sheeprl_tpu.algos.ppo.sdar_policy import is_language_model_policy
 from sheeprl_tpu.algos.ppo.utils import normalize_obs, prepare_obs, test
 from sheeprl_tpu.algos.ppo.vtrace import vtrace
 from sheeprl_tpu.config import instantiate
@@ -117,7 +118,12 @@ def make_update_fn(
     fabric.all_gather + DistributedSampler. False (the reference default)
     keeps minibatches rank-local: each device shard is permuted within
     itself and minibatches are rank-striped, so no rollout data ever
-    crosses devices — exactly DDP semantics."""
+    crosses devices — exactly DDP semantics.
+
+    ``algo.policy=sdar_moe`` (the language-model policy) takes the episode
+    update instead: ``make_episode_update_fn``."""
+    if is_language_model_policy(cfg):
+        return make_episode_update_fn(runtime, module, tx, cfg)
     cnn_keys = tuple(cfg.algo.cnn_keys.encoder)
     update_epochs = int(cfg.algo.update_epochs)
     share_data = bool(cfg.buffer.get("share_data", False))
@@ -375,6 +381,101 @@ def make_update_fn(
     return guard_update(runtime, update, cfg, n_state=2, donate_argnums=(0, 1))
 
 
+def make_episode_update_fn(runtime, policy, tx: optax.GradientTransformation, cfg: Dict[str, Any]):
+    """The PPO update of the language-model policy (``sdar_policy.SdarPolicy``):
+    the unit of a minibatch is a whole episode, because one forward pass over
+    an episode's packed denoising trajectory yields the log-probabilities and
+    values of all its steps.  GAE, the clipped losses and the optimizer are
+    the ones ``make_update_fn`` uses; the epoch shuffle permutes episodes.
+
+    ``data``: ``prompt`` (1, E, P) and ``actions`` (T, E, 2) integers,
+    ``logprobs`` / ``values`` / ``rewards`` / ``dones`` (T, E, 1), one whole
+    episode per env (``FusedDiffusionCollector``).  Returns ``(params,
+    opt_state, metrics, probe)``: ``metrics`` are scalars (losses, gradient
+    norm, the expert layer's counters); ``probe`` holds what each minibatch
+    step produced (its episodes, log-probabilities, values, losses, the
+    gradient's norm whole and leaf by leaf, the norm of every leaf's change
+    ``new - old`` in float32, routing choice and load per expert), stacked over
+    the call's steps, for whoever compares the update with the plain reference."""
+    if runtime.world_size > 1:
+        raise ValueError("the language-model policy updates on one device; set fabric.devices=1")
+    update_epochs = int(cfg.algo.update_epochs)
+    mb_eps = int(cfg.algo.per_rank_batch_size)
+    gamma, gae_lambda = float(cfg.algo.gamma), float(cfg.algo.gae_lambda)
+    vf_coef, clip_vloss = float(cfg.algo.vf_coef), bool(cfg.algo.clip_vloss)
+    reduction, normalize_adv = str(cfg.algo.loss_reduction), bool(cfg.algo.normalize_advantages)
+
+    def loss_fn(p, mb, clip_coef, ent_coef):
+        logp, entropy, values, aux = policy.evaluate_episodes(p, mb["prompt"], mb["actions"])
+        with jax.named_scope("ppo_loss"):
+            adv = normalize_tensor(mb["advantages"]) if normalize_adv else mb["advantages"]
+            pg = policy_loss(logp, mb["logprobs"], adv, clip_coef, reduction)
+            vl = value_loss(values, mb["values"], mb["returns"], clip_coef, clip_vloss, reduction)
+            ent = entropy_loss(entropy, reduction)
+            total = pg + vf_coef * vl + ent_coef * ent
+        return total, (jnp.stack([pg, vl, ent]), logp, values, aux)
+
+    grad_fn = jax.grad(loss_fn, has_aux=True)
+
+    def update(params, opt_state, data, next_obs, key, clip_coef, ent_coef, lr):
+        del next_obs  # every rollout ends with its episodes: nothing to bootstrap from
+        opt_state = _set_lr(opt_state, lr)
+        n_eps = data["rewards"].shape[1]
+        if n_eps % mb_eps:
+            raise ValueError(f"{n_eps} episodes a rollout do not divide into minibatches of {mb_eps}")
+        with jax.named_scope("ppo_loss"):
+            returns, advantages = gae(
+                data["rewards"], data["values"], data["dones"], jnp.zeros_like(data["values"][0]), gamma, gae_lambda
+            )
+        per_step = {"logprobs": data["logprobs"], "values": data["values"], "returns": returns, "advantages": advantages}
+        # episode-major: (E, T) per-step scalars, (E, T, 2) actions, (E, P) prompts
+        episodes = {k: jnp.swapaxes(v[..., 0], 0, 1) for k, v in per_step.items()}
+        episodes.update(actions=jnp.swapaxes(data["actions"], 0, 1), prompt=data["prompt"][0])
+
+        def mb_step(carry, ids):
+            params, opt_state = carry
+            mb = {k: v[ids] for k, v in episodes.items()}
+            grads, (losses, logp, values, aux) = grad_fn(params, mb, clip_coef, ent_coef)
+            with jax.named_scope("ppo_optim"):
+                updates, opt_state = tx.update(grads, opt_state, params)
+                new_params = optax.apply_updates(params, updates)
+                leaf_norm = lambda x: jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))  # noqa: E731
+                # what the step did to the state, read off the parameters the next step starts from
+                moved = jax.tree_util.tree_map(
+                    lambda new, old: leaf_norm(new.astype(jnp.float32) - old.astype(jnp.float32)), new_params, params
+                )
+            probe = {"episodes": ids, "logprobs": logp, "values": values, "losses": losses,
+                     "grad_norm": optax.global_norm(grads), "grad_leaf_norms": jax.tree_util.tree_map(leaf_norm, grads),
+                     "moved_leaf_norms": moved, "load": aux["load"], "dropped": aux["dropped"].sum(),
+                     "top_i": aux["top_i"], "entropy": aux["entropy"].mean()}
+            return (new_params, opt_state), probe
+
+        def epoch_step(carry, ekey):
+            ids = jax.random.permutation(ekey, n_eps).reshape(n_eps // mb_eps, mb_eps)
+            return jax.lax.scan(mb_step, carry, ids)
+
+        (params, opt_state), probe = jax.lax.scan(epoch_step, (params, opt_state), jax.random.split(key, update_epochs))
+        probe = jax.tree_util.tree_map(lambda x: x.reshape(-1, *x.shape[2:]), probe)  # (epochs * minibatches, ...)
+        losses = probe["losses"].mean(0)
+        load = probe["load"].sum(0).astype(jnp.float32)  # (layers, experts held) over the call
+        steps, _, positions, top_k = probe["top_i"].shape
+        assignments = steps * positions * top_k  # a layer's over the call, to held and absent experts alike
+        metrics = {
+            "Loss/policy_loss": losses[0],
+            "Loss/value_loss": losses[1],
+            "Loss/entropy_loss": losses[2],
+            "Grads/agent": probe["grad_norm"].mean(),
+            "MoE/load_max_over_mean": (load.max(-1) / jnp.maximum(load.mean(-1), 1.0)).max(),
+            "MoE/held_share": (load.sum(-1) / assignments).mean(),
+            "MoE/dropped": probe["dropped"].sum().astype(jnp.float32),
+            "MoE/router_entropy": probe["entropy"].mean(),
+            **{f"MoE/load_l{i}_e{e}": load[i, e] for i in range(load.shape[0]) for e in range(load.shape[1])},
+        }
+        return params, opt_state, metrics, probe
+
+    return guard_update(runtime, update, cfg, n_state=2, donate_argnums=(0, 1))
+
+
 def _set_lr(opt_state, lr):
     """Override learning_rate inside an InjectHyperparamsState (possibly
     nested in an optax.chain tuple or a bf16-true MasterWeightsState)."""
@@ -448,6 +549,12 @@ def main(runtime, cfg: Dict[str, Any]):
     clip_rewards_fn = (lambda r: np.tanh(r)) if cfg.env.clip_rewards else (lambda r: r)
 
     # ------------------------------------------------------------- agent
+    lm_policy = is_language_model_policy(cfg)
+    if lm_policy and (env_backend != "jax" or cfg.algo.run_test):
+        raise ValueError(
+            "algo.policy=sdar_moe collects through the fused device collector only and has no test "
+            "episode: set algo.env_backend=jax, env=jax_tokens and algo.run_test=False"
+        )
     module, params = build_agent(
         runtime,
         actions_dim,
@@ -467,7 +574,8 @@ def main(runtime, cfg: Dict[str, Any]):
     def _prep(obs):
         return prepare_obs(obs, cnn_keys=cnn_keys, num_envs=total_envs)
 
-    player = PPOPlayer(module, params, _prep, device=runtime.player_device())
+    # the language-model policy acts inside the fused collector only: no host-side player
+    player = None if lm_policy else PPOPlayer(module, params, _prep, device=runtime.player_device())
 
     if runtime.is_global_zero:
         save_configs(cfg, log_dir)
@@ -539,9 +647,9 @@ def main(runtime, cfg: Dict[str, Any]):
     if env_backend == "jax":
         # fused collect (envs/jax/collect.py): policy + env + append as
         # one lax.scan per rollout; the payload is born on device
-        from sheeprl_tpu.envs.jax.collect import FusedOnPolicyCollector
+        from sheeprl_tpu.envs.jax.collect import FusedDiffusionCollector, FusedOnPolicyCollector
 
-        collector = FusedOnPolicyCollector(
+        collector = (FusedDiffusionCollector if lm_policy else FusedOnPolicyCollector)(
             envs=envs,
             module=module,
             params=params,
@@ -609,6 +717,7 @@ def main(runtime, cfg: Dict[str, Any]):
         adopt_params_fn=adopt_params_fn,
     )
     metric_fetch_gate = MetricFetchGate(cfg.metric.get("fetch_every", 1))
+    moe_counters: Dict[str, float] = {}
 
     for iter_num, payload in pipeline:
         observability.on_iteration(policy_step)
@@ -617,7 +726,8 @@ def main(runtime, cfg: Dict[str, Any]):
 
         # ------------------------------------------------- device update
         with timer("Time/train_time", SumMetric, sync_on_compute=cfg.metric.sync_on_compute):
-            params, opt_state, train_metrics = update_fn(
+            # (the episode update also returns what each minibatch step produced: unused here)
+            params, opt_state, train_metrics, *_ = update_fn(
                 params,
                 opt_state,
                 payload.data,
@@ -638,17 +748,19 @@ def main(runtime, cfg: Dict[str, Any]):
         if aggregator and not aggregator.disabled and metric_fetch_gate():
             # materializing metrics blocks on the update; only pay that
             # sync when metrics are on, at the metric.fetch_every cadence
-            with trace_scope("block_until_ready"):
+            with timer("Time/loss_fetch"), trace_scope("block_until_ready"):
                 fetched_metrics = device_get_metrics(train_metrics)
             for k, v in fetched_metrics.items():
                 aggregator.update(k, v)
+            # the expert layer's counters ride the telemetry record ("moe" section)
+            moe_counters = {k[len("MoE/"):]: float(v) for k, v in fetched_metrics.items() if k.startswith("MoE/")}
 
         # ------------------------------------------------- logging
         if cfg.metric.log_level > 0 and logger:
             logger.log_metrics({"Info/learning_rate": current_lr}, policy_step)
             logger.log_metrics({"Info/clip_coef": current_clip, "Info/ent_coef": current_ent}, policy_step)
             if policy_step - last_log >= cfg.metric.log_every or iter_num == total_iters:
-                observability.on_log(policy_step, train_step)
+                observability.on_log(policy_step, train_step, extra={"moe": moe_counters} if moe_counters else None)
                 if aggregator and not aggregator.disabled:
                     logger.log_metrics(aggregator.compute(), policy_step)
                     aggregator.reset()
@@ -705,7 +817,8 @@ def main(runtime, cfg: Dict[str, Any]):
             break
 
     pipeline.close()  # before envs.close(): the collector may be mid-step
-    player.params = params  # the test episode runs on the final weights
+    if player is not None:
+        player.params = params  # the test episode runs on the final weights
     ckpt_mgr.close()
     envs.close()
     observability.close()

@@ -31,6 +31,7 @@ from sheeprl_tpu.envs.jax.core import (
 )
 from sheeprl_tpu.envs.jax.gridworld import GridWorldJax
 from sheeprl_tpu.envs.jax.gym_adapter import JaxToGymEnv, make_gym_env
+from sheeprl_tpu.envs.jax.tokens import TokenEnvJax
 from sheeprl_tpu.envs.jax.vector import JaxVectorEnv
 
 __all__ = [
@@ -41,6 +42,7 @@ __all__ = [
     "JaxToGymEnv",
     "JaxVectorEnv",
     "PendulumJax",
+    "TokenEnvJax",
     "initial_reset_key",
     "is_jax_env_id",
     "make_gym_env",
@@ -57,6 +59,7 @@ JAX_ENV_REGISTRY: Dict[str, Callable[..., JaxEnv]] = {
     "jax_cartpole": CartPoleJax,
     "jax_pendulum": PendulumJax,
     "jax_gridworld": GridWorldJax,
+    "jax_tokens": TokenEnvJax,
 }
 
 

@@ -44,7 +44,7 @@ from sheeprl_tpu.envs.jax.vector import JaxVectorEnv
 from sheeprl_tpu.parallel.pipeline import RolloutPayload
 from sheeprl_tpu.utils.utils import MetricFetchGate
 
-__all__ = ["FusedOnPolicyCollector", "FusedRecurrentCollector"]
+__all__ = ["FusedDiffusionCollector", "FusedOnPolicyCollector", "FusedRecurrentCollector"]
 
 
 class _FusedCollectorBase:
@@ -344,5 +344,101 @@ class FusedRecurrentCollector(_FusedCollectorBase):
         payload.data = data
         payload.next_obs = {k: self._carry["vstate"]["obs"][k] for k in self.obs_keys}
         payload.extras["next_values"] = next_values
+        payload.policy_step_end = self.policy_step
+        return payload
+
+
+class FusedDiffusionCollector(_FusedCollectorBase):
+    """Fused collection for the language-model policy (``algos/ppo/sdar_policy.py``
+    over ``envs/jax/tokens.py``): one rollout is one whole episode per env, one
+    env step per denoising step.
+
+    The scan runs over response blocks, and its carry holds the env state (the
+    block in progress lives in its tokens) and, per layer, the keys and values
+    of the clean positions so far.  A block costs five forward passes for its
+    four tokens: four denoising passes over the block as it stands, each
+    followed by an env step that writes the revealed token, then one pass over
+    the finished block that appends its keys and values to the cache.
+
+    Sampler: reveal the masked position whose top-1 probability is highest (a
+    function of the state alone), and draw its token from the categorical at
+    temperature 1, so that a step's recorded log-probability is exactly the
+    model's (SDAR's own low-confidence sampler ranks positions by the *sampled*
+    token's probability, which a policy-gradient ratio cannot reproduce)."""
+
+    def _initial_carry(self, base):
+        return vector_reset(self.jax_env, base, self.total_envs)
+
+    def _rollout_fn(self, params, vstate, key, env_base):
+        from sheeprl_tpu.models.sdar_moe import SdarMoE
+
+        policy, env = self.module, self.jax_env
+        model, mcfg = policy.model, policy.cfg
+        n_env, p_len = self.total_envs, env.prompt_len
+        block, steps, n_blocks = mcfg.block_length, mcfg.denoise_steps, policy.layout.n_blocks
+        s_max = policy.layout.n_clean
+
+        # the prompt's keys and values: one clean pass under the block-causal mask
+        prompt = vstate["obs"]["tokens"][:, :p_len]
+        _, _, kvs = model.apply(params, prompt, policy.prefill_layout, True, method=SdarMoE.hidden)
+        cache = [
+            tuple(jnp.zeros((n_env, s_max) + x.shape[2:], x.dtype).at[:, :p_len].set(x) for x in kv) for kv in kvs
+        ]
+
+        def current(vstate, length):
+            return jax.lax.dynamic_slice_in_dim(vstate["obs"]["tokens"], length, block, axis=1)
+
+        def block_fn(carry, xs):
+            vstate, cache = carry
+            b, keys = xs
+            length = p_len + b * block
+            pos = length + jnp.arange(block)
+            recs = []
+            for j in range(steps):
+                tokens = current(vstate, length)
+                hidden, _, _ = model.apply(params, tokens, pos, cache, length, method=SdarMoE.block)
+                logp_all, values = model.apply(params, hidden, method=SdarMoE.score)
+                masked = tokens == mcfg.mask_id
+                u = jnp.argmax(jnp.where(masked, logp_all.max(-1), -jnp.inf), axis=-1)
+                logp_u = jnp.take_along_axis(logp_all, u[:, None, None], axis=1)[:, 0]
+                x = jax.random.categorical(keys[j], logp_u, axis=-1)
+                vstate, out = vector_step(env, vstate, jnp.stack([u, x], -1).astype(jnp.int32), env_base, None)
+                recs.append({
+                    "actions": jnp.stack([u, x], -1).astype(jnp.int32),
+                    "logprobs": jnp.take_along_axis(logp_u, x[:, None], axis=-1),
+                    "values": jnp.take_along_axis(values, u[:, None], axis=1),
+                    "rewards": out["reward"][:, None],
+                    "dones": out["done"][:, None].astype(jnp.float32),
+                    "ev": {"done": out["done"], "ep_return": out["ep_return"], "ep_length": out["ep_length"]},
+                })
+            # the finished block's keys and values join the cache (after the last
+            # block the env has reset, and what is written is never read)
+            _, _, kvs = model.apply(params, current(vstate, length), pos, cache, length, method=SdarMoE.block)
+            cache = [
+                tuple(jax.lax.dynamic_update_slice_in_dim(c, x, length, axis=1) for c, x in zip(kv_cache, kv))
+                for kv_cache, kv in zip(cache, kvs)
+            ]
+            return (vstate, cache), jax.tree_util.tree_map(lambda *x: jnp.stack(x), *recs)
+
+        keys = jax.random.split(jnp.asarray(key), n_blocks * steps).reshape(n_blocks, steps, -1)
+        (vstate, _), recs = jax.lax.scan(block_fn, (vstate, cache), (jnp.arange(n_blocks), keys))
+        recs = jax.tree_util.tree_map(lambda x: x.reshape(n_blocks * steps, *x.shape[2:]), recs)
+        events = recs.pop("ev")
+        recs["prompt"] = prompt[None]
+        return vstate, recs, events
+
+    def collect(self, iter_num: int, inline: bool, key_fn) -> RolloutPayload:
+        from sheeprl_tpu.utils.metric import SumMetric
+        from sheeprl_tpu.utils.timer import timer
+
+        payload = RolloutPayload(iter_num)
+        step_start = self.policy_step
+        with timer("Time/env_interaction_time", SumMetric, sync_on_compute=False):
+            self._carry, data, events = self._rollout(self.params, self._carry, key_fn(), self._env_base)
+        self._n_rollouts += 1
+        self.policy_step += self.rollout_steps * self.total_envs
+        self._apply_events(events, step_start)
+        payload.data = data
+        payload.next_obs = {k: self._carry["obs"][k] for k in self.obs_keys}
         payload.policy_step_end = self.policy_step
         return payload

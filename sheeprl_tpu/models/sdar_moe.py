@@ -1,0 +1,428 @@
+"""SDAR-MoE as flax modules: a sparse-expert transformer that generates by
+diffusion over blocks (``model_type: sdar_moe``; layer equations in
+``sdar_moe_reference.py``, the plain reference every test compares this with).
+
+What is particular here:
+
+- ``RoutedExperts`` is told which experts it holds (``experts_held`` from
+  ``expert_offset``): it routes over all ``num_experts``, keeps the published
+  top-k, and computes its own experts' part of the result.  That is the layer
+  expert parallelism needs; on one chip it runs without its exchange, and what
+  absent experts would add is left out (``howto/language_model_policy.md``).
+  It is dropless: the sorted buffer has a row for every assignment a token
+  could make to a held expert (``tokens x min(top_k, experts_held)``), so
+  nothing is ever cut, whatever the imbalance.  The held experts run as one
+  grouped product (``jax.lax.ragged_dot``, tokens sorted by expert).
+- attention takes its mask as data (``ops.block_sparse_attention.SegmentMask``): one
+  packed episode holds the clean sequence and every denoising step's noised
+  copy of its block (``EpisodeLayout``), so that one forward pass scores a whole
+  denoising trajectory.
+- ``SdarMoE.block`` is the cached pass collection makes: a block of
+  ``block_length`` tokens against the clean keys and values of the finished
+  blocks (a denoising pass, or the pass that writes a finished block).
+
+Router logits, softmax, top-k and every norm run in float32 (the router's
+product at ``highest`` precision); products elsewhere in ``dtype``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Any, Dict, Mapping, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from sheeprl_tpu.ops.block_sparse_attention import SegmentMask, block_sparse_flash_attention
+
+Dtype = Any
+_INIT = nn.initializers.normal(0.02)
+_NEG = -1e30  # the logit of an id the policy may never draw ([MASK])
+
+
+@dataclasses.dataclass(frozen=True)
+class SdarConfig:
+    """The published ``config.json`` keys this model reads, and the cut."""
+
+    hidden_size: int = 2048
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    rope_theta: float = 1e6
+    rms_norm_eps: float = 1e-6
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    norm_topk_prob: bool = True
+    moe_intermediate_size: int = 768
+    num_hidden_layers: int = 48
+    vocab_size: int = 151936
+    experts_held: int = 128
+    expert_offset: int = 0
+    block_length: int = 4
+    denoise_steps: int = 4
+    mask_id: int = 151935
+    attention_block: int = 512  # tile of the masked attention (the TPU's block-sparse flash kernel)
+    attention_interpret: bool = False  # run that kernel through Pallas' interpreter: off a TPU, for the tests
+
+    @classmethod
+    def from_mapping(cls, cfg: Mapping[str, Any]) -> "SdarConfig":
+        names = {f.name for f in dataclasses.fields(cls)}
+        self = cls(**{k: v for k, v in dict(cfg).items() if k in names})
+        if self.denoise_steps != self.block_length:
+            raise ValueError("one token a step: denoise_steps must equal block_length")
+        if not 0 <= self.expert_offset <= self.num_experts - self.experts_held:
+            raise ValueError(f"experts {self.expert_offset}..+{self.experts_held} are not among {self.num_experts}")
+        if not 0 <= self.mask_id < self.vocab_size:
+            raise ValueError(f"mask_id {self.mask_id} lies outside the vocabulary slice of {self.vocab_size}")
+        return self
+
+
+@dataclasses.dataclass(frozen=True)
+class EpisodeLayout:
+    """One packed episode: ``prompt_len + response_len`` clean positions, then
+    for every response block its ``steps`` noised copies (copy ``j`` is the
+    block as it stood before denoising step ``j``), at the clean block's rotary
+    positions.  ``response_len = 0`` is a clean-only sequence (a prefill)."""
+
+    prompt_len: int
+    response_len: int
+    block: int
+    steps: int
+
+    @property
+    def n_clean(self) -> int:
+        return self.prompt_len + self.response_len
+
+    @property
+    def n_blocks(self) -> int:
+        return self.response_len // self.block
+
+    @property
+    def length(self) -> int:
+        return self.n_clean + self.steps * self.response_len
+
+    @functools.cached_property
+    def _arrays(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        if self.prompt_len % self.block or self.response_len % self.block:
+            raise ValueError(f"prompt and response must be multiples of the block ({self.block})")
+        clean = np.arange(self.n_clean)
+        b = np.repeat(np.arange(self.n_blocks), self.steps * self.block)
+        j = np.tile(np.repeat(np.arange(self.steps), self.block), self.n_blocks)
+        u = np.tile(np.arange(self.block), self.n_blocks * self.steps)
+        noised = self.prompt_len + b * self.block + u
+        pos = np.concatenate([clean, noised]).astype(np.int32)
+        copy = np.concatenate([np.zeros(self.n_clean, np.int64), 1 + b * self.steps + j]).astype(np.int32)
+        return pos, pos // self.block, copy
+
+    @property
+    def positions(self) -> np.ndarray:
+        return self._arrays[0]
+
+    @functools.cached_property
+    def mask(self) -> SegmentMask:
+        """A clean position of block ``b`` sees clean positions of blocks
+        ``<= b``; a noised one sees clean positions of blocks ``< b`` and its
+        own copy; nothing else sees a noised position."""
+        _, blk, copy = self._arrays
+        return SegmentMask(q_limit=blk - (copy > 0), q_segment=copy, k_index=blk, k_segment=copy)
+
+    def pack(self, prompt: jax.Array, actions: jax.Array, mask_id: int) -> Tuple[jax.Array, jax.Array]:
+        """``prompt`` (B, P) and the episode's actions (B, T, 2) = (position in
+        the block, token), one a denoising step -> the packed token ids (B, N)
+        and the packed index of every step's action position (B, T)."""
+        bsz = prompt.shape[0]
+        order = actions[..., 0].reshape(bsz, self.n_blocks, self.steps)
+        token = actions[..., 1].reshape(bsz, self.n_blocks, self.steps)
+        hit = order[..., None] == jnp.arange(self.block)  # (B, nb, step, u): step j reveals position u
+        response = (hit * token[..., None]).sum(-2)
+        step_of = (hit * jnp.arange(self.steps)[:, None]).sum(-2)
+        copies = jnp.where(step_of[:, :, None, :] < jnp.arange(self.steps)[:, None], response[:, :, None, :], mask_id)
+        tokens = jnp.concatenate([prompt, response.reshape(bsz, -1), copies.reshape(bsz, -1)], axis=1)
+        base = self.n_clean + (jnp.arange(self.n_blocks)[:, None] * self.steps + jnp.arange(self.steps)) * self.block
+        return tokens.astype(jnp.int32), (base + order).reshape(bsz, -1).astype(jnp.int32)
+
+
+def rms_norm(x: jax.Array, g: jax.Array, eps: float) -> jax.Array:
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * g
+
+
+def rope(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
+    """Rotate-half over the whole head, positions given per token.
+    ``x``: (..., N, H, D) float32; ``pos``: (..., N) or (N,)."""
+    d = x.shape[-1]
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = pos.astype(jnp.float32)[..., None] * inv_freq
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[..., None, :]
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[..., None, :]
+    x1, x2 = x[..., : d // 2], x[..., d // 2:]
+    return x * cos + jnp.concatenate([-x2, x1], -1) * sin
+
+
+# ------------------------------------------------------- dropless dispatch
+# Assignments are (token, choice) pairs, N x k of them.  ``perm`` (rows,) lists the assignments in
+# sorted order (by held expert; the head of a permutation, long enough for every assignment a held
+# expert could get) and ``inv`` (N, k) is each assignment's row.  Both directions of both moves are
+# gathers: XLA's own transpose of a gather is a scatter-add, which a TPU serialises.
+def _rows_of(sorted_rows, inv, keep):
+    """Every assignment's row (N, k, d); zeros where ``keep`` (N, k) is unset."""
+    rows = sorted_rows[jnp.minimum(inv, sorted_rows.shape[0] - 1)]
+    return jnp.where(keep[..., None], rows, 0)
+
+
+@jax.custom_vjp
+def _dispatch(m, perm, inv, held):
+    """Rows of ``m`` (N, d) in sorted order: row ``r`` is the token of assignment ``perm[r]``."""
+    return m[perm // inv.shape[1]]
+
+
+def _dispatch_fwd(m, perm, inv, held):
+    return _dispatch(m, perm, inv, held), (inv, held)
+
+
+def _dispatch_bwd(res, g):
+    inv, held = res
+    return _rows_of(g, inv, held).sum(1).astype(g.dtype), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(y_sorted, w, perm, inv):
+    """``out[t] = sum_j w[t, j] * y_sorted[inv[t, j]]``, float32; ``w`` (N, k) is 0 where the
+    assignment is not held."""
+    return jnp.einsum("nkd,nk->nd", _rows_of(y_sorted, inv, w != 0), w, preferred_element_type=jnp.float32)
+
+
+def _combine_fwd(y_sorted, w, perm, inv):
+    return _combine(y_sorted, w, perm, inv), (y_sorted, w, perm, inv)
+
+
+def _combine_bwd(res, g):
+    y_sorted, w, perm, inv = res
+    gw = jnp.einsum("nkd,nd->nk", _rows_of(y_sorted, inv, w != 0), g, preferred_element_type=jnp.float32)
+    # gathered in the rows' dtype: in f32 this gather alone wrote 1.1 GB a layer at the published sizes
+    gy = g.astype(y_sorted.dtype)[perm // w.shape[1]] * w.reshape(-1)[perm][:, None].astype(y_sorted.dtype)
+    return gy, gw, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+class RoutedExperts(nn.Module):
+    """Router over all experts, SwiGLU experts held here, dropless."""
+
+    cfg: SdarConfig
+    dtype: Dtype = jnp.float32
+
+    def setup(self) -> None:
+        c = self.cfg
+        d, f, held = c.hidden_size, c.moe_intermediate_size, c.experts_held
+        self.router = self.param("router", _INIT, (d, c.num_experts), jnp.float32)
+        self.w_gate = self.param("w_gate", _INIT, (held, d, f), jnp.float32)
+        self.w_up = self.param("w_up", _INIT, (held, d, f), jnp.float32)
+        self.w_down = self.param("w_down", _INIT, (held, f, d), jnp.float32)
+
+    def __call__(self, m: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        """``m``: (N, hidden) float32, already normed.  Returns the held
+        experts' part of the layer's output (N, hidden) float32 and the
+        counters."""
+        c = self.cfg
+        n, k, held_n = m.shape[0], c.num_experts_per_tok, c.experts_held
+        with jax.named_scope("moe_router"):
+            logits = jnp.dot(m, self.router, precision=jax.lax.Precision.HIGHEST)
+            probs = jax.nn.softmax(logits, axis=-1)
+            top_p, top_i = jax.lax.top_k(probs, k)
+            weights = top_p / top_p.sum(-1, keepdims=True) if c.norm_topk_prob else top_p
+            entropy = -(probs * jnp.log(jnp.maximum(probs, 1e-30))).sum(-1).mean()
+        with jax.named_scope("moe_dispatch"):
+            local = top_i - c.expert_offset
+            held = (local >= 0) & (local < held_n)
+            key = jnp.where(held, local, held_n).reshape(-1)
+            # every assignment a token could make to a held expert has a row: nothing is ever cut
+            rows = n * min(k, held_n)
+            order = jnp.argsort(key, stable=True)
+            inv = jnp.argsort(order).reshape(n, k)
+            perm = order[:rows]
+            group_sizes = (key[:, None] == jnp.arange(held_n)).sum(0).astype(jnp.int32)
+            dropped = jnp.maximum(held.sum() - rows, 0)
+            x = _dispatch(m.astype(self.dtype), perm, inv, held)
+        with jax.named_scope("moe_experts"):
+            # gate and up as one grouped product: the sorted rows are read once, their gradient summed once
+            w_in = jnp.concatenate([self.w_gate, self.w_up], axis=-1).astype(self.dtype)
+            gate, up = jnp.split(jax.lax.ragged_dot(x, w_in, group_sizes), 2, axis=-1)
+            y_sorted = jax.lax.ragged_dot(jax.nn.silu(gate) * up, self.w_down.astype(self.dtype), group_sizes)
+        with jax.named_scope("moe_dispatch"):
+            y = _combine(y_sorted, jnp.where(held, weights, 0.0), perm, inv)
+        aux = {"load": group_sizes, "dropped": dropped, "entropy": entropy, "top_i": top_i}
+        return y, aux
+
+
+class GroupedQueryAttention(nn.Module):
+    """Grouped-query attention with per-head RMSNorm on q and k and rotary
+    positions given per token; the mask is the caller's."""
+
+    cfg: SdarConfig
+    dtype: Dtype = jnp.float32
+
+    def setup(self) -> None:
+        c = self.cfg
+        d, hd = c.hidden_size, c.head_dim
+        self.wq = self.param("wq", _INIT, (d, c.num_attention_heads * hd), jnp.float32)
+        self.wk = self.param("wk", _INIT, (d, c.num_key_value_heads * hd), jnp.float32)
+        self.wv = self.param("wv", _INIT, (d, c.num_key_value_heads * hd), jnp.float32)
+        self.wo = self.param("wo", _INIT, (c.num_attention_heads * hd, d), jnp.float32)
+        self.q_norm = self.param("q_norm", nn.initializers.ones, (hd,), jnp.float32)
+        self.k_norm = self.param("k_norm", nn.initializers.ones, (hd,), jnp.float32)
+
+    def qkv(self, a: jax.Array, pos: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+        c = self.cfg
+        a = a.astype(self.dtype)
+        lead = a.shape[:-1]
+        q = (a @ self.wq.astype(self.dtype)).reshape(*lead, c.num_attention_heads, c.head_dim)
+        k = (a @ self.wk.astype(self.dtype)).reshape(*lead, c.num_key_value_heads, c.head_dim)
+        v = (a @ self.wv.astype(self.dtype)).reshape(*lead, c.num_key_value_heads, c.head_dim)
+        q = rope(rms_norm(q, self.q_norm, c.rms_norm_eps), pos, c.rope_theta).astype(self.dtype)
+        k = rope(rms_norm(k, self.k_norm, c.rms_norm_eps), pos, c.rope_theta).astype(self.dtype)
+        return q, k, v
+
+    def out(self, o: jax.Array) -> jax.Array:
+        return (o.reshape(*o.shape[:-2], -1) @ self.wo.astype(self.dtype)).astype(jnp.float32)
+
+    def __call__(self, a: jax.Array, pos: jax.Array, mask: SegmentMask):
+        """``a``: (B, N, hidden).  Blocked online-softmax attention under
+        ``mask``; also returns this sequence's keys and values."""
+        c = self.cfg
+        q, k, v = self.qkv(a, pos)
+        with jax.named_scope("blockdiff_attn"):
+            o = block_sparse_flash_attention(q, k, v, mask, c.attention_block, interpret=c.attention_interpret)
+        return self.out(o), (k, v)
+
+    def cached(self, a: jax.Array, pos: jax.Array, k_cache: jax.Array, v_cache: jax.Array, length: jax.Array):
+        """One block (B, block, hidden) against the first ``length`` cached
+        clean positions and itself."""
+        c = self.cfg
+        q, k, v = self.qkv(a, pos)
+        bsz, blk = q.shape[:2]
+        rep = c.num_attention_heads // c.num_key_value_heads
+        qg = q.reshape(bsz, blk, c.num_key_value_heads, rep, c.head_dim)
+        scale = 1.0 / jnp.sqrt(jnp.float32(c.head_dim))
+        past = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_cache, preferred_element_type=jnp.float32) * scale
+        past = jnp.where(jnp.arange(k_cache.shape[1]) < length, past, -jnp.inf)
+        own = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k, preferred_element_type=jnp.float32) * scale
+        p = jax.nn.softmax(jnp.concatenate([past, own], axis=-1), axis=-1)
+        p_past, p_own = p[..., : k_cache.shape[1]], p[..., k_cache.shape[1]:]
+        o = jnp.einsum("bgrqk,bkgd->bqgrd", p_past.astype(self.dtype), v_cache, preferred_element_type=jnp.float32)
+        o = o + jnp.einsum("bgrqk,bkgd->bqgrd", p_own.astype(self.dtype), v, preferred_element_type=jnp.float32)
+        o = o.astype(self.dtype).reshape(bsz, blk, c.num_attention_heads, c.head_dim)
+        return self.out(o), (k, v)
+
+
+class SdarLayer(nn.Module):
+    cfg: SdarConfig
+    dtype: Dtype = jnp.float32
+
+    def setup(self) -> None:
+        d = self.cfg.hidden_size
+        self.norm1 = self.param("norm1", nn.initializers.ones, (d,), jnp.float32)
+        self.norm2 = self.param("norm2", nn.initializers.ones, (d,), jnp.float32)
+        self.attn = GroupedQueryAttention(self.cfg, self.dtype)
+        self.moe = RoutedExperts(self.cfg, self.dtype)
+
+    def _moe(self, h1: jax.Array):
+        m = rms_norm(h1, self.norm2, self.cfg.rms_norm_eps)
+        y, aux = self.moe(m.reshape(-1, m.shape[-1]))
+        return h1 + y.reshape(h1.shape), aux
+
+    def __call__(self, h: jax.Array, pos: jax.Array, layout: EpisodeLayout):
+        """``h``: (B, N, hidden) float32, one packed episode a row."""
+        with jax.named_scope("sdar_attn"):
+            o, kv = self.attn(rms_norm(h, self.norm1, self.cfg.rms_norm_eps), pos, layout.mask)
+        h2, aux = self._moe(h + o)
+        return h2, aux, kv
+
+    def cached(self, h: jax.Array, pos: jax.Array, k_cache, v_cache, length):
+        with jax.named_scope("sdar_attn"):
+            o, kv = self.attn.cached(rms_norm(h, self.norm1, self.cfg.rms_norm_eps), pos, k_cache, v_cache, length)
+        h2, aux = self._moe(h + o)
+        return h2, aux, kv
+
+
+class SdarMoE(nn.Module):
+    """Embedding, the layers (unrolled: a scan would hide them from the
+    profiler's scopes), final norm, an untied head over the vocabulary slice
+    and a scalar value head (this system's addition)."""
+
+    cfg: SdarConfig
+    dtype: Dtype = jnp.float32
+    remat: bool = True
+
+    def setup(self) -> None:
+        c = self.cfg
+        self.embed = self.param("embed", _INIT, (c.vocab_size, c.hidden_size), jnp.float32)
+        self.head = self.param("head", _INIT, (c.hidden_size, c.vocab_size), jnp.float32)
+        self.value = self.param("value", _INIT, (c.hidden_size, 1), jnp.float32)
+        self.final_norm = self.param("final_norm", nn.initializers.ones, (c.hidden_size,), jnp.float32)
+        layer = nn.remat(SdarLayer, static_argnums=(3,)) if self.remat else SdarLayer
+        self.layers = [layer(c, self.dtype, name=f"layer_{i}") for i in range(c.num_hidden_layers)]
+
+    def _finish(self, h, auxes):
+        aux = {k: jnp.stack([a[k] for a in auxes]) for k in auxes[0]}
+        return rms_norm(h, self.final_norm, self.cfg.rms_norm_eps), aux
+
+    def hidden(self, tokens: jax.Array, layout: EpisodeLayout, with_kv: bool = False):
+        """Final-norm hidden states (B, N, hidden) of packed episodes
+        ``tokens`` (B, N), the counters stacked over layers and, on request,
+        every layer's keys and values (a prefill)."""
+        with jax.named_scope("sdar_embed"):
+            h = self.embed[tokens]
+        pos = jnp.asarray(layout.positions)
+        auxes, kvs = [], []
+        for layer in self.layers:
+            h, aux, kv = layer(h, pos, layout)
+            auxes.append(aux)
+            kvs.append(kv)
+        out = self._finish(h, auxes)
+        return (*out, kvs) if with_kv else out
+
+    def block(self, tokens: jax.Array, pos: jax.Array, cache, length):
+        """One block of tokens (B, block) against the cache of clean keys and
+        values (per layer ``(k, v)``, each (B, S, kv heads, head)): a denoising
+        pass, or the pass that writes a finished block.  Returns the hidden
+        states, the counters and the block's keys and values per layer."""
+        h = self.embed[tokens]
+        auxes, kvs = [], []
+        for layer, (k_cache, v_cache) in zip(self.layers, cache):
+            h, aux, kv = layer.cached(h, pos, k_cache, v_cache, length)
+            auxes.append(aux)
+            kvs.append(kv)
+        return (*self._finish(h, auxes), kvs)
+
+    def score(self, at: jax.Array) -> Tuple[jax.Array, jax.Array]:
+        """Hidden states at action positions (..., hidden) -> the policy's
+        log-probabilities over the vocabulary slice (float32; ``[MASK]`` is
+        never drawn) and the value."""
+        with jax.named_scope("sdar_head"):
+            logits = jnp.dot(at.astype(self.dtype), self.head.astype(self.dtype), preferred_element_type=jnp.float32)
+            logits = jnp.where(jnp.arange(logits.shape[-1]) == self.cfg.mask_id, _NEG, logits)
+            values = jnp.dot(at, self.value, precision=jax.lax.Precision.HIGHEST)[..., 0]
+            return jax.nn.log_softmax(logits, axis=-1), values
+
+    def __call__(self, tokens: jax.Array, layout: EpisodeLayout):
+        hidden, aux = self.hidden(tokens, layout)
+        return (*self.score(hidden), aux)
+
+
+def reference_params(params: Mapping[str, Any]) -> Dict[str, Any]:
+    """The program's parameter tree in the layout of the plain reference
+    (``sdar_moe_reference.init_params``)."""
+    p = params["params"] if "params" in params else params
+    layers = []
+    for i in range(sum(1 for k in p if k.startswith("layer_"))):
+        lp = p[f"layer_{i}"]
+        layers.append({"norm1": lp["norm1"], "norm2": lp["norm2"], **lp["attn"], **lp["moe"]})
+    return {"embed": p["embed"], "head": p["head"], "value": p["value"], "final_norm": p["final_norm"], "layers": layers}

@@ -119,3 +119,46 @@ def test_no_interpret_default_asks_the_default_backend():
     for path in sources:
         with open(path) as f:
             assert "default_backend" not in f.read(), path
+
+
+# ---- the language-model policy's two device paths at the published widths (one v5e's share:
+# 3 packed episodes of 5,632 positions, 32 / 4 heads of 128; 16 experts of 2048 x 768 held of 128)
+@pytest.mark.parametrize("direction", ["fwd", "grad"])
+@pytest.mark.parametrize("prompt_len,response_len", [(512, 1024), (508, 1000)], ids=["whole_tiles", "padded"])
+def test_block_diffusion_attention_compiles_for_v5e(chip, prompt_len, response_len, direction):
+    from sheeprl_tpu.models.sdar_moe import EpisodeLayout
+    from sheeprl_tpu.ops.block_sparse_attention import block_sparse_flash_attention
+
+    layout = EpisodeLayout(prompt_len, response_len, 4, 4)  # 5,632 positions = 11 tiles of 512; 5,508 are padded to them
+
+    def fwd(q, k, v):
+        return block_sparse_flash_attention(q, k, v, layout.mask, block_size=512).astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((3, layout.length, 32, 128), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((3, layout.length, 4, 128), jnp.bfloat16, sharding=chip)
+    fn = fwd if direction == "fwd" else jax.grad(fwd, argnums=(0, 1, 2))
+    compiled = jax.jit(fn).lower(q, kv, kv).compile()
+    # blocked: far from the 3 x 32 x 5632^2 x 4 bytes = 12 GB a materialised score matrix would take
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("direction", ["fwd", "grad"])
+def test_routed_experts_compile_for_v5e(chip, direction):
+    from sheeprl_tpu.models.sdar_moe import RoutedExperts, SdarConfig
+
+    cfg = SdarConfig(num_hidden_layers=1, experts_held=16, vocab_size=18992, mask_id=18991)
+    layer = RoutedExperts(cfg, jnp.bfloat16)
+    m = jax.ShapeDtypeStruct((16896, 2048), jnp.float32, sharding=chip)
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), jnp.zeros((8, 2048), jnp.float32)),
+    )
+
+    def fwd(p, m):
+        y, aux = layer.apply(p, m)
+        return y.sum(), aux["load"]
+
+    fn = fwd if direction == "fwd" else jax.grad(lambda p, m: fwd(p, m)[0], argnums=(0, 1))
+    text = jax.jit(fn).lower(params, m).compile().as_text()
+    assert "tpu_custom_call" in text  # the grouped products run as the TPU's ragged-dot kernel
