@@ -42,7 +42,7 @@ def prepare_obs(obs: Dict[str, Any], mlp_keys: Sequence[str], num_envs: int) -> 
 class VPGPlayer:
     """Env-loop policy wrapper: jitted sample/greedy action selection bound
     to a mutable params reference.  ``device`` comes from
-    ``runtime.player_device()`` — beside a chip a tiny policy runs on the
+    ``runtime.player_device(params)`` — beside a chip a tiny policy runs on the
     host CPU backend so each env step skips a device dispatch and fetch
     (see howto/scaling.md)."""
 

@@ -131,7 +131,7 @@ def main(runtime, cfg: Dict[str, Any]):
     )
     update_fn = make_update_fn(runtime, module, tx, cfg)
     player = VPGPlayer(module, params, obs_keys, total_envs,
-                       device=runtime.player_device())
+                       device=runtime.player_device(params))
 
     if runtime.is_global_zero:
         save_configs(cfg, log_dir)

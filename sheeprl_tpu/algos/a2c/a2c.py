@@ -203,7 +203,7 @@ def main(runtime, cfg: Dict[str, Any]):
         module,
         params,
         lambda obs: prepare_obs(obs, num_envs=total_envs),
-        device=runtime.player_device(),
+        device=runtime.player_device(params),
     )
 
     if runtime.is_global_zero:

@@ -42,7 +42,7 @@ from sheeprl_tpu.algos.dreamer_v2.agent import (
 )
 from sheeprl_tpu.models.models import resolve_activation
 from sheeprl_tpu.utils.distribution import Normal
-from sheeprl_tpu.utils.utils import transfer_tree
+from sheeprl_tpu.utils.utils import place_player_params
 
 
 def compute_stochastic_state(
@@ -288,7 +288,7 @@ class PlayerDV1:
 
     @params.setter
     def params(self, value):
-        self._params = transfer_tree(value, self.device)
+        self._params = place_player_params(value, self.device)
 
     def get_expl_amount(self, step: int) -> float:
         amount = self.expl_amount

@@ -400,7 +400,7 @@ def main(runtime, cfg: Dict[str, Any]):
         expl_amount=float(cfg.algo.actor.get("expl_amount", 0.0)),
         expl_decay=float(cfg.algo.actor.get("expl_decay", 0.0)),
         expl_min=float(cfg.algo.actor.get("expl_min", 0.0)),
-        device=runtime.player_device(),
+        device=runtime.player_device(player_params),
     )
 
     if runtime.is_global_zero:

@@ -37,7 +37,7 @@ from sheeprl_tpu.utils.distribution import (
     TanhNormal,
     TruncatedNormal,
 )
-from sheeprl_tpu.utils.utils import transfer_tree
+from sheeprl_tpu.utils.utils import place_player_params
 
 xavier_init = nn.initializers.xavier_normal()
 
@@ -642,7 +642,7 @@ class PlayerDV2:
 
     @params.setter
     def params(self, value):
-        self._params = transfer_tree(value, self.device)
+        self._params = place_player_params(value, self.device)
 
     def init_states(self, reset_envs: Optional[Sequence[int]] = None) -> None:
         if reset_envs is None or len(reset_envs) == 0:

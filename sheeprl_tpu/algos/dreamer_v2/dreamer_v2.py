@@ -490,7 +490,7 @@ def main(runtime, cfg: Dict[str, Any]):
         cfg.algo.world_model.recurrent_model.recurrent_state_size,
         discrete_size=cfg.algo.world_model.discrete_size,
         expl_amount=float(cfg.algo.actor.get("expl_amount", 0.0)),
-        device=runtime.player_device(),
+        device=runtime.player_device(player_params),
     )
 
     if runtime.is_global_zero:

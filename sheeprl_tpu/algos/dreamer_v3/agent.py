@@ -44,7 +44,7 @@ from sheeprl_tpu.utils.distribution import (
     OneHotCategoricalStraightThrough,
     TanhNormal,
 )
-from sheeprl_tpu.utils.utils import symlog, transfer_tree
+from sheeprl_tpu.utils.utils import place_player_params, symlog
 
 # Hafner inits (reference dreamer_v3/utils.py:143-187)
 trunc_init = nn.initializers.variance_scaling(1.0, "fan_avg", "truncated_normal")
@@ -868,8 +868,25 @@ class PlayerDV3:
             )
             return actions, jnp.concatenate(actions, -1), recurrent_state, stoch_flat
 
+        def _reset(rssm_params, mask, actions, recurrent_state, stochastic_state):
+            num_envs = mask.shape[0]
+            rec, stoch = self.wm.rssm.apply(rssm_params, (1, num_envs), method=RSSM.get_initial_states)
+            m = mask[None, :, None]
+            return (
+                jnp.where(m, 0.0, actions),
+                jnp.where(m, rec.astype(recurrent_state.dtype), recurrent_state),
+                jnp.where(m, stoch.reshape(1, num_envs, -1).astype(stochastic_state.dtype), stochastic_state),
+            )
+
         self._step = jax.jit(_step, static_argnums=(6,))
+        # one program for every reset, whichever envs are done, and what it
+        # returns is laid out like the step's own outputs (with the
+        # weights), so the step compiles once
+        self._reset = jax.jit(_reset)
         self.init_states()
+        # an episode's end hands the reset device arrays, not host zeros:
+        # that program compiles here, not in the middle of a run
+        self.init_states(range(num_envs))
 
     @property
     def params(self):
@@ -877,26 +894,28 @@ class PlayerDV3:
 
     @params.setter
     def params(self, value):
-        self._params = transfer_tree(value, self.device)
+        self._params = place_player_params(value, self.device)
 
     def init_states(self, reset_envs: Optional[Sequence[int]] = None) -> None:
+        """Reset the given envs' (actions, recurrent, stochastic) states, or
+        with no argument every env's, from nothing and at the current
+        ``num_envs`` (the test episode runs with one)."""
         if reset_envs is None or len(reset_envs) == 0:
-            self.actions = jnp.zeros((1, self.num_envs, int(np.sum(self.actions_dim))))
-            rec, stoch = self._initial_states((1, self.num_envs))
-            self.recurrent_state = rec
-            self.stochastic_state = stoch.reshape(1, self.num_envs, -1)
-        else:
-            idx = np.asarray(reset_envs)
-            self.actions = self.actions.at[:, idx].set(0.0)
-            rec, stoch = self._initial_states((1, len(idx)))
-            self.recurrent_state = self.recurrent_state.at[:, idx].set(rec)
-            self.stochastic_state = self.stochastic_state.at[:, idx].set(
-                stoch.reshape(1, len(idx), -1)
+            mask = np.ones((self.num_envs,), dtype=bool)
+            states = tuple(
+                np.zeros((1, self.num_envs, width), np.float32)
+                for width in (
+                    int(np.sum(self.actions_dim)),
+                    self.recurrent_state_size,
+                    self.stochastic_size * self.discrete_size,
+                )
             )
-
-    def _initial_states(self, batch_shape):
-        return self.wm.rssm.apply(
-            self._params["world_model"]["rssm"], batch_shape, method=RSSM.get_initial_states
+        else:
+            mask = np.zeros((self.num_envs,), dtype=bool)
+            mask[np.asarray(reset_envs)] = True
+            states = (self.actions, self.recurrent_state, self.stochastic_state)
+        self.actions, self.recurrent_state, self.stochastic_state = self._reset(
+            self._params["world_model"]["rssm"], mask, *states
         )
 
     def get_actions(

@@ -12,8 +12,10 @@ TPU-first design:
   SPMD (the reference all_gathers by hand, utils.py:57);
 - EMA target-critic update is a tiny separate jitted call driven by the
   host cadence counter (reference dreamer_v3.py:674-680);
-- the stateful player (masked RSSM resets on dones) runs on the host CPU
-  backend when training is on an accelerator.
+- the stateful player (masked RSSM resets on dones) acts where
+  ``runtime.player_device`` puts it: beside a chip on the host CPU backend
+  while its weights are small, on the training device (sharing the
+  learner's arrays) once they are large.
 """
 
 from __future__ import annotations
@@ -761,7 +763,7 @@ def main(runtime, cfg: Dict[str, Any]):
         cfg.algo.world_model.recurrent_model.recurrent_state_size,
         discrete_size=cfg.algo.world_model.discrete_size,
         decoupled_rssm=bool(cfg.algo.world_model.decoupled_rssm),
-        device=runtime.player_device(),
+        device=runtime.player_device(player_params),
     )
 
     if runtime.is_global_zero:
@@ -972,8 +974,13 @@ def main(runtime, cfg: Dict[str, Any]):
                     params = restore_like(params, rolled["agent"])
                     opt_states = restore_like(opt_states, rolled["opt_states"])
                     moments_state = restore_like(moments_state, rolled["moments"])
-                # one device-to-host copy of the player's weights, which also
-                # waits for the update that produced them
+                # The update donated the tree the player was acting with, so
+                # the player is handed the new one here, directly after the
+                # dispatch (and after a rollback put restored arrays in its
+                # place) and before the next policy step can read the old one.
+                # On the training device this is a rebinding of the learner's
+                # own arrays; on the host CPU it is one device-to-host copy,
+                # which also waits for the update that produced them.
                 with timer("Time/params_refresh"):
                     player.params = {"world_model": params["world_model"], "actor": params["actor"]}
                 # metric.fetch_every amortizes the per-iteration device

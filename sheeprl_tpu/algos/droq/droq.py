@@ -247,7 +247,7 @@ def main(runtime, cfg: Dict[str, Any]):
         actor,
         params["actor"],
         lambda obs: prepare_obs(obs, mlp_keys=mlp_keys, num_envs=total_envs),
-        device=runtime.player_device(),
+        device=runtime.player_device(params["actor"]),
     )
 
     if runtime.is_global_zero:

@@ -157,5 +157,5 @@ def make_player(
         expl_decay=float(cfg.algo.actor.get("expl_decay", 0.0)),
         expl_min=float(cfg.algo.actor.get("expl_min", 0.0)),
         actor_type=actor_type,
-        device=runtime.player_device(),
+        device=runtime.player_device(player_params),
     )

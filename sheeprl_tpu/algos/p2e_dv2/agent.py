@@ -155,5 +155,5 @@ def make_player(
         discrete_size=cfg.algo.world_model.discrete_size,
         actor_type=actor_type,
         expl_amount=float(cfg.algo.actor.get("expl_amount", 0.0)),
-        device=runtime.player_device(),
+        device=runtime.player_device(player_params),
     )

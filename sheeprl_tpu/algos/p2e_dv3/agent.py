@@ -171,6 +171,6 @@ def make_player(
         cfg.algo.world_model.recurrent_model.recurrent_state_size,
         discrete_size=cfg.algo.world_model.discrete_size,
         actor_type=actor_type,
-        device=runtime.player_device(),
+        device=runtime.player_device(player_params),
     )
     return player

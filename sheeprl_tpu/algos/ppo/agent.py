@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from sheeprl_tpu.models.models import MLP, CNN, MultiEncoder
 from sheeprl_tpu.utils.distribution import Independent, Normal, OneHotCategorical
-from sheeprl_tpu.utils.utils import transfer_tree
+from sheeprl_tpu.utils.utils import place_player_params
 
 Dtype = Any
 
@@ -223,7 +223,7 @@ class PPOPlayer:
 
     @params.setter
     def params(self, value: Any) -> None:
-        self._params = transfer_tree(value, self.device)
+        self._params = place_player_params(value, self.device)
 
     def _obs(self, obs: Dict[str, Any]) -> Dict[str, jax.Array]:
         prepared = self._prepare_obs(obs)

@@ -575,7 +575,7 @@ def main(runtime, cfg: Dict[str, Any]):
         return prepare_obs(obs, cnn_keys=cnn_keys, num_envs=total_envs)
 
     # the language-model policy acts inside the fused collector only: no host-side player
-    player = None if lm_policy else PPOPlayer(module, params, _prep, device=runtime.player_device())
+    player = None if lm_policy else PPOPlayer(module, params, _prep, device=runtime.player_device(params))
 
     if runtime.is_global_zero:
         save_configs(cfg, log_dir)

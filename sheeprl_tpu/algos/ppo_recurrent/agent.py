@@ -24,7 +24,7 @@ import numpy as np
 from sheeprl_tpu.algos.ppo.agent import CNNEncoder, MLPEncoder
 from sheeprl_tpu.models.models import MLP, MultiEncoder
 from sheeprl_tpu.utils.distribution import Independent, Normal, OneHotCategorical
-from sheeprl_tpu.utils.utils import transfer_tree
+from sheeprl_tpu.utils.utils import place_player_params
 
 Dtype = Any
 
@@ -284,7 +284,7 @@ class RecurrentPPOPlayer:
 
     @params.setter
     def params(self, value: Any) -> None:
-        self._params = transfer_tree(value, self.device)
+        self._params = place_player_params(value, self.device)
 
     def init_states(self) -> None:
         h = self.module.rnn_hidden_size

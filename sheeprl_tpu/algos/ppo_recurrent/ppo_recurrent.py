@@ -390,7 +390,7 @@ def main(runtime, cfg: Dict[str, Any]):
     def _prep(obs):
         return prepare_obs(obs, cnn_keys=cnn_keys, num_envs=total_envs)
 
-    player = RecurrentPPOPlayer(module, params, _prep, num_envs=total_envs, device=runtime.player_device())
+    player = RecurrentPPOPlayer(module, params, _prep, num_envs=total_envs, device=runtime.player_device(params))
 
     if runtime.is_global_zero:
         save_configs(cfg, log_dir)

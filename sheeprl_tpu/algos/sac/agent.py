@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from sheeprl_tpu.models.models import MLP
-from sheeprl_tpu.utils.utils import transfer_tree
+from sheeprl_tpu.utils.utils import place_player_params
 
 LOG_STD_MIN = -5.0
 LOG_STD_MAX = 2.0
@@ -130,7 +130,7 @@ class SACPlayer:
 
     @params.setter
     def params(self, value: Any) -> None:
-        self._params = transfer_tree(value, self.device)
+        self._params = place_player_params(value, self.device)
 
     def get_actions(self, obs: Dict[str, Any], key: Optional[jax.Array] = None, greedy: bool = False):
         prepared = self._prepare_obs(obs)

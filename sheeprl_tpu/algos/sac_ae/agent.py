@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from sheeprl_tpu.utils.utils import transfer_tree
+from sheeprl_tpu.utils.utils import place_player_params
 
 LOG_STD_MIN = -10.0
 LOG_STD_MAX = 2.0
@@ -312,7 +312,7 @@ class SACAEPlayer:
 
     @params.setter
     def params(self, value):
-        self._params = transfer_tree(value, self.device)
+        self._params = place_player_params(value, self.device)
 
     def get_actions(self, obs, key=None, greedy: bool = False):
         prepared = self.prepare_obs_fn(obs)

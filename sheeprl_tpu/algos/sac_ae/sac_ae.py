@@ -308,7 +308,7 @@ def main(runtime, cfg: Dict[str, Any]):
         modules,
         player_params,
         lambda obs: prepare_obs(obs, cnn_keys=cnn_keys, num_envs=total_envs),
-        device=runtime.player_device(),
+        device=runtime.player_device(player_params),
     )
 
     if runtime.is_global_zero:

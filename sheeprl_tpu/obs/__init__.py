@@ -113,6 +113,12 @@ class Observability:
         # setup_observability wires MeshRuntime.mesh_telemetry here so
         # every record carries a "mesh" section (howto/observability.md)
         self.mesh_stats: Optional[Any] = None
+        # zero-arg provider of the player's placement (device, weight
+        # bytes) and the bytes its weight refreshes copied across backends
+        # since the last record; setup_observability wires
+        # MeshRuntime.player_telemetry here ("player" section; None until a
+        # loop placed its player)
+        self.player_stats: Optional[Any] = None
         if not self.enabled:
             return
         self._world_size = max(1, int(world_size))
@@ -183,6 +189,13 @@ class Observability:
         if self.mesh_stats is not None:
             try:
                 extra = {**(extra or {}), "mesh": self.mesh_stats()}
+            except Exception:
+                pass
+        if self.player_stats is not None:
+            try:
+                player = self.player_stats()
+                if player is not None:
+                    extra = {**(extra or {}), "player": player}
             except Exception:
                 pass
         led = ledger.get_ledger()
@@ -328,6 +341,7 @@ def setup_observability(runtime, cfg, log_dir: Optional[str], logger: Any = None
         name=str(cfg.get("algo", {}).get("name", "run")),
     )
     obs.mesh_stats = getattr(runtime, "mesh_telemetry", None)
+    obs.player_stats = getattr(runtime, "player_telemetry", None)
     # flight recorder (ISSUE 13): the coupled loops get their process
     # recorder here (role "main"); the decoupled loops configure their
     # own role BEFORE calling this, which wins — first configure sticks

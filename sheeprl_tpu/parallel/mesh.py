@@ -29,6 +29,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from sheeprl_tpu.parallel.sharding import BATCH_AXES, ShardingLayout, build_mesh
+from sheeprl_tpu.utils.utils import take_refresh_copied_bytes
 
 
 def _sanitize_enabled() -> bool:
@@ -69,6 +70,25 @@ def configure_compilation_cache() -> str:
 _PRECISIONS = ("32-true", "bf16-mixed", "bf16-true")
 _STRATEGIES = ("auto", "dp", "ddp", "fsdp")
 _PLAYER_DEVICES = ("auto", "cpu", "accelerator")
+# fabric.player_device=auto beside a chip: players whose weights reach this
+# many bytes act on the training device, smaller ones on the host CPU.
+# Set between the largest player the host won with and the smallest the chip
+# won with, by the wall per policy step of ``cli.run`` on one v5e
+# (benchmarks/player_device_readings.py, PR 27; host | chip, ms):
+#   exp=ppo MLP, 4 envs (a refresh per 128 steps)   0.10 MB  0.35 | 0.45
+#                                                   1.00 MB  0.36 | 0.45
+#                                                   3.57 MB  0.38 | 0.45   <- host wins up to here
+#                                                  13.42 MB  0.46 | 0.45
+#   DreamerV3, 1 env (a refresh per 2 steps)        4.29 MB  22.1 | 11.2   <- chip wins from here
+#                                                   9.63 MB  23.9 | 11.0
+#                                             XS   31.50 MB  29.7 | 12.3
+#                                             S    67.01 MB  73.4 | 14.4
+#                                             M   222.37 MB 160.7 | 22.2
+#                                             XL  764.14 MB 565.8 | 56.4
+# On the chip a step costs the same whatever the weights weigh (1.8-2.7 ms of
+# Time/player_step); on the host the step and the refresh both grow with them,
+# and a refresh costs ~20 ms in per-leaf work before its first byte.
+PLAYER_ON_CHIP_BYTES = 4 * 1024 * 1024
 
 
 class MeshRuntime:
@@ -101,6 +121,7 @@ class MeshRuntime:
         self._player_device = player_device
         self._mesh_shape = mesh_shape
         self._player_choice_logged = False
+        self._player_placement: Optional[Dict[str, Any]] = None
         self._launched = False
         self._mesh: Optional[Mesh] = None
         self._layout: Optional[ShardingLayout] = None
@@ -485,25 +506,43 @@ class MeshRuntime:
         rt._key = self._key
         return rt
 
-    def player_device(self):
+    def player_device(self, params: Any = None):
         """Device for env-interaction policies; None = the training device.
 
-        "auto"/"cpu" (default): the host CPU backend when training runs on
-        an accelerator and a CPU backend exists — the env hot loop then
-        dispatches tiny policy steps without queueing behind train steps
-        (CPU-actor/TPU-learner split).  "accelerator": keep the player on
-        the training device.  Configured via ``fabric.player_device``; the
-        SHEEPRL_PLAYER_DEVICE env var overrides the config."""
+        ``params`` is the tree the loop hands to its player: under
+        ``auto`` the player acts where its weights are cheapest to read.
+        Small weights go to the host CPU backend beside a chip (the env hot
+        loop then dispatches tiny policy steps without queueing behind
+        train steps: CPU-actor/TPU-learner split); from
+        ``PLAYER_ON_CHIP_BYTES`` up the player stays on the training device
+        and shares the learner's arrays, so no step streams them through
+        the host's memory and no update ships them there.  "cpu" and
+        "accelerator" force either side.  Configured via
+        ``fabric.player_device``; the SHEEPRL_PLAYER_DEVICE env var
+        overrides the config."""
         choice = os.environ.get("SHEEPRL_PLAYER_DEVICE", self._player_device)
         if choice not in _PLAYER_DEVICES:
             raise ValueError(
                 f"player_device must be one of {_PLAYER_DEVICES}, got '{choice}'"
             )
-        device, why = self._player_device_decision(choice)
+        nbytes = self._player_params_nbytes(params)
+        device, why = self._player_device_decision(choice, nbytes)
+        placed = device if device is not None else self.device
+        self._player_placement = {"device": f"{placed.platform}:{placed.id}", "param_bytes": nbytes}
+        take_refresh_copied_bytes()  # the count is this process's: a run's first record starts from its own player
         if not self._player_choice_logged:
             self._player_choice_logged = True
             self.print(f"Player device: {device if device is not None else 'training device'} ({why})")
         return device
+
+    def player_telemetry(self) -> Optional[Dict[str, Any]]:
+        """The telemetry record's ``player`` key (howto/observability.md):
+        where the player acts, the bytes of its weights, and the bytes its
+        refreshes copied across backends since the last record (0 while it
+        shares the learner's arrays).  None before a loop placed a player."""
+        if self._player_placement is None:
+            return None
+        return {**self._player_placement, "refresh_copied_bytes": take_refresh_copied_bytes()}
 
     def _player_params_nbytes(self, params: Any) -> int:
         return sum(
@@ -511,11 +550,13 @@ class MeshRuntime:
             for leaf in jax.tree_util.tree_leaves(params)
         )
 
-    def _player_device_decision(self, choice: str):
+    def _player_device_decision(self, choice: str, nbytes: int):
         """(device-or-None, reason); None = stay on the training device.
-        Three cases, pinned by tests/test_parallel/test_mesh.py: an
-        explicit ``accelerator``, training already on the CPU, a local
-        chip (host CPU player when a CPU backend exists)."""
+        The cases, pinned by tests/test_parallel/test_mesh.py: an explicit
+        ``accelerator``, training already on the CPU, no CPU backend, and
+        beside a local chip ``cpu`` (always the host) and ``auto`` (the
+        host below ``PLAYER_ON_CHIP_BYTES`` of weights, the chip from it
+        up)."""
         if choice == "accelerator":
             return None, "player_device=accelerator"
         if self.device.platform == "cpu":
@@ -524,7 +565,10 @@ class MeshRuntime:
             cpu = jax.local_devices(backend="cpu")[0]
         except RuntimeError:
             return None, "no host CPU backend in this process (JAX_PLATFORMS excludes cpu)"
-        return cpu, f"player_device={choice}: host CPU player beside the local chip"
+        sizes = f"{nbytes} B of player weights, bound {PLAYER_ON_CHIP_BYTES} B"
+        if choice == "auto" and nbytes >= PLAYER_ON_CHIP_BYTES:
+            return None, f"player_device=auto: {sizes}: the player shares the learner's arrays on the chip"
+        return cpu, f"player_device={choice}: {sizes}: host CPU player beside the local chip"
 
     # ------------------------------------------------------------------ #
     # host-side collectives (metrics, small objects)

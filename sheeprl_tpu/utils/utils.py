@@ -364,6 +364,33 @@ def transfer_tree(tree: Any, device) -> Any:
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
+# bytes the players' weight refreshes copied across backends since the last
+# telemetry record took them (the record's ``player.refresh_copied_bytes``)
+_refresh_copied_bytes = 0
+
+
+def place_player_params(tree: Any, device) -> Any:
+    """What a player's ``params`` setter does with the tree it is given:
+    ``device=None`` (the player acts on the training device) keeps the
+    learner's own arrays, by reference; any other device gets a copy through
+    :func:`transfer_tree`, and the bytes that cross backends are counted."""
+    global _refresh_copied_bytes
+    if device is not None:
+        _refresh_copied_bytes += sum(
+            leaf.nbytes
+            for leaf in jax.tree_util.tree_leaves(tree)
+            if hasattr(leaf, "devices") and next(iter(leaf.devices())).platform != device.platform
+        )
+    return transfer_tree(tree, device)
+
+
+def take_refresh_copied_bytes() -> int:
+    """The count above, read and reset (once per telemetry record)."""
+    global _refresh_copied_bytes
+    taken, _refresh_copied_bytes = _refresh_copied_bytes, 0
+    return taken
+
+
 def save_configs(cfg: dotdict, log_dir: str) -> None:
     """Persist the resolved run config next to the logs (utils/utils.py:257)."""
     import yaml

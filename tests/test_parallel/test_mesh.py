@@ -142,22 +142,34 @@ def test_single_device_view():
 
 class _FakeChip:
     platform = "tpu"
+    id = 0
 
 
 @pytest.mark.parametrize(
-    "choice,on_chip,cpu_backend,expect_cpu,why",
+    "choice,on_chip,cpu_backend,bound_multiple,expect_cpu,why",
     [
-        ("accelerator", True, True, False, "player_device=accelerator"),
-        ("auto", False, True, False, "already the host CPU"),
-        ("cpu", False, True, False, "already the host CPU"),
-        ("auto", True, True, True, "beside the local chip"),
-        ("cpu", True, True, True, "beside the local chip"),
-        ("auto", True, False, False, "no host CPU backend"),
+        ("accelerator", True, True, 0, False, "player_device=accelerator"),
+        ("auto", False, True, 0, False, "already the host CPU"),
+        ("cpu", False, True, 0, False, "already the host CPU"),
+        ("auto", True, True, 0, True, "beside the local chip"),
+        ("cpu", True, True, 0, True, "beside the local chip"),
+        ("auto", True, False, 0, False, "no host CPU backend"),
+        # auto beside a chip goes by the bytes of the player's weights
+        ("auto", True, True, 0.999, True, "beside the local chip"),
+        ("auto", True, True, 1, False, "shares the learner's arrays"),
+        ("auto", True, True, 25, False, "shares the learner's arrays"),
+        ("cpu", True, True, 25, True, "beside the local chip"),
+        ("auto", False, True, 25, False, "already the host CPU"),
+        ("auto", True, False, 25, False, "no host CPU backend"),
     ],
 )
-def test_player_device_decision_table(monkeypatch, choice, on_chip, cpu_backend, expect_cpu, why):
-    """The three cases left: an explicit ``accelerator``, training already
-    on the CPU, a local chip (host CPU player when a CPU backend exists)."""
+def test_player_device_decision_table(monkeypatch, choice, on_chip, cpu_backend, bound_multiple, expect_cpu, why):
+    """An explicit ``accelerator``, training already on the CPU, no CPU
+    backend, and beside a local chip: ``cpu`` the host whatever the player
+    weighs, ``auto`` the host below ``PLAYER_ON_CHIP_BYTES`` and the chip from
+    it up."""
+    from sheeprl_tpu.parallel.mesh import PLAYER_ON_CHIP_BYTES
+
     rt = MeshRuntime(devices=1, accelerator="cpu").launch()
     fake_cpu = object()
 
@@ -169,9 +181,30 @@ def test_player_device_decision_table(monkeypatch, choice, on_chip, cpu_backend,
     monkeypatch.setattr("jax.local_devices", fake_local_devices)
     if on_chip:
         monkeypatch.setattr(type(rt), "device", property(lambda self: _FakeChip()))
-    device, reason = rt._player_device_decision(choice)
+    device, reason = rt._player_device_decision(choice, int(bound_multiple * PLAYER_ON_CHIP_BYTES))
     assert (device is fake_cpu) == expect_cpu and (device is None) != expect_cpu
     assert why in reason
+
+
+@pytest.mark.parametrize("choice,floats", [("auto", 1 << 10), ("auto", 1 << 26), ("cpu", 1 << 26)])
+def test_player_device_reason_names_the_bytes_and_the_bound(monkeypatch, capsys, choice, floats):
+    """``Player device: <where> (<why>)`` is what a run prints and what
+    chip_smoke.py parses: beside a chip the why carries both numbers."""
+    from sheeprl_tpu.parallel.mesh import PLAYER_ON_CHIP_BYTES
+
+    rt = MeshRuntime(devices=1, accelerator="cpu", player_device=choice).launch()
+    cpu = jax.devices("cpu")[0]
+    monkeypatch.setattr(type(rt), "device", property(lambda self: _FakeChip()))
+    monkeypatch.delenv("SHEEPRL_PLAYER_DEVICE", raising=False)
+    params = {"w": jax.ShapeDtypeStruct((floats,), np.float32), "b": np.zeros((3,), np.float16)}
+    nbytes = 4 * floats + 6
+    device = rt.player_device(params)
+    assert (device is None) == (choice == "auto" and nbytes >= PLAYER_ON_CHIP_BYTES)
+    assert device is None or device == cpu
+    line = [ln for ln in capsys.readouterr().out.splitlines() if ln.startswith("Player device: ")]
+    assert len(line) == 1
+    assert f"{nbytes} B of player weights" in line[0] and f"bound {PLAYER_ON_CHIP_BYTES} B" in line[0]
+    assert line[0].startswith("Player device: training device (" if device is None else f"Player device: {cpu} (")
 
 
 def test_requested_accelerator_that_is_absent_raises():
