@@ -931,6 +931,33 @@ class PlayerDV3:
         return actions
 
 
+def build_player(
+    runtime,
+    world_model: WorldModel,
+    actor: Actor,
+    player_params: Dict[str, Any],
+    actions_dim: Sequence[int],
+    num_envs: int,
+    cfg: Dict[str, Any],
+    actor_type: Optional[str] = None,
+) -> PlayerDV3:
+    """The training loops' player over ``{"world_model", "actor"}`` params,
+    sized by ``cfg`` and placed where ``runtime.player_device`` says."""
+    return PlayerDV3(
+        world_model,
+        actor,
+        player_params,
+        actions_dim,
+        num_envs,
+        cfg.algo.world_model.stochastic_size,
+        cfg.algo.world_model.recurrent_model.recurrent_state_size,
+        discrete_size=cfg.algo.world_model.discrete_size,
+        decoupled_rssm=bool(cfg.algo.world_model.decoupled_rssm),
+        actor_type=actor_type,
+        device=runtime.player_device(player_params),
+    )
+
+
 def build_agent(
     runtime,
     actions_dim: Sequence[int],

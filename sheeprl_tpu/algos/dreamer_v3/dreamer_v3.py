@@ -22,17 +22,15 @@ from __future__ import annotations
 
 import os
 import time
-import warnings
 from functools import partial
-from typing import Any, Dict, Sequence
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 
-from sheeprl_tpu.algos.dreamer_v3.agent import RSSM, PlayerDV3, build_agent
-from sheeprl_tpu.models.models import resolve_activation
+from sheeprl_tpu.algos.dreamer_v3.agent import RSSM, build_agent, build_player
 from sheeprl_tpu.algos.dreamer_v3.loss import reconstruction_loss
 from sheeprl_tpu.algos.dreamer_v3.utils import (
     compute_lambda_values,
@@ -42,7 +40,6 @@ from sheeprl_tpu.algos.dreamer_v3.utils import (
     update_moments,
 )
 from sheeprl_tpu.config import instantiate
-from sheeprl_tpu.config.compose import _locate
 from sheeprl_tpu.data.buffers import EnvIndependentReplayBuffer, SequentialReplayBuffer
 from sheeprl_tpu.data.device_buffer import maybe_create_for, sequence_batches
 from sheeprl_tpu.envs.wrappers import RestartOnException
@@ -83,127 +80,37 @@ from sheeprl_tpu.optim import restore_opt_states
 sg = jax.lax.stop_gradient
 
 
-def _mlp_geometry(tree):
-    """(n_hidden_layers, units, has_layer_norm) of a DreamerMLP param tree,
-    or None if the tree isn't shaped like one."""
-    p = tree.get("params", tree)
-    layers = sorted(k for k in p if k.startswith("LinearLnAct_"))
-    if not layers or "Dense_0" not in p:
-        return None
-    first = p[layers[0]]
-    if "Dense_0" not in first:
-        return None
-    units = first["Dense_0"]["kernel"].shape[-1]
-    has_ln = "LayerNorm_0" in first
-    for name in layers:
-        blk = p[name]
-        if blk["Dense_0"]["kernel"].shape[-1] != units or ("LayerNorm_0" in blk) != has_ln:
-            return None
-    return len(layers), units, has_ln
-
-
-def fused_mlp_heads(trees, x, eps, act_fn, dtype):
-    """Run several same-geometry DreamerMLP heads over one shared input as
-    batched matmuls.
-
-    The DV3 trajectory heads (critic / reward / continue, and the two
-    critics of the value loss) each run a small (D, U) MLP over the same
-    (H+1, T*B, D) imagined-trajectory tensor; issued separately they are
-    latency-bound dispatches.  Concatenating the first-layer kernels and
-    batching the deeper layers as ``einsum('...hu,huv->...hv')`` turns 3N
-    small ops into N wide MXU ops.  Returns the per-head f32 logits list.
-    Gradients flow exactly as in the unfused form (concat/slice are linear).
-    """
-    n = len(trees)
-    ps = [t.get("params", t) for t in trees]
-    geom = _mlp_geometry(trees[0])
-    layers, units, has_ln = geom
-    k1 = jnp.concatenate(
-        [p["LinearLnAct_0"]["Dense_0"]["kernel"].astype(dtype) for p in ps], -1
-    )
-    h = (x.astype(dtype) @ k1).reshape(*x.shape[:-1], n, units)
-    for li in range(layers):
-        if li > 0:
-            wl = jnp.stack(
-                [p[f"LinearLnAct_{li}"]["Dense_0"]["kernel"].astype(dtype) for p in ps]
-            )
-            h = jnp.einsum("...hu,huv->...hv", h, wl)
-        if has_ln:
-            scale = jnp.stack([p[f"LinearLnAct_{li}"]["LayerNorm_0"]["scale"] for p in ps])
-            bias = jnp.stack([p[f"LinearLnAct_{li}"]["LayerNorm_0"]["bias"] for p in ps])
-            hf = h.astype(jnp.float32)
-            mu = hf.mean(-1, keepdims=True)
-            var = ((hf - mu) ** 2).mean(-1, keepdims=True)
-            h = (hf - mu) * jax.lax.rsqrt(var + eps) * scale + bias
-        else:
-            h = h + jnp.stack(
-                [p[f"LinearLnAct_{li}"]["Dense_0"]["bias"] for p in ps]
-            ).astype(h.dtype)
-        h = act_fn(h.astype(dtype))
-    hf = h.astype(jnp.float32)
-    return [
-        hf[..., i, :] @ p["Dense_0"]["kernel"] + p["Dense_0"]["bias"]
-        for i, p in enumerate(ps)
-    ]
-
-
-def _heads_fusible(trees, modules):
-    # measured OFF by default: on a single v5e the fused path compiled to
-    # MORE flops (the separate per-head evals let XLA CSE the online-critic
-    # forward between the actor and critic losses) and a slower step
-    # (17.1 ms vs 15.9 ms at DV3-S); kept behind a flag for multi-chip
-    # studies where dispatch latency dominates
-    if os.environ.get("SHEEPRL_FUSE_HEADS", "0") != "1":
-        return False
-    # the fused path evaluates every head with ONE activation/eps — require
-    # the modules to actually agree, not just their kernel geometry
-    m0 = modules[0]
-    if not all(m.act == m0.act and m.eps == m0.eps and m.layer_norm == m0.layer_norm for m in modules):
-        return False
-    geoms = [_mlp_geometry(t) for t in trees]
-    return all(g is not None and g == geoms[0] for g in geoms)
-
-
 def _make_optimizer(optim_cfg, clip_gradients, precision="32-true"):
     from sheeprl_tpu.optim import build_optimizer
 
     return build_optimizer(optim_cfg, clip_gradients, precision)
 
 
-def make_train_fn(runtime, world_model, actor, critic, txs, cfg, is_continuous, actions_dim):
-    """Build the single jitted DV3 gradient step."""
-    wm_tx, actor_tx, critic_tx = txs
+def make_wm_grad_fn(runtime, world_model, cfg, detach_heads: bool = False):
+    """The DreamerV3 world-model loss and its gradient, for every update of the
+    family: ``wm_grad(wm_params, data, key) -> ((rec_loss, aux), grads)``.
+    ``detach_heads`` hands the reward and continue heads ``stop_gradient`` of
+    the latents (Plan2Explore's exploration phase); nothing else differs."""
     cnn_keys = tuple(cfg.algo.cnn_keys.encoder)
     mlp_keys = tuple(cfg.algo.mlp_keys.encoder)
     cnn_keys_dec = tuple(cfg.algo.cnn_keys.decoder)
     mlp_keys_dec = tuple(cfg.algo.mlp_keys.decoder)
     stochastic_size = int(cfg.algo.world_model.stochastic_size)
     discrete_size = int(cfg.algo.world_model.discrete_size)
-    stoch_state_size = stochastic_size * discrete_size
     recurrent_state_size = int(cfg.algo.world_model.recurrent_model.recurrent_state_size)
-    horizon = int(cfg.algo.horizon)
-    gamma = float(cfg.algo.gamma)
-    lmbda = float(cfg.algo.lmbda)
-    ent_coef = float(cfg.algo.actor.ent_coef)
     kl_dynamic = float(cfg.algo.world_model.kl_dynamic)
     kl_representation = float(cfg.algo.world_model.kl_representation)
     kl_free_nats = float(cfg.algo.world_model.kl_free_nats)
     kl_regularizer = float(cfg.algo.world_model.kl_regularizer)
     continue_scale_factor = float(cfg.algo.world_model.continue_scale_factor)
-    moments_cfg = cfg.algo.actor.moments
     decoupled = bool(cfg.algo.world_model.decoupled_rssm)
     # scan bodies at Dreamer sizes are launch/latency-bound (B=16 rows keep
     # every matmul far below an MXU tile): unrolling lets XLA fuse across
     # iterations and cuts while-loop trip counts, which round-3 profiling
-    # showed to be 56% of device step time (dv3_profile_r3.json)
-    # shared knobs (utils.scan_remat / scan_unroll_setting): "dots" remat
-    # measured best for BOTH scans on a v5e (imagination: kills the ~40
-    # stacked (H, T*B, 512) residual buffers; dynamic: 16.15 ms vs
-    # 16.78 ms without remat even at B=16 rows)
+    # showed to be 56% of device step time (dv3_profile_r3.json); "dots"
+    # remat (utils.scan_remat) measured best for the dynamic scan on a v5e
+    # (16.15 ms vs 16.78 ms without remat even at B=16 rows)
     scan_unroll = scan_unroll_setting(cfg, "dyn")
-    img_unroll = scan_unroll_setting(cfg, "img")
-    dyn_remat_policy = os.environ.get("SHEEPRL_DYN_REMAT")
-    _remat = scan_remat
 
     rssm = world_model.rssm
     # efficient-BPTT dynamic scan (ops/dyn_bptt.py): same fwd lax.scan, but a
@@ -211,13 +118,8 @@ def make_train_fn(runtime, world_model, actor, critic, txs, cfg, is_continuous, 
     # accumulators leave the backward while-loop's carry
     dyn_bptt = dyn_bptt_setting(cfg) and rssm_dyn_bptt_eligible(rssm)
 
-    def train(params, opt_states, moments_state, data, key):
-        # one jax.named_scope per phase, names fixed and disjoint: they are
-        # HLO metadata only, and what a profile (and chipbench/scope_reduce.py)
-        # splits the update's device time by; backward ops inherit the name
+    def wm_grad(wm_params, data, k_dyn):
         T, B = data["rewards"].shape[:2]
-        k_dyn, k_img, k_actor = jax.random.split(key, 3)
-
         with jax.named_scope("wm_encoder"):
             batch_obs = {k: data[k] / 255.0 - 0.5 for k in cnn_keys}
             batch_obs.update({k: data[k] for k in mlp_keys})
@@ -228,7 +130,6 @@ def make_train_fn(runtime, world_model, actor, critic, txs, cfg, is_continuous, 
             [jnp.zeros_like(data["actions"][:1]), data["actions"][:-1]], axis=0
         )
 
-        # ---------------------------------------------------- world model
         # all the rollout's categorical-sampling randomness is drawn HERE, in
         # two batched gumbel ops, instead of 3 threefry chains per scan
         # iteration — the scan bodies are latency-bound, so op count inside
@@ -362,7 +263,7 @@ def make_train_fn(runtime, world_model, actor, critic, txs, cfg, is_continuous, 
                             jnp.zeros((B, recurrent_state_size)),
                         )
                         _, (recurrent_states, posteriors, posteriors_logits) = jax.lax.scan(
-                            _remat(dyn_step, dyn_remat_policy), init,
+                            scan_remat(dyn_step), init,
                             (batch_actions, emb_proj, is_first, dyn_noise_q),
                             unroll=scan_unroll,
                         )
@@ -393,12 +294,15 @@ def make_train_fn(runtime, world_model, actor, critic, txs, cfg, is_continuous, 
                         for k in mlp_keys_dec
                     }
                 )
+                # Plan2Explore's exploration phase trains the reward and continue
+                # heads on detached latents (reference p2e_dv3_exploration.py:160-163)
+                head_latents = sg(latent_states) if detach_heads else latent_states
                 pr = TwoHotEncodingDistribution(
-                    world_model.reward_model.apply(wm_params["reward_model"], latent_states), dims=1
+                    world_model.reward_model.apply(wm_params["reward_model"], head_latents), dims=1
                 )
                 pc = Independent(
                     BernoulliSafeMode(
-                        logits=world_model.continue_model.apply(wm_params["continue_model"], latent_states)
+                        logits=world_model.continue_model.apply(wm_params["continue_model"], head_latents)
                     ),
                     1,
                 )
@@ -433,9 +337,39 @@ def make_train_fn(runtime, world_model, actor, critic, txs, cfg, is_continuous, 
                 }
             return rec_loss, aux
 
-        (rec_loss, wm_aux), wm_grads = jax.value_and_grad(wm_loss_fn, has_aux=True)(
-            params["world_model"]
-        )
+        return jax.value_and_grad(wm_loss_fn, has_aux=True)(wm_params)
+
+    return wm_grad
+
+
+def make_train_fn(runtime, world_model, actor, critic, txs, cfg, is_continuous, actions_dim):
+    """Build the single jitted DV3 gradient step."""
+    wm_tx, actor_tx, critic_tx = txs
+    stochastic_size = int(cfg.algo.world_model.stochastic_size)
+    discrete_size = int(cfg.algo.world_model.discrete_size)
+    stoch_state_size = stochastic_size * discrete_size
+    recurrent_state_size = int(cfg.algo.world_model.recurrent_model.recurrent_state_size)
+    horizon = int(cfg.algo.horizon)
+    gamma = float(cfg.algo.gamma)
+    lmbda = float(cfg.algo.lmbda)
+    ent_coef = float(cfg.algo.actor.ent_coef)
+    moments_cfg = cfg.algo.actor.moments
+    # as the dynamic scan (make_wm_grad_fn): "dots" remat kills the ~40 stacked
+    # (H, T*B, 512) residual buffers of the imagination scan
+    img_unroll = scan_unroll_setting(cfg, "img")
+
+    rssm = world_model.rssm
+    wm_grad = make_wm_grad_fn(runtime, world_model, cfg)
+
+    def train(params, opt_states, moments_state, data, key):
+        # one jax.named_scope per phase, names fixed and disjoint: they are
+        # HLO metadata only, and what a profile (and chipbench/scope_reduce.py)
+        # splits the update's device time by; backward ops inherit the name
+        T, B = data["rewards"].shape[:2]
+        k_dyn, k_img, k_actor = jax.random.split(key, 3)
+
+        # ---------------------------------------------------- world model
+        (rec_loss, wm_aux), wm_grads = wm_grad(params["world_model"], data, k_dyn)
         with jax.named_scope("wm_optim"):
             updates, new_wm_opt = wm_tx.update(wm_grads, opt_states["world_model"], params["world_model"])
             new_wm_params = optax.apply_updates(params["world_model"], updates)
@@ -501,7 +435,7 @@ def make_train_fn(runtime, world_model, actor, critic, txs, cfg, is_continuous, 
                 # backward pass — recomputing the body instead keeps only the
                 # carry + outputs and cuts the loop's memory traffic several-fold
                 (_, _, _), (latents, actions_seq) = jax.lax.scan(
-                    _remat(img_step), (imagined_prior0, recurrent_state0, action0),
+                    scan_remat(img_step), (imagined_prior0, recurrent_state0, action0),
                     (img_noise, act_keys[1:]),
                     unroll=img_unroll,
                 )
@@ -509,25 +443,13 @@ def make_train_fn(runtime, world_model, actor, critic, txs, cfg, is_continuous, 
                 imagined_actions = jnp.concatenate([action0[None], actions_seq], 0)
 
             with jax.named_scope("bh_actor"):
-                traj_head_trees = [
-                    params["critic"],
-                    new_wm_params["reward_model"],
-                    new_wm_params["continue_model"],
-                ]
-                traj_head_modules = (critic, world_model.reward_model, world_model.continue_model)
-                if _heads_fusible(traj_head_trees, traj_head_modules):
-                    v_logits, r_logits, c_logits = fused_mlp_heads(
-                        traj_head_trees, imagined_trajectories,
-                        float(critic.eps), resolve_activation(critic.act), traj_dtype,
-                    )
-                else:
-                    v_logits = critic.apply(params["critic"], imagined_trajectories)
-                    r_logits = world_model.reward_model.apply(
-                        new_wm_params["reward_model"], imagined_trajectories
-                    )
-                    c_logits = world_model.continue_model.apply(
-                        new_wm_params["continue_model"], imagined_trajectories
-                    )
+                v_logits = critic.apply(params["critic"], imagined_trajectories)
+                r_logits = world_model.reward_model.apply(
+                    new_wm_params["reward_model"], imagined_trajectories
+                )
+                c_logits = world_model.continue_model.apply(
+                    new_wm_params["continue_model"], imagined_trajectories
+                )
                 predicted_values = TwoHotEncodingDistribution(v_logits, dims=1).mean
                 predicted_rewards = TwoHotEncodingDistribution(r_logits, dims=1).mean
                 continues = Independent(BernoulliSafeMode(logits=c_logits), 1).mode
@@ -593,16 +515,8 @@ def make_train_fn(runtime, world_model, actor, critic, txs, cfg, is_continuous, 
 
         def critic_loss_fn(critic_params):
             with jax.named_scope("bh_critic"):
-                # _heads_fusible reads only static metadata (tree structure +
-                # leaf shapes), so this is a compile-time specialization
-                if _heads_fusible([critic_params, params["target_critic"]], (critic, critic)):  # jaxlint: disable=retrace-branch
-                    q_logits, tgt_logits = fused_mlp_heads(
-                        [critic_params, params["target_critic"]], traj,
-                        float(critic.eps), resolve_activation(critic.act), traj_dtype,
-                    )
-                else:
-                    q_logits = critic.apply(critic_params, traj)
-                    tgt_logits = critic.apply(params["target_critic"], traj)
+                q_logits = critic.apply(critic_params, traj)
+                tgt_logits = critic.apply(params["target_critic"], traj)
                 qv = TwoHotEncodingDistribution(q_logits, dims=1)
                 predicted_target_values = TwoHotEncodingDistribution(tgt_logits, dims=1).mean
                 value_loss = -qv.log_prob(lambda_vals)
@@ -654,14 +568,128 @@ def make_train_fn(runtime, world_model, actor, critic, txs, cfg, is_continuous, 
     return guard_update(runtime, train, cfg, n_state=3, donate_argnums=(0, 1, 2))
 
 
+@jax.jit
+def _ema(source_params, target_params, tau):  # the program keeps the name a trace of the loop knows it by
+    return optax.incremental_update(source_params, target_params, tau)
+
+
+def target_update_tau(critic_cfg, gradient_steps: int):
+    """``tau`` of the target critics' EMA update that is due before this
+    gradient step (1, a hard copy, before the very first), else ``None``."""
+    if gradient_steps % critic_cfg.per_rank_target_network_update_freq != 0:
+        return None
+    return 1.0 if gradient_steps == 0 else critic_cfg.tau
+
+
+def dv3_optimizers(cfg, precision):
+    """(world model, actor, critic) optimizers of a DreamerV3 update."""
+    return tuple(
+        _make_optimizer(cfg.algo[name].optimizer, cfg.algo[name].clip_gradients, precision)
+        for name in ("world_model", "actor", "critic")
+    )
+
+
+class DV3Learner:
+    """The learner's side of :func:`train_loop`: it owns ``params``,
+    ``opt_states`` and the moments, and offers the loop the player's params,
+    one gradient step, the sentinel's rollback and its part of a checkpoint.
+    It holds no env, ring, timer or logger."""
+
+    test_name = ""  # the label handed to ``test`` when the run ends
+
+    def __init__(self, runtime, cfg, modules, txs, params, opt_states, moments, is_continuous, actions_dim):
+        self.world_model, self.actor, _ = modules
+        self.params, self.opt_states, self.moments = params, opt_states, moments
+        self.gradient_steps = 0
+        self._critic_cfg = cfg.algo.critic
+        # resolved in this module's globals at call time: the benchmark's spy
+        # and chip_smoke.py wrap ``make_train_fn`` by assignment
+        self._train_fn = make_train_fn(runtime, *modules, txs, cfg, is_continuous, actions_dim)
+        self.health = self._train_fn.health
+
+    @classmethod
+    def from_state(cls, runtime, cfg, state, observation_space, actions_dim, is_continuous):
+        *modules, params = build_agent(
+            runtime,
+            actions_dim,
+            is_continuous,
+            cfg,
+            observation_space,
+            state["world_model"] if state else None,
+            state["actor"] if state else None,
+            state["critic"] if state else None,
+            state["target_critic"] if state else None,
+        )
+        # bf16-true: bf16 parameter storage (the EMA target keeps f32 — its
+        # small per-step updates would drown in bf16 rounding); the optimizers
+        # below hold the f32 master copy (optim.master_weights)
+        params = runtime.replicate(runtime.to_param_dtype(params, exclude=("target_critic",)))
+        txs = dv3_optimizers(cfg, runtime.precision)
+        if state is not None:
+            opt_states = restore_opt_states(state["opt_states"], params, runtime.precision)
+            moments = jax.tree_util.tree_map(jnp.asarray, state["moments"])
+        else:
+            opt_states = runtime.replicate(
+                {name: tx.init(params[name]) for name, tx in zip(("world_model", "actor", "critic"), txs)}
+            )
+            moments = runtime.replicate(init_moments())
+        return cls(runtime, cfg, modules, txs, params, opt_states, moments, is_continuous, actions_dim)
+
+    def player_params(self, test: bool = False):
+        """What the player acts with; ``test`` asks for the closing test's."""
+        return {"world_model": self.params["world_model"], "actor": self.params["actor"]}
+
+    def step(self, batch, key):
+        """One gradient step on ``batch``, the target critic's EMA update
+        included where it is due; returns the metrics."""
+        tau = target_update_tau(self._critic_cfg, self.gradient_steps)
+        if tau is not None:
+            self.params["target_critic"] = _ema(self.params["critic"], self.params["target_critic"], tau)
+        self.params, self.opt_states, self.moments, metrics = self._train_fn(
+            self.params, self.opt_states, self.moments, batch, key
+        )
+        self.gradient_steps += 1
+        return metrics
+
+    def restore(self, rolled):
+        """Adopt the checkpoint state the sentinel rolled back to."""
+        self.params = restore_like(self.params, {k: rolled[k] for k in self.params})
+        self.opt_states = restore_like(self.opt_states, rolled["opt_states"])
+        self.moments = restore_like(self.moments, rolled["moments"])
+
+    def checkpoint_state(self):
+        """The agent's part of a checkpoint (and what a rollback loads); the
+        loop adds its own."""
+        return {**self.params, "opt_states": self.opt_states, "moments": self.moments}
+
+
+def resume_states(cfg):
+    """``(state, rb_state)`` of ``checkpoint.resume_from``: the checkpoint a run
+    resumes from, and the same if its ring is to be restored from it."""
+    state = load_checkpoint(cfg.checkpoint.resume_from) if cfg.checkpoint.resume_from else None
+    return state, state if state and cfg.buffer.checkpoint else None
+
+
 @register_algorithm()
 def main(runtime, cfg: Dict[str, Any]):
+    runtime.seed_everything(cfg.seed)
+    state, rb_state = resume_states(cfg)
+    train_loop(runtime, cfg, partial(DV3Learner.from_state, runtime, cfg, state), state, rb_state)
+
+
+def train_loop(runtime, cfg, build_learner, state=None, rb_state=None, random_prefill: bool = True):
+    """The collect -> replay -> train loop of the DreamerV3 family.
+
+    ``build_learner(observation_space, actions_dim, is_continuous)`` gives the
+    learner (see :class:`DV3Learner`) once the envs exist; ``state`` is the
+    checkpoint whose counters the loop resumes from, ``rb_state`` the one its
+    ring (and replay priorities) are restored from; without
+    ``random_prefill`` the player acts from the first step on.  Nothing here
+    asks which algorithm runs."""
     import gymnasium as gym
     from gymnasium.vector import AsyncVectorEnv, AutoresetMode, SyncVectorEnv
 
     world_size = runtime.world_size
-    runtime.seed_everything(cfg.seed)
-    state = load_checkpoint(cfg.checkpoint.resume_from) if cfg.checkpoint.resume_from else None
 
     cfg.env.frame_stack = -1
     if 2 ** int(np.log2(cfg.env.screen_size)) != cfg.env.screen_size:
@@ -719,51 +747,9 @@ def main(runtime, cfg: Dict[str, Any]):
         runtime.print("Decoder MLP keys:", cfg.algo.mlp_keys.decoder)
     obs_keys = cfg.algo.cnn_keys.encoder + cfg.algo.mlp_keys.encoder
 
-    world_model, actor, critic, params = build_agent(
-        runtime,
-        actions_dim,
-        is_continuous,
-        cfg,
-        observation_space,
-        state["world_model"] if state else None,
-        state["actor"] if state else None,
-        state["critic"] if state else None,
-        state["target_critic"] if state else None,
-    )
-    # bf16-true: bf16 parameter storage (the EMA target keeps f32 — its
-    # small per-step updates would drown in bf16 rounding); the optimizers
-    # below hold the f32 master copy (optim.master_weights)
-    params = runtime.replicate(runtime.to_param_dtype(params, exclude=("target_critic",)))
-
-    precision = runtime.precision
-    wm_tx = _make_optimizer(cfg.algo.world_model.optimizer, cfg.algo.world_model.clip_gradients, precision)
-    actor_tx = _make_optimizer(cfg.algo.actor.optimizer, cfg.algo.actor.clip_gradients, precision)
-    critic_tx = _make_optimizer(cfg.algo.critic.optimizer, cfg.algo.critic.clip_gradients, precision)
-    if state is not None:
-        opt_states = restore_opt_states(state["opt_states"], params, runtime.precision)
-        moments_state = jax.tree_util.tree_map(jnp.asarray, state["moments"])
-    else:
-        opt_states = runtime.replicate(
-            {
-                "world_model": wm_tx.init(params["world_model"]),
-                "actor": actor_tx.init(params["actor"]),
-                "critic": critic_tx.init(params["critic"]),
-            }
-        )
-        moments_state = runtime.replicate(init_moments())
-
-    player_params = {"world_model": params["world_model"], "actor": params["actor"]}
-    player = PlayerDV3(
-        world_model,
-        actor,
-        player_params,
-        actions_dim,
-        total_envs,
-        cfg.algo.world_model.stochastic_size,
-        cfg.algo.world_model.recurrent_model.recurrent_state_size,
-        discrete_size=cfg.algo.world_model.discrete_size,
-        decoupled_rssm=bool(cfg.algo.world_model.decoupled_rssm),
-        device=runtime.player_device(player_params),
+    learner = build_learner(observation_space, actions_dim, is_continuous)
+    player = build_player(
+        runtime, learner.world_model, learner.actor, learner.player_params(), actions_dim, total_envs, cfg
     )
 
     if runtime.is_global_zero:
@@ -781,16 +767,14 @@ def main(runtime, cfg: Dict[str, Any]):
         memmap_dir=os.path.join(log_dir, "memmap_buffer", f"rank_{runtime.global_rank}"),
         buffer_cls=SequentialReplayBuffer,
     )
-    if state and cfg.buffer.checkpoint:
-        rb = restore_buffer(state["rb"], memmap=cfg.buffer.memmap)
+    if rb_state:
+        rb = restore_buffer(rb_state["rb"], memmap=cfg.buffer.memmap)
 
     # HBM-resident replay window + on-device sampling (data/device_buffer.py):
     # the host feed samples and re-uploads ~12.6 MB per gradient step — the
     # cache cuts that to one on-device gather, leaving only new frames
     # (n_envs x ~12 KB/step) to upload
-    device_cache = maybe_create_for(
-        cfg, runtime, rb, state if state and cfg.buffer.checkpoint else None
-    )
+    device_cache = maybe_create_for(cfg, runtime, rb, rb_state)
 
     train_step = 0
     train_metrics = None
@@ -815,16 +799,10 @@ def main(runtime, cfg: Dict[str, Any]):
     ckpt_mgr = CheckpointManager(
         runtime, cfg, log_dir, observability=observability, last_checkpoint=last_checkpoint
     )
-    train_fn = make_train_fn(
-        runtime, world_model, actor, critic, (wm_tx, actor_tx, critic_tx), cfg, is_continuous, actions_dim
-    )
-    health = train_fn.health.bind(ckpt_mgr=ckpt_mgr, select=("agent", "opt_states", "moments"))
+    # a rollback loads the agent's keys; the ring and the loop's counters stay live
+    health = learner.health.bind(ckpt_mgr=ckpt_mgr, select=tuple(learner.checkpoint_state()))
     if health.enabled:
         observability.health_stats = health.stats
-
-    @jax.jit
-    def _ema(critic_params, target_params, tau):
-        return optax.incremental_update(critic_params, target_params, tau)
 
     step_data: Dict[str, np.ndarray] = {}
     obs = envs.reset(seed=cfg.seed)[0]
@@ -836,7 +814,6 @@ def main(runtime, cfg: Dict[str, Any]):
     step_data["is_first"] = np.ones_like(step_data["terminated"])
     player.init_states()
 
-    cumulative_per_rank_gradient_steps = 0
     metric_fetch_gate = MetricFetchGate(cfg.metric.get("fetch_every", 1))
     heartbeat_t = time.perf_counter()
     for iter_num in range(start_iter, total_iters + 1):
@@ -844,7 +821,7 @@ def main(runtime, cfg: Dict[str, Any]):
         policy_step += policy_steps_per_iter
 
         with timer("Time/env_interaction_time", SumMetric, sync_on_compute=False):
-            if iter_num <= learning_starts and cfg.checkpoint.resume_from is None:
+            if random_prefill and iter_num <= learning_starts and cfg.checkpoint.resume_from is None:
                 real_actions = actions = np.array(envs.action_space.sample())
                 if not is_continuous:
                     actions = np.concatenate(
@@ -943,23 +920,6 @@ def main(runtime, cfg: Dict[str, Any]):
             ratio_steps = policy_step - prefill_steps * policy_steps_per_iter
             per_rank_gradient_steps = ratio(ratio_steps / world_size)
             if per_rank_gradient_steps > 0:
-                def _grad_step(batch):
-                    nonlocal params, opt_states, moments_state, train_metrics
-                    nonlocal cumulative_per_rank_gradient_steps
-                    if (
-                        cumulative_per_rank_gradient_steps
-                        % cfg.algo.critic.per_rank_target_network_update_freq
-                        == 0
-                    ):
-                        tau = 1.0 if cumulative_per_rank_gradient_steps == 0 else cfg.algo.critic.tau
-                        params["target_critic"] = _ema(
-                            params["critic"], params["target_critic"], tau
-                        )
-                    params, opt_states, moments_state, train_metrics = train_fn(
-                        params, opt_states, moments_state, batch, runtime.next_key()
-                    )
-                    cumulative_per_rank_gradient_steps += 1
-
                 with sequence_batches(
                     rb, device_cache, runtime, per_rank_gradient_steps,
                     cfg.algo.per_rank_batch_size * world_size,
@@ -967,13 +927,11 @@ def main(runtime, cfg: Dict[str, Any]):
                 ) as feed:
                     with timer("Time/train_time", SumMetric, sync_on_compute=cfg.metric.sync_on_compute):
                         for batch in feed:
-                            _grad_step(batch)
+                            train_metrics = learner.step(batch, runtime.next_key())
                     train_step += world_size
                 rolled = health.tick()
                 if rolled is not None:
-                    params = restore_like(params, rolled["agent"])
-                    opt_states = restore_like(opt_states, rolled["opt_states"])
-                    moments_state = restore_like(moments_state, rolled["moments"])
+                    learner.restore(rolled)
                 # The update donated the tree the player was acting with, so
                 # the player is handed the new one here, directly after the
                 # dispatch (and after a rollback put restored arrays in its
@@ -982,7 +940,7 @@ def main(runtime, cfg: Dict[str, Any]):
                 # own arrays; on the host CPU it is one device-to-host copy,
                 # which also waits for the update that produced them.
                 with timer("Time/params_refresh"):
-                    player.params = {"world_model": params["world_model"], "actor": params["actor"]}
+                    player.params = learner.player_params()
                 # metric.fetch_every amortizes the per-iteration device
                 # sync of the losses dict on high-latency links (1 =
                 # reference cadence; the aggregator still averages over the
@@ -1007,7 +965,7 @@ def main(runtime, cfg: Dict[str, Any]):
                         logger.log_metrics(aggregator.compute(), policy_step)
                         aggregator.reset()
                     logger.log_metrics(
-                        {"Params/replay_ratio": cumulative_per_rank_gradient_steps * world_size / policy_step},
+                        {"Params/replay_ratio": learner.gradient_steps * world_size / policy_step},
                         policy_step,
                     )
                     if not timer.disabled:
@@ -1040,7 +998,7 @@ def main(runtime, cfg: Dict[str, Any]):
                 runtime.print(
                     f"Rank-0: heartbeat policy_step={policy_step}, "
                     f"sps={(policy_step - last_log) / max(heartbeat_now - heartbeat_t, 1e-9):.2f}, "
-                    f"gradient_steps={cumulative_per_rank_gradient_steps}" + split
+                    f"gradient_steps={learner.gradient_steps}" + split
                 )
                 heartbeat_t = heartbeat_now
                 last_log = policy_step
@@ -1049,12 +1007,7 @@ def main(runtime, cfg: Dict[str, Any]):
         # ------------------------------------------------------ checkpoint
         def _ckpt_state():
             ckpt_state = {
-                "world_model": params["world_model"],
-                "actor": params["actor"],
-                "critic": params["critic"],
-                "target_critic": params["target_critic"],
-                "opt_states": opt_states,
-                "moments": moments_state,
+                **learner.checkpoint_state(),
                 "ratio": ratio.state_dict(),
                 "iter_num": iter_num * world_size,
                 "batch_size": cfg.algo.per_rank_batch_size * world_size,
@@ -1082,7 +1035,8 @@ def main(runtime, cfg: Dict[str, Any]):
     envs.close()
     observability.close()
     if runtime.is_global_zero and cfg.algo.run_test:
-        test_rew = test(player, runtime, cfg, log_dir, greedy=False)
+        player.params = learner.player_params(test=True)
+        test_rew = test(player, runtime, cfg, log_dir, learner.test_name, greedy=False)
         if logger:
             logger.log_metrics({"Test/cumulative_reward": test_rew}, policy_step)
     if logger:

@@ -32,6 +32,7 @@ from sheeprl_tpu.algos.dreamer_v3.agent import (
     WorldModel,
     _ln_enabled,
     _ln_eps,
+    build_player,
     uniform_out_init,
 )
 from sheeprl_tpu.algos.dreamer_v3.agent import build_agent as dv3_build_agent
@@ -161,16 +162,4 @@ def make_player(
     module and re-ties weights, p2e_dv3_finetuning.py:350-353)."""
     actor_params = params["actor_exploration"] if actor_type == "exploration" else params["actor_task"]
     player_params = {"world_model": params["world_model"], "actor": actor_params}
-    player = PlayerDV3(
-        world_model,
-        actor,
-        player_params,
-        actions_dim,
-        num_envs,
-        cfg.algo.world_model.stochastic_size,
-        cfg.algo.world_model.recurrent_model.recurrent_state_size,
-        discrete_size=cfg.algo.world_model.discrete_size,
-        actor_type=actor_type,
-        device=runtime.player_device(player_params),
-    )
-    return player
+    return build_player(runtime, world_model, actor, player_params, actions_dim, num_envs, cfg, actor_type)

@@ -16,9 +16,8 @@ One jitted gradient step composed of:
 
 from __future__ import annotations
 
-import os
 from functools import partial
-from typing import Any, Dict, Sequence
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
@@ -26,45 +25,26 @@ import numpy as np
 import optax
 
 from sheeprl_tpu.algos.dreamer_v3.agent import RSSM
-from sheeprl_tpu.algos.dreamer_v3.dreamer_v3 import _make_optimizer
-from sheeprl_tpu.algos.dreamer_v3.loss import reconstruction_loss
-from sheeprl_tpu.algos.dreamer_v3.utils import (
-    compute_lambda_values,
-    init_moments,
-    prepare_obs,
-    test,
-    update_moments,
+from sheeprl_tpu.algos.dreamer_v3.dreamer_v3 import (
+    _make_optimizer,
+    _ema,
+    make_wm_grad_fn,
+    resume_states,
+    target_update_tau,
+    train_loop,
 )
-from sheeprl_tpu.algos.p2e_dv3.agent import build_agent, make_player
-from sheeprl_tpu.config import instantiate
-from sheeprl_tpu.data.device_buffer import maybe_create_for, sequence_batches
-from sheeprl_tpu.data.buffers import EnvIndependentReplayBuffer, SequentialReplayBuffer
-from sheeprl_tpu.envs.wrappers import RestartOnException
-from sheeprl_tpu.ops.dyn_bptt import (
-    dyn_bptt_setting,
-    dyn_rssm_sequence,
-    extract_dyn_params,
-    rssm_dyn_bptt_eligible,
-)
-from sheeprl_tpu.obs import setup_observability, trace_scope
-from sheeprl_tpu.resilience import CheckpointManager
+from sheeprl_tpu.algos.dreamer_v3.utils import compute_lambda_values, init_moments, update_moments
+from sheeprl_tpu.algos.p2e_dv3.agent import build_agent
+from sheeprl_tpu.optim import restore_opt_states
 from sheeprl_tpu.resilience.sentinel import guard_update, restore_like
-from sheeprl_tpu.utils.callback import load_checkpoint, restore_buffer
 from sheeprl_tpu.utils.distribution import (
     BernoulliSafeMode,
     Independent,
-    MSEDistribution,
     OneHotCategorical,
-    SymlogDistribution,
     TwoHotEncodingDistribution,
 )
-from sheeprl_tpu.utils.env import make_env
-from sheeprl_tpu.utils.logger import get_log_dir, get_logger
-from sheeprl_tpu.utils.metric import MetricAggregator, SumMetric
+from sheeprl_tpu.utils.metric import MetricAggregator
 from sheeprl_tpu.utils.registry import register_algorithm
-from sheeprl_tpu.utils.timer import timer
-from sheeprl_tpu.utils.utils import fetch_actions, MetricFetchGate, device_get_metrics, Ratio, save_configs, scan_remat, scan_unroll_setting
-from sheeprl_tpu.optim import restore_opt_states
 
 sg = jax.lax.stop_gradient
 
@@ -74,10 +54,6 @@ def make_train_fn(
 ):
     """Build the single jitted P2E-DV3 exploration gradient step."""
     wm_tx, ens_tx, actor_task_tx, critic_task_tx, actor_expl_tx, critics_expl_txs = txs
-    cnn_keys = tuple(cfg.algo.cnn_keys.encoder)
-    mlp_keys = tuple(cfg.algo.mlp_keys.encoder)
-    cnn_keys_dec = tuple(cfg.algo.cnn_keys.decoder)
-    mlp_keys_dec = tuple(cfg.algo.mlp_keys.decoder)
     stochastic_size = int(cfg.algo.world_model.stochastic_size)
     discrete_size = int(cfg.algo.world_model.discrete_size)
     stoch_state_size = stochastic_size * discrete_size
@@ -86,20 +62,13 @@ def make_train_fn(
     gamma = float(cfg.algo.gamma)
     lmbda = float(cfg.algo.lmbda)
     ent_coef = float(cfg.algo.actor.ent_coef)
-    kl_dynamic = float(cfg.algo.world_model.kl_dynamic)
-    kl_representation = float(cfg.algo.world_model.kl_representation)
-    kl_free_nats = float(cfg.algo.world_model.kl_free_nats)
-    kl_regularizer = float(cfg.algo.world_model.kl_regularizer)
-    continue_scale_factor = float(cfg.algo.world_model.continue_scale_factor)
-    decoupled = bool(cfg.algo.world_model.decoupled_rssm)
     moments_cfg = cfg.algo.actor.moments
     intrinsic_reward_multiplier = float(cfg.algo.intrinsic_reward_multiplier)
     critic_names = tuple(critics_cfg.keys())
     weights_sum = sum(c["weight"] for c in critics_cfg.values())
 
     rssm = world_model.rssm
-    # efficient-BPTT dynamic scan (see dreamer_v3.py / ops/dyn_bptt.py)
-    dyn_bptt = dyn_bptt_setting(cfg) and rssm_dyn_bptt_eligible(rssm)
+    wm_grad = make_wm_grad_fn(runtime, world_model, cfg, detach_heads=True)
 
     def _update_moments(state, x):
         return update_moments(
@@ -114,28 +83,30 @@ def make_train_fn(
     def _imagine(actor_params, wm_params, imagined_prior0, recurrent_state0, key):
         """(H+1, TB, L) trajectories + (H+1, TB, A) actions, actions sampled
         from the given actor at every imagined state."""
-        keys = jax.random.split(key, horizon + 1)
-        latent0 = jnp.concatenate([imagined_prior0, recurrent_state0], -1)
-        acts0, _ = actor.apply(actor_params, sg(latent0), False, keys[0])
-        action0 = jnp.concatenate(acts0, -1)
+        with jax.named_scope("bh_imagine"):
+            keys = jax.random.split(key, horizon + 1)
+            latent0 = jnp.concatenate([imagined_prior0, recurrent_state0], -1)
+            acts0, _ = actor.apply(actor_params, sg(latent0), False, keys[0])
+            action0 = jnp.concatenate(acts0, -1)
 
-        def img_step(carry, kk):
-            prior, rec, action = carry
-            k_im, k_act = jax.random.split(kk)
-            imagined_prior, rec = rssm.apply(
-                wm_params["rssm"], prior, rec, action, k_im, method=RSSM.imagination
+            @jax.named_scope("bh_imagine")  # again inside the scan body, as dreamer_v3's
+            def img_step(carry, kk):
+                prior, rec, action = carry
+                k_im, k_act = jax.random.split(kk)
+                imagined_prior, rec = rssm.apply(
+                    wm_params["rssm"], prior, rec, action, k_im, method=RSSM.imagination
+                )
+                imagined_prior = imagined_prior.reshape(-1, stoch_state_size)
+                latent = jnp.concatenate([imagined_prior, rec], -1)
+                acts, _ = actor.apply(actor_params, sg(latent), False, k_act)
+                action = jnp.concatenate(acts, -1)
+                return (imagined_prior, rec, action), (latent, action)
+
+            _, (latents, actions_seq) = jax.lax.scan(
+                img_step, (imagined_prior0, recurrent_state0, action0), keys[1:]
             )
-            imagined_prior = imagined_prior.reshape(-1, stoch_state_size)
-            latent = jnp.concatenate([imagined_prior, rec], -1)
-            acts, _ = actor.apply(actor_params, sg(latent), False, k_act)
-            action = jnp.concatenate(acts, -1)
-            return (imagined_prior, rec, action), (latent, action)
-
-        _, (latents, actions_seq) = jax.lax.scan(
-            img_step, (imagined_prior0, recurrent_state0, action0), keys[1:]
-        )
-        traj = jnp.concatenate([latent0[None], latents], 0)
-        acts = jnp.concatenate([action0[None], actions_seq], 0)
+            traj = jnp.concatenate([latent0[None], latents], 0)
+            acts = jnp.concatenate([action0[None], actions_seq], 0)
         return traj, acts
 
     def _policy_objective(actor_params, traj, imagined_actions, advantage, key):
@@ -158,6 +129,7 @@ def make_train_fn(
         return objective, entropy
 
     def _critic_update(critic_params, target_params, tx, opt_state, traj, lambda_vals, discount):
+        @jax.named_scope("bh_critic")
         def loss_fn(cp):
             qv = TwoHotEncodingDistribution(critic.apply(cp, traj[:-1]), dims=1)
             target_values = TwoHotEncodingDistribution(
@@ -167,174 +139,21 @@ def make_train_fn(
             return jnp.mean(value_loss * discount[:-1].squeeze(-1))
 
         loss, grads = jax.value_and_grad(loss_fn)(critic_params)
-        updates, new_opt = tx.update(grads, opt_state, critic_params)
-        return optax.apply_updates(critic_params, updates), new_opt, loss, optax.global_norm(grads)
+        with jax.named_scope("critic_optim"):
+            updates, new_opt = tx.update(grads, opt_state, critic_params)
+            new_params = optax.apply_updates(critic_params, updates)
+        return new_params, new_opt, loss, optax.global_norm(grads)
 
     def train(params, opt_states, moments_task, moments_expl, data, key):
         T, B = data["rewards"].shape[:2]
         k_dyn, k_img_e, k_pol_e, k_img_t, k_pol_t = jax.random.split(key, 5)
 
-        batch_obs = {k: data[k] / 255.0 - 0.5 for k in cnn_keys}
-        batch_obs.update({k: data[k] for k in mlp_keys})
-        is_first = data["is_first"].at[0].set(1.0)
-        batch_actions = jnp.concatenate(
-            [jnp.zeros_like(data["actions"][:1]), data["actions"][:-1]], axis=0
-        )
-        # sampling RNG hoisted out of the scan body into one batched gumbel
-        # draw (the scan bodies are latency-bound; see dreamer_v3)
-        dyn_noise_q = jax.random.gumbel(
-            k_dyn, (T, B, stochastic_size, discrete_size), jnp.float32
-        )
-
         # ---------------------------------------------------- world model
-        def wm_loss_fn(wm_params):
-            embedded_obs = world_model.encoder.apply(wm_params["encoder"], batch_obs)
-            init_states = rssm.apply(wm_params["rssm"], (B,), method=RSSM.get_initial_states)
-            init_states = (init_states[0], init_states[1].reshape(B, -1))
-
-            if decoupled:
-                # DecoupledRSSM: the posterior depends only on obs, so it
-                # batches over the whole sequence and the scan body is just
-                # the gated recurrent step (see dreamer_v3.py's branch)
-                posteriors_logits, posteriors = rssm.apply(
-                    wm_params["rssm"], embedded_obs, None, noise=dyn_noise_q,
-                    method=RSSM._representation,
-                )
-                prev_posteriors = jnp.concatenate(
-                    [jnp.zeros_like(posteriors[:1]), posteriors[:-1]], 0
-                )
-
-                # input projection batched over the sequence; only the gated
-                # GRU cell stays sequential (RSSM.recurrent_features_seq)
-                feats = rssm.apply(
-                    wm_params["rssm"], prev_posteriors, batch_actions,
-                    is_first, init_states[1],
-                    method=RSSM.recurrent_features_seq,
-                )
-
-                if rssm.seq_scan_eligible(int(feats.shape[-1])):
-                    # whole recurrence in ONE Pallas kernel (see dreamer_v3)
-                    recurrent_states = rssm.apply(
-                        wm_params["rssm"], feats, is_first, init_states[0],
-                        method=RSSM.gru_sequence_gated,
-                    )
-                else:
-                    def dyn_step_dec(recurrent_state, inp):
-                        feat, first = inp
-                        recurrent_state = rssm.apply(
-                            wm_params["rssm"], feat, recurrent_state, first,
-                            init_states[0], method=RSSM.gru_step_gated,
-                        )
-                        return recurrent_state, recurrent_state
-
-                    _, recurrent_states = jax.lax.scan(
-                        scan_remat(dyn_step_dec),
-                        jnp.zeros((B, recurrent_state_size)),
-                        (feats, is_first),
-                        unroll=scan_unroll_setting(cfg, "dyn"),
-                    )
-            else:
-                emb_proj = rssm.apply(
-                    wm_params["rssm"], embedded_obs, method=RSSM.representation_embed_proj
-                )
-
-                if dyn_bptt:
-                    recurrent_states, zst_, posteriors_logits = dyn_rssm_sequence(
-                        jnp.zeros((B, stochastic_size * discrete_size)),
-                        jnp.zeros((B, recurrent_state_size)),
-                        batch_actions,
-                        emb_proj,
-                        is_first,
-                        dyn_noise_q,
-                        init_states[0],
-                        init_states[1],
-                        extract_dyn_params(wm_params["rssm"], recurrent_state_size),
-                        eps_proj=rssm.eps,
-                        eps_rep=rssm.eps,
-                        unimix=rssm.unimix,
-                        discrete=discrete_size,
-                        matmul_dtype=rssm.dtype,
-                        unroll=scan_unroll_setting(cfg, "dyn"),
-                    )
-                    posteriors = zst_.reshape(T, B, stochastic_size, discrete_size)
-                else:
-                    def dyn_step(carry, inp):
-                        posterior, recurrent_state = carry
-                        action, emb, first, nq_t = inp
-                        recurrent_state, posterior, posterior_logits = rssm.apply(
-                            wm_params["rssm"], posterior, recurrent_state, action, emb, first,
-                            init_states, noise=nq_t, method=RSSM.dynamic_posterior,
-                        )
-                        return (posterior, recurrent_state), (
-                            recurrent_state, posterior, posterior_logits,
-                        )
-
-                    init = (
-                        jnp.zeros((B, stochastic_size, discrete_size)),
-                        jnp.zeros((B, recurrent_state_size)),
-                    )
-                    _, (recurrent_states, posteriors, posteriors_logits) = jax.lax.scan(
-                        scan_remat(dyn_step),
-                        init, (batch_actions, emb_proj, is_first, dyn_noise_q),
-                        unroll=scan_unroll_setting(cfg, "dyn"),
-                    )
-            # prior logits for the KL, batched over the stacked recurrent
-            # states (the prior SAMPLE is unused by the world-model loss)
-            priors_logits, _ = rssm.apply(
-                wm_params["rssm"], recurrent_states, None, sample_state=False,
-                method=RSSM._transition,
-            )
-            latent_states = jnp.concatenate([posteriors.reshape(T, B, -1), recurrent_states], -1)
-            reconstructed_obs = world_model.observation_model.apply(
-                wm_params["observation_model"], latent_states
-            )
-            po = {
-                k: MSEDistribution(reconstructed_obs[k], dims=len(reconstructed_obs[k].shape[2:]))
-                for k in cnn_keys_dec
-            }
-            po.update(
-                {
-                    k: SymlogDistribution(reconstructed_obs[k], dims=len(reconstructed_obs[k].shape[2:]))
-                    for k in mlp_keys_dec
-                }
-            )
-            # reward/continue heads read detached latents in the exploration
-            # phase (reference p2e_dv3_exploration.py:160-163)
-            pr = TwoHotEncodingDistribution(
-                world_model.reward_model.apply(wm_params["reward_model"], sg(latent_states)), dims=1
-            )
-            pc = Independent(
-                BernoulliSafeMode(
-                    logits=world_model.continue_model.apply(wm_params["continue_model"], sg(latent_states))
-                ),
-                1,
-            )
-            continue_targets = 1 - data["terminated"]
-            pl = priors_logits.reshape(T, B, stochastic_size, discrete_size)
-            psl = posteriors_logits.reshape(T, B, stochastic_size, discrete_size)
-            rec_loss, kl, state_loss, reward_loss, observation_loss, continue_loss = reconstruction_loss(
-                po, batch_obs, pr, data["rewards"], pl, psl,
-                kl_dynamic, kl_representation, kl_free_nats, kl_regularizer,
-                pc, continue_targets, continue_scale_factor,
-            )
-            aux = {
-                "posteriors": posteriors,
-                "recurrent_states": recurrent_states,
-                "posteriors_logits": psl,
-                "priors_logits": pl,
-                "kl": kl,
-                "state_loss": state_loss,
-                "reward_loss": reward_loss,
-                "observation_loss": observation_loss,
-                "continue_loss": continue_loss,
-            }
-            return rec_loss, aux
-
-        (rec_loss, wm_aux), wm_grads = jax.value_and_grad(wm_loss_fn, has_aux=True)(
-            params["world_model"]
-        )
-        updates, new_wm_opt = wm_tx.update(wm_grads, opt_states["world_model"], params["world_model"])
-        new_wm_params = optax.apply_updates(params["world_model"], updates)
+        # dreamer_v3's loss; the reward/continue heads read DETACHED latents
+        (rec_loss, wm_aux), wm_grads = wm_grad(params["world_model"], data, k_dyn)
+        with jax.named_scope("wm_optim"):
+            updates, new_wm_opt = wm_tx.update(wm_grads, opt_states["world_model"], params["world_model"])
+            new_wm_params = optax.apply_updates(params["world_model"], updates)
 
         posts_flat = sg(wm_aux["posteriors"]).reshape(T, B, stoch_state_size)
         rec_states = sg(wm_aux["recurrent_states"])
@@ -366,66 +185,68 @@ def make_train_fn(
             traj, imagined_actions = _imagine(
                 actor_params, new_wm_params, imagined_prior0, recurrent_state0, k_img_e
             )
-            continues = Independent(
-                BernoulliSafeMode(
-                    logits=world_model.continue_model.apply(new_wm_params["continue_model"], traj)
-                ),
-                1,
-            ).mode
-            continues = jnp.concatenate([true_continue[None], continues[1:]], 0)
+            with jax.named_scope("bh_actor"):
+                continues = Independent(
+                    BernoulliSafeMode(
+                        logits=world_model.continue_model.apply(new_wm_params["continue_model"], traj)
+                    ),
+                    1,
+                ).mode
+                continues = jnp.concatenate([true_continue[None], continues[1:]], 0)
 
-            advantages = []
-            new_moments = {}
-            per_critic = {}
-            for name in critic_names:
-                ccfg = critics_cfg[name]
-                predicted_values = TwoHotEncodingDistribution(
-                    critic.apply(params["critics_exploration"][name]["module"], traj), dims=1
-                ).mean
-                if ccfg["reward_type"] == "intrinsic":
-                    ens_traj_in = jnp.concatenate([sg(traj), sg(imagined_actions)], -1)
-                    preds = jax.vmap(lambda p: ensemble.apply(p, ens_traj_in))(new_ens_params)
-                    # torch's Tensor.var is unbiased (ddof=1), reference :285
-                    reward = preds.var(0, ddof=1).mean(-1, keepdims=True) * intrinsic_reward_multiplier
-                else:
-                    reward = TwoHotEncodingDistribution(
-                        world_model.reward_model.apply(new_wm_params["reward_model"], traj), dims=1
+                advantages = []
+                new_moments = {}
+                per_critic = {}
+                for name in critic_names:
+                    ccfg = critics_cfg[name]
+                    predicted_values = TwoHotEncodingDistribution(
+                        critic.apply(params["critics_exploration"][name]["module"], traj), dims=1
                     ).mean
-                lambda_vals = compute_lambda_values(
-                    reward[1:], predicted_values[1:], continues[1:] * gamma, lmbda
-                )
-                nm, offset, invscale = _update_moments(moments_expl[name], lambda_vals)
-                new_moments[name] = nm
-                normed_lambda = (lambda_vals - offset) / invscale
-                normed_baseline = (predicted_values[:-1] - offset) / invscale
-                advantages.append((normed_lambda - normed_baseline) * ccfg["weight"] / weights_sum)
-                per_critic[name] = {
-                    "lambda_values": sg(lambda_vals),
-                    "predicted_values_mean": sg(predicted_values).mean(),
-                    "reward_mean": sg(reward).mean() if ccfg["reward_type"] == "intrinsic" else None,
-                }
-            advantage = jnp.stack(advantages, 0).sum(0)
-            discount = sg(jnp.cumprod(continues * gamma, 0) / gamma)
+                    if ccfg["reward_type"] == "intrinsic":
+                        ens_traj_in = jnp.concatenate([sg(traj), sg(imagined_actions)], -1)
+                        preds = jax.vmap(lambda p: ensemble.apply(p, ens_traj_in))(new_ens_params)
+                        # torch's Tensor.var is unbiased (ddof=1), reference :285
+                        reward = preds.var(0, ddof=1).mean(-1, keepdims=True) * intrinsic_reward_multiplier
+                    else:
+                        reward = TwoHotEncodingDistribution(
+                            world_model.reward_model.apply(new_wm_params["reward_model"], traj), dims=1
+                        ).mean
+                    lambda_vals = compute_lambda_values(
+                        reward[1:], predicted_values[1:], continues[1:] * gamma, lmbda
+                    )
+                    nm, offset, invscale = _update_moments(moments_expl[name], lambda_vals)
+                    new_moments[name] = nm
+                    normed_lambda = (lambda_vals - offset) / invscale
+                    normed_baseline = (predicted_values[:-1] - offset) / invscale
+                    advantages.append((normed_lambda - normed_baseline) * ccfg["weight"] / weights_sum)
+                    per_critic[name] = {
+                        "lambda_values": sg(lambda_vals),
+                        "predicted_values_mean": sg(predicted_values).mean(),
+                        "reward_mean": sg(reward).mean() if ccfg["reward_type"] == "intrinsic" else None,
+                    }
+                advantage = jnp.stack(advantages, 0).sum(0)
+                discount = sg(jnp.cumprod(continues * gamma, 0) / gamma)
 
-            objective, entropy = _policy_objective(
-                actor_params, traj, imagined_actions, advantage, k_pol_e
-            )
-            policy_loss = -jnp.mean(sg(discount[:-1]) * (objective + entropy[..., None][:-1]))
-            aux = {
-                "traj": sg(traj),
-                "discount": discount,
-                "per_critic": per_critic,
-                "moments": new_moments,
-            }
+                objective, entropy = _policy_objective(
+                    actor_params, traj, imagined_actions, advantage, k_pol_e
+                )
+                policy_loss = -jnp.mean(sg(discount[:-1]) * (objective + entropy[..., None][:-1]))
+                aux = {
+                    "traj": sg(traj),
+                    "discount": discount,
+                    "per_critic": per_critic,
+                    "moments": new_moments,
+                }
             return policy_loss, aux
 
         (policy_loss_expl, expl_aux), actor_expl_grads = jax.value_and_grad(
             actor_expl_loss_fn, has_aux=True
         )(params["actor_exploration"])
-        updates, new_actor_expl_opt = actor_expl_tx.update(
-            actor_expl_grads, opt_states["actor_exploration"], params["actor_exploration"]
-        )
-        new_actor_expl = optax.apply_updates(params["actor_exploration"], updates)
+        with jax.named_scope("actor_optim"):
+            updates, new_actor_expl_opt = actor_expl_tx.update(
+                actor_expl_grads, opt_states["actor_exploration"], params["actor_exploration"]
+            )
+            new_actor_expl = optax.apply_updates(params["actor_exploration"], updates)
 
         # per-critic exploration value updates
         new_critics_expl = {}
@@ -455,46 +276,48 @@ def make_train_fn(
             traj, imagined_actions = _imagine(
                 actor_params, new_wm_params, imagined_prior0, recurrent_state0, k_img_t
             )
-            predicted_values = TwoHotEncodingDistribution(
-                critic.apply(params["critic_task"], traj), dims=1
-            ).mean
-            predicted_rewards = TwoHotEncodingDistribution(
-                world_model.reward_model.apply(new_wm_params["reward_model"], traj), dims=1
-            ).mean
-            continues = Independent(
-                BernoulliSafeMode(
-                    logits=world_model.continue_model.apply(new_wm_params["continue_model"], traj)
-                ),
-                1,
-            ).mode
-            continues = jnp.concatenate([true_continue[None], continues[1:]], 0)
-            lambda_vals = compute_lambda_values(
-                predicted_rewards[1:], predicted_values[1:], continues[1:] * gamma, lmbda
-            )
-            nm, offset, invscale = _update_moments(moments_task, lambda_vals)
-            normed_lambda = (lambda_vals - offset) / invscale
-            normed_baseline = (predicted_values[:-1] - offset) / invscale
-            advantage = normed_lambda - normed_baseline
-            discount = sg(jnp.cumprod(continues * gamma, 0) / gamma)
-            objective, entropy = _policy_objective(
-                actor_params, traj, imagined_actions, advantage, k_pol_t
-            )
-            policy_loss = -jnp.mean(sg(discount[:-1]) * (objective + entropy[..., None][:-1]))
-            aux = {
-                "traj": sg(traj),
-                "discount": discount,
-                "lambda_values": sg(lambda_vals),
-                "moments": nm,
-            }
+            with jax.named_scope("bh_actor"):
+                predicted_values = TwoHotEncodingDistribution(
+                    critic.apply(params["critic_task"], traj), dims=1
+                ).mean
+                predicted_rewards = TwoHotEncodingDistribution(
+                    world_model.reward_model.apply(new_wm_params["reward_model"], traj), dims=1
+                ).mean
+                continues = Independent(
+                    BernoulliSafeMode(
+                        logits=world_model.continue_model.apply(new_wm_params["continue_model"], traj)
+                    ),
+                    1,
+                ).mode
+                continues = jnp.concatenate([true_continue[None], continues[1:]], 0)
+                lambda_vals = compute_lambda_values(
+                    predicted_rewards[1:], predicted_values[1:], continues[1:] * gamma, lmbda
+                )
+                nm, offset, invscale = _update_moments(moments_task, lambda_vals)
+                normed_lambda = (lambda_vals - offset) / invscale
+                normed_baseline = (predicted_values[:-1] - offset) / invscale
+                advantage = normed_lambda - normed_baseline
+                discount = sg(jnp.cumprod(continues * gamma, 0) / gamma)
+                objective, entropy = _policy_objective(
+                    actor_params, traj, imagined_actions, advantage, k_pol_t
+                )
+                policy_loss = -jnp.mean(sg(discount[:-1]) * (objective + entropy[..., None][:-1]))
+                aux = {
+                    "traj": sg(traj),
+                    "discount": discount,
+                    "lambda_values": sg(lambda_vals),
+                    "moments": nm,
+                }
             return policy_loss, aux
 
         (policy_loss_task, task_aux), actor_task_grads = jax.value_and_grad(
             actor_task_loss_fn, has_aux=True
         )(params["actor_task"])
-        updates, new_actor_task_opt = actor_task_tx.update(
-            actor_task_grads, opt_states["actor_task"], params["actor_task"]
-        )
-        new_actor_task = optax.apply_updates(params["actor_task"], updates)
+        with jax.named_scope("actor_optim"):
+            updates, new_actor_task_opt = actor_task_tx.update(
+                actor_task_grads, opt_states["actor_task"], params["actor_task"]
+            )
+            new_actor_task = optax.apply_updates(params["actor_task"], updates)
 
         new_critic_task, new_critic_task_opt, value_loss_task, critic_task_grads = _critic_update(
             params["critic_task"],
@@ -586,416 +409,119 @@ def expand_exploration_metric_keys(cfg, critics_cfg) -> None:
             metrics.pop(g, None)
 
 
+class ExplorationLearner:
+    """Plan2Explore's exploration learner for ``dreamer_v3.train_loop`` (the
+    contract is ``dreamer_v3.DV3Learner``'s): the player acts with the
+    exploration actor and the run is tested zero-shot with the task actor;
+    every exploration critic has an EMA target beside the task critic's."""
+
+    test_name = "zero-shot"
+
+    def __init__(self, runtime, cfg, state, observation_space, actions_dim, is_continuous):
+        self.world_model, self.actor, critic, ensemble, critics_cfg, params = build_agent(
+            runtime,
+            actions_dim,
+            is_continuous,
+            cfg,
+            observation_space,
+            state["world_model"] if state else None,
+            state["ensembles"] if state else None,
+            state["actor_task"] if state else None,
+            state["critic_task"] if state else None,
+            state["target_critic_task"] if state else None,
+            state["actor_exploration"] if state else None,
+            state["critics_exploration"] if state else None,
+        )
+        # the trainable exploration critics get bf16 storage like everything
+        # else; only their nested EMA target_module subtrees stay f32
+        params = runtime.replicate(
+            runtime.to_param_dtype(params, exclude=("target_critic_task", "target_module"))
+        )
+
+        def tx(part):
+            return _make_optimizer(cfg.algo[part].optimizer, cfg.algo[part].clip_gradients, runtime.precision)
+
+        wm_tx, ens_tx, actor_task_tx, critic_task_tx = tx("world_model"), tx("ensembles"), tx("actor"), tx("critic")
+        actor_expl_tx, critics_expl_txs = tx("actor"), {name: tx("critic") for name in critics_cfg}
+        if state is not None:
+            params_for_opt = {
+                **params,
+                "critics_exploration": {n: p["module"] for n, p in params["critics_exploration"].items()},
+            }
+            opt_states = restore_opt_states(state["opt_states"], params_for_opt, runtime.precision)
+            moments_task = jax.tree_util.tree_map(jnp.asarray, state["moments_task"])
+            moments_expl = jax.tree_util.tree_map(jnp.asarray, state["moments_exploration"])
+        else:
+            opt_states = runtime.replicate(
+                {
+                    "world_model": wm_tx.init(params["world_model"]),
+                    "ensembles": ens_tx.init(params["ensembles"]),
+                    "actor_task": actor_task_tx.init(params["actor_task"]),
+                    "critic_task": critic_task_tx.init(params["critic_task"]),
+                    "actor_exploration": actor_expl_tx.init(params["actor_exploration"]),
+                    "critics_exploration": {
+                        name: critics_expl_txs[name].init(params["critics_exploration"][name]["module"])
+                        for name in critics_cfg
+                    },
+                }
+            )
+            moments_task = runtime.replicate(init_moments())
+            moments_expl = runtime.replicate({name: init_moments() for name in critics_cfg})
+        self.params, self.opt_states = params, opt_states
+        self.moments_task, self.moments_exploration = moments_task, moments_expl
+        self.gradient_steps = 0
+        self._critic_cfg = cfg.algo.critic
+        if not MetricAggregator.disabled:
+            expand_exploration_metric_keys(cfg, critics_cfg)
+        self._train_fn = make_train_fn(
+            runtime,
+            self.world_model,
+            self.actor,
+            critic,
+            ensemble,
+            critics_cfg,
+            (wm_tx, ens_tx, actor_task_tx, critic_task_tx, actor_expl_tx, critics_expl_txs),
+            cfg,
+            is_continuous,
+            actions_dim,
+        )
+        self.health = self._train_fn.health
+
+    def player_params(self, test: bool = False):
+        actor = "actor_task" if test else "actor_exploration"
+        return {"world_model": self.params["world_model"], "actor": self.params[actor]}
+
+    def step(self, batch, key):
+        tau = target_update_tau(self._critic_cfg, self.gradient_steps)
+        if tau is not None:
+            params = self.params
+            params["target_critic_task"] = _ema(params["critic_task"], params["target_critic_task"], tau)
+            for critic in params["critics_exploration"].values():
+                critic["target_module"] = _ema(critic["module"], critic["target_module"], tau)
+        self.params, self.opt_states, self.moments_task, self.moments_exploration, metrics = self._train_fn(
+            self.params, self.opt_states, self.moments_task, self.moments_exploration, batch, key
+        )
+        self.gradient_steps += 1
+        return metrics
+
+    def restore(self, rolled):
+        self.params = restore_like(self.params, {k: rolled[k] for k in self.params})
+        self.opt_states = restore_like(self.opt_states, rolled["opt_states"])
+        self.moments_task = restore_like(self.moments_task, rolled["moments_task"])
+        self.moments_exploration = restore_like(self.moments_exploration, rolled["moments_exploration"])
+
+    def checkpoint_state(self):
+        return {
+            **self.params,
+            "opt_states": self.opt_states,
+            "moments_task": self.moments_task,
+            "moments_exploration": self.moments_exploration,
+        }
+
+
 @register_algorithm()
 def main(runtime, cfg: Dict[str, Any]):
-    import gymnasium as gym
-    from gymnasium.vector import AsyncVectorEnv, AutoresetMode, SyncVectorEnv
-
-    world_size = runtime.world_size
     runtime.seed_everything(cfg.seed)
-    state = load_checkpoint(cfg.checkpoint.resume_from) if cfg.checkpoint.resume_from else None
-
-    cfg.env.frame_stack = -1
+    state, rb_state = resume_states(cfg)
     cfg.algo.player.actor_type = "exploration"
-    if 2 ** int(np.log2(cfg.env.screen_size)) != cfg.env.screen_size:
-        raise ValueError(f"The screen size must be a power of 2, got: {cfg.env.screen_size}")
-
-    logger = get_logger(runtime, cfg)
-    log_dir = get_log_dir(runtime, cfg.root_dir, cfg.run_name)
-    runtime.print(f"Log dir: {log_dir}")
-    observability = setup_observability(runtime, cfg, log_dir, logger=logger)
-    if logger:
-        logger.log_hyperparams(cfg)
-
-    total_envs = cfg.env.num_envs * world_size
-    thunks = [
-        partial(
-            RestartOnException,
-            make_env(cfg, cfg.seed + i, 0, log_dir if runtime.is_global_zero else None, "train", vector_env_idx=i),
-        )
-        for i in range(total_envs)
-    ]
-    envs = (
-        SyncVectorEnv(thunks, autoreset_mode=AutoresetMode.SAME_STEP)
-        if cfg.env.sync_env
-        else AsyncVectorEnv(thunks, context="spawn", autoreset_mode=AutoresetMode.SAME_STEP)
-    )
-    action_space = envs.single_action_space
-    observation_space = envs.single_observation_space
-
-    is_continuous = isinstance(action_space, gym.spaces.Box)
-    is_multidiscrete = isinstance(action_space, gym.spaces.MultiDiscrete)
-    actions_dim = tuple(
-        action_space.shape
-        if is_continuous
-        else (action_space.nvec.tolist() if is_multidiscrete else [action_space.n])
-    )
-    clip_rewards_fn = (lambda r: np.tanh(r)) if cfg.env.clip_rewards else (lambda r: r)
-    if not isinstance(observation_space, gym.spaces.Dict):
-        raise RuntimeError(f"Unexpected observation type, should be of type Dict, got: {observation_space}")
-    if len(set(cfg.algo.cnn_keys.decoder) - set(cfg.algo.cnn_keys.encoder)) > 0:
-        raise RuntimeError("The CNN keys of the decoder must be contained in the encoder ones")
-    if len(set(cfg.algo.mlp_keys.decoder) - set(cfg.algo.mlp_keys.encoder)) > 0:
-        raise RuntimeError("The MLP keys of the decoder must be contained in the encoder ones")
-    obs_keys = cfg.algo.cnn_keys.encoder + cfg.algo.mlp_keys.encoder
-
-    world_model, actor, critic, ensemble, critics_cfg, params = build_agent(
-        runtime,
-        actions_dim,
-        is_continuous,
-        cfg,
-        observation_space,
-        state["world_model"] if state else None,
-        state["ensembles"] if state else None,
-        state["actor_task"] if state else None,
-        state["critic_task"] if state else None,
-        state["target_critic_task"] if state else None,
-        state["actor_exploration"] if state else None,
-        state["critics_exploration"] if state else None,
-    )
-    # the trainable exploration critics get bf16 storage like everything
-    # else; only their nested EMA target_module subtrees stay f32
-    params = runtime.replicate(
-        runtime.to_param_dtype(params, exclude=("target_critic_task", "target_module"))
-    )
-    precision = runtime.precision
-
-    wm_tx = _make_optimizer(cfg.algo.world_model.optimizer, cfg.algo.world_model.clip_gradients, precision)
-    ens_tx = _make_optimizer(cfg.algo.ensembles.optimizer, cfg.algo.ensembles.clip_gradients, precision)
-    actor_task_tx = _make_optimizer(cfg.algo.actor.optimizer, cfg.algo.actor.clip_gradients, precision)
-    critic_task_tx = _make_optimizer(cfg.algo.critic.optimizer, cfg.algo.critic.clip_gradients, precision)
-    actor_expl_tx = _make_optimizer(cfg.algo.actor.optimizer, cfg.algo.actor.clip_gradients, precision)
-    critics_expl_txs = {
-        name: _make_optimizer(cfg.algo.critic.optimizer, cfg.algo.critic.clip_gradients, precision)
-        for name in critics_cfg
-    }
-    if state is not None:
-        params_for_opt = {
-            **params,
-            "critics_exploration": {
-                n: p["module"] for n, p in params["critics_exploration"].items()
-            },
-        }
-        opt_states = restore_opt_states(state["opt_states"], params_for_opt, runtime.precision)
-        moments_task = jax.tree_util.tree_map(jnp.asarray, state["moments_task"])
-        moments_expl = jax.tree_util.tree_map(jnp.asarray, state["moments_exploration"])
-    else:
-        opt_states = runtime.replicate(
-            {
-                "world_model": wm_tx.init(params["world_model"]),
-                "ensembles": ens_tx.init(params["ensembles"]),
-                "actor_task": actor_task_tx.init(params["actor_task"]),
-                "critic_task": critic_task_tx.init(params["critic_task"]),
-                "actor_exploration": actor_expl_tx.init(params["actor_exploration"]),
-                "critics_exploration": {
-                    name: critics_expl_txs[name].init(params["critics_exploration"][name]["module"])
-                    for name in critics_cfg
-                },
-            }
-        )
-        moments_task = runtime.replicate(init_moments())
-        moments_expl = runtime.replicate({name: init_moments() for name in critics_cfg})
-
-    player = make_player(
-        runtime, world_model, actor, params, actions_dim, total_envs, cfg, "exploration"
-    )
-
-    if runtime.is_global_zero:
-        save_configs(cfg, log_dir)
-
-    aggregator = None
-    if not MetricAggregator.disabled:
-        expand_exploration_metric_keys(cfg, critics_cfg)
-        aggregator = instantiate(dict(cfg.metric.aggregator))
-
-    buffer_size = cfg.buffer.size // total_envs if not cfg.dry_run else 2
-    rb = EnvIndependentReplayBuffer(
-        max(buffer_size, 2),
-        n_envs=total_envs,
-        memmap=cfg.buffer.memmap,
-        memmap_dir=os.path.join(log_dir, "memmap_buffer", f"rank_{runtime.global_rank}"),
-        buffer_cls=SequentialReplayBuffer,
-    )
-    if state and cfg.buffer.checkpoint:
-        rb = restore_buffer(state["rb"], memmap=cfg.buffer.memmap)
-    # HBM-resident replay window + on-device sampling (data/device_buffer.py)
-    device_cache = maybe_create_for(cfg, runtime, rb, state)
-
-    train_step = 0
-    last_train = 0
-    start_iter = (state["iter_num"] // world_size) + 1 if state else 1
-    policy_step = state["iter_num"] * cfg.env.num_envs if state else 0
-    last_log = state["last_log"] if state else 0
-    last_checkpoint = state["last_checkpoint"] if state else 0
-    policy_steps_per_iter = int(total_envs)
-    total_iters = int(cfg.algo.total_steps // policy_steps_per_iter) if not cfg.dry_run else 1
-    learning_starts = cfg.algo.learning_starts // policy_steps_per_iter if not cfg.dry_run else 0
-    prefill_steps = learning_starts - int(learning_starts > 0)
-    if state:
-        cfg.algo.per_rank_batch_size = state["batch_size"] // world_size
-        learning_starts += start_iter
-        prefill_steps += start_iter
-
-    ratio = Ratio(cfg.algo.replay_ratio, pretrain_steps=cfg.algo.per_rank_pretrain_steps)
-    if state:
-        ratio.load_state_dict(state["ratio"])
-
-    ckpt_mgr = CheckpointManager(
-        runtime, cfg, log_dir, observability=observability, last_checkpoint=last_checkpoint
-    )
-    train_fn = make_train_fn(
-        runtime,
-        world_model,
-        actor,
-        critic,
-        ensemble,
-        critics_cfg,
-        (wm_tx, ens_tx, actor_task_tx, critic_task_tx, actor_expl_tx, critics_expl_txs),
-        cfg,
-        is_continuous,
-        actions_dim,
-    )
-    # training health: params components are checkpointed under their own
-    # top-level keys (no "agent"), so the rollback select mirrors them
-    health = train_fn.health.bind(
-        ckpt_mgr=ckpt_mgr, select=tuple(params) + ("opt_states", "moments_task", "moments_exploration",)
-    )
-    if health.enabled:
-        observability.health_stats = health.stats
-
-    @jax.jit
-    def _ema(src, dst, tau):
-        return optax.incremental_update(src, dst, tau)
-
-    step_data: Dict[str, np.ndarray] = {}
-    obs = envs.reset(seed=cfg.seed)[0]
-    for k in obs_keys:
-        step_data[k] = obs[k][np.newaxis]
-    step_data["rewards"] = np.zeros((1, total_envs, 1))
-    step_data["truncated"] = np.zeros((1, total_envs, 1))
-    step_data["terminated"] = np.zeros((1, total_envs, 1))
-    step_data["is_first"] = np.ones_like(step_data["terminated"])
-    player.init_states()
-
-    cumulative_per_rank_gradient_steps = 0
-    metric_fetch_gate = MetricFetchGate(cfg.metric.get("fetch_every", 1))
-    for iter_num in range(start_iter, total_iters + 1):
-        observability.on_iteration(policy_step)
-        policy_step += policy_steps_per_iter
-
-        with timer("Time/env_interaction_time", SumMetric, sync_on_compute=False):
-            if iter_num <= learning_starts and cfg.checkpoint.resume_from is None:
-                real_actions = actions = np.array(envs.action_space.sample())
-                if not is_continuous:
-                    actions = np.concatenate(
-                        [
-                            np.eye(act_dim, dtype=np.float32)[act]
-                            for act, act_dim in zip(actions.reshape(len(actions_dim), -1), actions_dim)
-                        ],
-                        axis=-1,
-                    )
-            else:
-                prepared = prepare_obs(obs, cnn_keys=cfg.algo.cnn_keys.encoder, num_envs=total_envs)
-                mask = {k: v for k, v in prepared.items() if k.startswith("mask")} or None
-                action_list = player.get_actions(prepared, runtime.next_key(), mask=mask)
-                actions, real_actions = fetch_actions(
-                    action_list, actions_dim, is_continuous, total_envs
-                )
-
-            step_data["actions"] = np.asarray(actions).reshape(1, total_envs, -1)
-            rb.add(step_data, validate_args=cfg.buffer.validate_args)
-            if device_cache is not None:
-                device_cache.add(step_data)
-
-            next_obs, rewards, terminated, truncated, infos = envs.step(
-                np.asarray(real_actions).reshape(envs.action_space.shape)
-            )
-            dones = np.logical_or(terminated, truncated).astype(np.uint8)
-
-        step_data["is_first"] = np.zeros_like(step_data["terminated"])
-        if "restart_on_exception" in infos:
-            for i, agent_roe in enumerate(infos["restart_on_exception"]):
-                if agent_roe and not dones[i]:
-                    last_inserted_idx = (rb.buffer[i]._pos - 1) % rb.buffer[i].buffer_size
-                    rb.buffer[i]["terminated"][last_inserted_idx] = np.zeros_like(
-                        rb.buffer[i]["terminated"][last_inserted_idx]
-                    )
-                    rb.buffer[i]["truncated"][last_inserted_idx] = np.ones_like(
-                        rb.buffer[i]["truncated"][last_inserted_idx]
-                    )
-                    rb.buffer[i]["is_first"][last_inserted_idx] = np.zeros_like(
-                        rb.buffer[i]["is_first"][last_inserted_idx]
-                    )
-                    step_data["is_first"][:, i] = np.ones_like(step_data["is_first"][:, i])
-
-        if cfg.metric.log_level > 0 and "final_info" in infos:
-            ep = infos["final_info"].get("episode")
-            if ep is not None:
-                for i in np.nonzero(infos["final_info"]["_episode"])[0]:
-                    if aggregator and not aggregator.disabled:
-                        aggregator.update("Rewards/rew_avg", float(ep["r"][i]))
-                        aggregator.update("Game/ep_len_avg", float(ep["l"][i]))
-                    runtime.print(f"Rank-0: policy_step={policy_step}, reward_env_{i}={float(ep['r'][i])}")
-
-        real_next_obs = {k: np.array(v) for k, v in next_obs.items()}
-        if "final_obs" in infos:
-            for idx in np.nonzero(infos["_final_obs"])[0]:
-                for k, v in infos["final_obs"][idx].items():
-                    real_next_obs[k][idx] = v
-
-        for k in obs_keys:
-            step_data[k] = next_obs[k][np.newaxis]
-        obs = next_obs
-
-        rewards = rewards.reshape((1, total_envs, -1))
-        step_data["terminated"] = terminated.reshape((1, total_envs, -1)).astype(np.float32)
-        step_data["truncated"] = truncated.reshape((1, total_envs, -1)).astype(np.float32)
-        step_data["rewards"] = clip_rewards_fn(rewards)
-
-        dones_idxes = dones.nonzero()[0].tolist()
-        reset_envs = len(dones_idxes)
-        if reset_envs > 0:
-            reset_data = {}
-            for k in obs_keys:
-                reset_data[k] = (real_next_obs[k][dones_idxes])[np.newaxis]
-            reset_data["terminated"] = step_data["terminated"][:, dones_idxes]
-            reset_data["truncated"] = step_data["truncated"][:, dones_idxes]
-            reset_data["actions"] = np.zeros((1, reset_envs, int(np.sum(actions_dim))))
-            reset_data["rewards"] = step_data["rewards"][:, dones_idxes]
-            reset_data["is_first"] = np.zeros_like(reset_data["terminated"])
-            rb.add(reset_data, dones_idxes, validate_args=cfg.buffer.validate_args)
-            if device_cache is not None:
-                device_cache.add(reset_data, dones_idxes)
-
-            step_data["rewards"][:, dones_idxes] = np.zeros_like(reset_data["rewards"])
-            step_data["terminated"][:, dones_idxes] = np.zeros_like(step_data["terminated"][:, dones_idxes])
-            step_data["truncated"][:, dones_idxes] = np.zeros_like(step_data["truncated"][:, dones_idxes])
-            step_data["is_first"][:, dones_idxes] = np.ones_like(step_data["is_first"][:, dones_idxes])
-            player.init_states(dones_idxes)
-
-        # ------------------------------------------------------ train
-        if iter_num >= learning_starts:
-            ratio_steps = policy_step - prefill_steps * policy_steps_per_iter
-            per_rank_gradient_steps = ratio(ratio_steps / world_size)
-            if per_rank_gradient_steps > 0:
-                with sequence_batches(
-                    rb, device_cache, runtime, per_rank_gradient_steps,
-                    cfg.algo.per_rank_batch_size * world_size,
-                    cfg.algo.per_rank_sequence_length, runtime.next_key(),
-                ) as feed:
-                    with timer("Time/train_time", SumMetric, sync_on_compute=cfg.metric.sync_on_compute):
-                        for batch in feed:
-                            if (
-                                cumulative_per_rank_gradient_steps
-                                % cfg.algo.critic.per_rank_target_network_update_freq
-                                == 0
-                            ):
-                                tau = 1.0 if cumulative_per_rank_gradient_steps == 0 else cfg.algo.critic.tau
-                                params["target_critic_task"] = _ema(
-                                    params["critic_task"], params["target_critic_task"], tau
-                                )
-                                for name in critics_cfg:
-                                    params["critics_exploration"][name]["target_module"] = _ema(
-                                        params["critics_exploration"][name]["module"],
-                                        params["critics_exploration"][name]["target_module"],
-                                        tau,
-                                    )
-                            params, opt_states, moments_task, moments_expl, train_metrics = train_fn(
-                                params, opt_states, moments_task, moments_expl, batch, runtime.next_key()
-                            )
-                            cumulative_per_rank_gradient_steps += 1
-                    train_step += world_size
-                rolled = health.tick()
-                if rolled is not None:
-                    params = restore_like(params, {k: rolled[k] for k in params})
-                    opt_states = restore_like(opt_states, rolled["opt_states"])
-                    moments_task = restore_like(moments_task, rolled["moments_task"])
-                    moments_expl = restore_like(moments_expl, rolled["moments_exploration"])
-                player.params = {
-                    "world_model": params["world_model"],
-                    "actor": params["actor_exploration"],
-                }
-                if aggregator and not aggregator.disabled and metric_fetch_gate():
-                    with trace_scope("block_until_ready"):
-                        fetched_metrics = device_get_metrics(train_metrics)
-                    for k, v in fetched_metrics.items():
-                        aggregator.update(k, v)
-
-        # ------------------------------------------------------ logging
-        if cfg.metric.log_level > 0 and (
-            policy_step - last_log >= cfg.metric.log_every or iter_num == total_iters
-        ):
-            observability.on_log(policy_step, train_step)
-            if logger:
-                if aggregator and not aggregator.disabled:
-                    logger.log_metrics(aggregator.compute(), policy_step)
-                    aggregator.reset()
-                logger.log_metrics(
-                    {"Params/replay_ratio": cumulative_per_rank_gradient_steps * world_size / policy_step},
-                    policy_step,
-                )
-                if not timer.disabled:
-                    timer_metrics = timer.compute()
-                    if timer_metrics.get("Time/train_time", 0) > 0:
-                        logger.log_metrics(
-                            {"Time/sps_train": (train_step - last_train) / timer_metrics["Time/train_time"]},
-                            policy_step,
-                        )
-                    if timer_metrics.get("Time/env_interaction_time", 0) > 0:
-                        logger.log_metrics(
-                            {
-                                "Time/sps_env_interaction": (
-                                    (policy_step - last_log) / world_size * cfg.env.action_repeat
-                                )
-                                / timer_metrics["Time/env_interaction_time"]
-                            },
-                            policy_step,
-                        )
-                    timer.reset()
-            last_log = policy_step
-            last_train = train_step
-
-        # ------------------------------------------------------ checkpoint
-        def _ckpt_state():
-            ckpt_state = {
-                "world_model": params["world_model"],
-                "actor_task": params["actor_task"],
-                "critic_task": params["critic_task"],
-                "target_critic_task": params["target_critic_task"],
-                "actor_exploration": params["actor_exploration"],
-                "critics_exploration": params["critics_exploration"],
-                "ensembles": params["ensembles"],
-                "opt_states": opt_states,
-                "moments_task": moments_task,
-                "moments_exploration": moments_expl,
-                "ratio": ratio.state_dict(),
-                "iter_num": iter_num * world_size,
-                "batch_size": cfg.algo.per_rank_batch_size * world_size,
-                "last_log": last_log,
-                "last_checkpoint": ckpt_mgr.last_checkpoint,
-            }
-            if cfg.buffer.checkpoint:
-                ckpt_state["rb"] = rb
-            return ckpt_state
-
-        ckpt_mgr.maybe_checkpoint(
-            policy_step=policy_step, is_last=iter_num == total_iters, state_fn=_ckpt_state
-        )
-        if ckpt_mgr.preempted:
-            runtime.print(
-                f"Preemption signal: emergency checkpoint written, stopping at iter {iter_num}"
-            )
-            break
-
-    ckpt_mgr.close()
-    envs.close()
-    observability.close()
-    # task test zero-shot
-    if runtime.is_global_zero and cfg.algo.run_test:
-        player.params = {"world_model": params["world_model"], "actor": params["actor_task"]}
-        player.actor_type = "task"
-        test_rew = test(player, runtime, cfg, log_dir, "zero-shot", greedy=False)
-        if logger:
-            logger.log_metrics({"Test/cumulative_reward": test_rew}, policy_step)
-    if logger:
-        logger.finalize()
+    train_loop(runtime, cfg, partial(ExplorationLearner, runtime, cfg, state), state, rb_state)

@@ -11,33 +11,30 @@ switches to the task actor (reference p2e_dv3_finetuning.py:350-353)."""
 
 from __future__ import annotations
 
-import os
 import pathlib
+from functools import partial
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-import optax
 
-from sheeprl_tpu.algos.dreamer_v3.dreamer_v3 import _make_optimizer, make_train_fn
-from sheeprl_tpu.algos.dreamer_v3.utils import init_moments, prepare_obs, test
-from sheeprl_tpu.algos.p2e_dv3.agent import build_agent, make_player
-from sheeprl_tpu.config import instantiate
+from sheeprl_tpu.algos.dreamer_v3.dreamer_v3 import DV3Learner, dv3_optimizers, train_loop
+from sheeprl_tpu.algos.dreamer_v3.utils import init_moments
+from sheeprl_tpu.algos.p2e_dv3.agent import build_agent
 from sheeprl_tpu.config.compose import yaml_load
-from sheeprl_tpu.data.device_buffer import maybe_create_for, sequence_batches
-from sheeprl_tpu.data.buffers import EnvIndependentReplayBuffer, SequentialReplayBuffer
-from sheeprl_tpu.obs import setup_observability, trace_scope
-from sheeprl_tpu.resilience import CheckpointManager
-from sheeprl_tpu.resilience.sentinel import guard_update, restore_like
-from sheeprl_tpu.utils.callback import load_checkpoint, restore_buffer
-from sheeprl_tpu.utils.env import make_env
-from sheeprl_tpu.utils.logger import get_log_dir, get_logger
-from sheeprl_tpu.utils.metric import MetricAggregator, SumMetric
-from sheeprl_tpu.utils.registry import register_algorithm
-from sheeprl_tpu.utils.timer import timer
-from sheeprl_tpu.utils.utils import fetch_actions, MetricFetchGate, device_get_metrics, Ratio, dotdict, save_configs
 from sheeprl_tpu.optim import restore_opt_states
+from sheeprl_tpu.resilience.sentinel import restore_like
+from sheeprl_tpu.utils.callback import load_checkpoint
+from sheeprl_tpu.utils.registry import register_algorithm
+from sheeprl_tpu.utils.utils import dotdict
+
+# the DV3 update's names for the task behaviour -> the names a P2E checkpoint holds them under
+CKPT_NAMES = {
+    "world_model": "world_model",
+    "actor": "actor_task",
+    "critic": "critic_task",
+    "target_critic": "target_critic_task",
+}
 
 
 def _load_exploration_cfg(ckpt_path: str) -> dotdict:
@@ -51,12 +48,78 @@ def _load_exploration_cfg(ckpt_path: str) -> dotdict:
         return dotdict(yaml_load(f.read()))
 
 
+class FinetuningLearner(DV3Learner):
+    """DreamerV3's learner on the TASK behaviour of a Plan2Explore agent,
+    checkpointed under the exploration phase's names.  The player collects
+    with ``algo.player.actor_type``'s actor until the first gradient step, then
+    with the task actor (reference p2e_dv3_finetuning.py:350-353)."""
+
+    test_name = "few-shot"
+
+    @classmethod
+    def from_state(cls, runtime, cfg, state, observation_space, actions_dim, is_continuous):
+        world_model, actor, critic, _, _, p2e_params = build_agent(
+            runtime,
+            actions_dim,
+            is_continuous,
+            cfg,
+            observation_space,
+            state["world_model"],
+            state.get("ensembles"),
+            state["actor_task"],
+            state["critic_task"],
+            state["target_critic_task"],
+            state["actor_exploration"],
+            state.get("critics_exploration"),
+        )
+        p2e_params = runtime.replicate(runtime.to_param_dtype(p2e_params, exclude=("target_critic_task",)))
+        # DV3-shaped view for the task training step; the pytrees are shared, not copied
+        params = {name: p2e_params[saved] for name, saved in CKPT_NAMES.items()}
+        txs = dv3_optimizers(cfg, runtime.precision)
+        saved_opt = state.get("opt_states", {})
+        opt_states = {
+            name: (
+                restore_opt_states(saved_opt[CKPT_NAMES[name]], params[name], runtime.precision)
+                if CKPT_NAMES[name] in saved_opt
+                else runtime.replicate(tx.init(params[name]))
+            )
+            for name, tx in zip(("world_model", "actor", "critic"), txs)
+        }
+        moments = (
+            jax.tree_util.tree_map(jnp.asarray, state["moments_task"])
+            if "moments_task" in state
+            else runtime.replicate(init_moments())
+        )
+        learner = cls(
+            runtime, cfg, (world_model, actor, critic), txs, params, opt_states, moments, is_continuous, actions_dim
+        )
+        learner.actor_exploration = p2e_params["actor_exploration"]
+        learner.explores_first = str(cfg.algo.player.actor_type) == "exploration"
+        return learner
+
+    def player_params(self, test: bool = False):
+        if self.explores_first and self.gradient_steps == 0 and not test:
+            return {"world_model": self.params["world_model"], "actor": self.actor_exploration}
+        return super().player_params()
+
+    def restore(self, rolled):
+        self.params = restore_like(self.params, {name: rolled[saved] for name, saved in CKPT_NAMES.items()})
+        self.opt_states = restore_like(
+            self.opt_states, {name: rolled["opt_states"][CKPT_NAMES[name]] for name in self.opt_states}
+        )
+        self.moments = restore_like(self.moments, rolled["moments_task"])
+
+    def checkpoint_state(self):
+        return {
+            **{saved: self.params[name] for name, saved in CKPT_NAMES.items()},
+            "actor_exploration": self.actor_exploration,
+            "opt_states": {CKPT_NAMES[name]: opt for name, opt in self.opt_states.items()},
+            "moments_task": self.moments,
+        }
+
+
 @register_algorithm()
 def main(runtime, cfg: Dict[str, Any]):
-    import gymnasium as gym
-    from gymnasium.vector import AsyncVectorEnv, AutoresetMode, SyncVectorEnv
-
-    world_size = runtime.world_size
     runtime.seed_everything(cfg.seed)
 
     ckpt_path = cfg.checkpoint.exploration_ckpt_path
@@ -74,366 +137,18 @@ def main(runtime, cfg: Dict[str, Any]):
         if key in exploration_cfg.algo:
             cfg.algo[key] = exploration_cfg.algo[key]
     cfg.env.clip_rewards = exploration_cfg.env.clip_rewards
-    if cfg.buffer.get("load_from_exploration", False) and exploration_cfg.buffer.checkpoint:
+    load_ring = bool(cfg.buffer.get("load_from_exploration", False))
+    if load_ring and exploration_cfg.buffer.checkpoint:
         cfg.env.num_envs = exploration_cfg.env.num_envs
-    cfg.env.frame_stack = -1
 
-    logger = get_logger(runtime, cfg)
-    log_dir = get_log_dir(runtime, cfg.root_dir, cfg.run_name)
-    runtime.print(f"Log dir: {log_dir}")
-    observability = setup_observability(runtime, cfg, log_dir, logger=logger)
-    if logger:
-        logger.log_hyperparams(cfg)
-
-    total_envs = cfg.env.num_envs * world_size
-    thunks = [
-        make_env(cfg, cfg.seed + i, 0, log_dir if runtime.is_global_zero else None, "train", vector_env_idx=i)
-        for i in range(total_envs)
-    ]
-    envs = (
-        SyncVectorEnv(thunks, autoreset_mode=AutoresetMode.SAME_STEP)
-        if cfg.env.sync_env
-        else AsyncVectorEnv(thunks, context="spawn", autoreset_mode=AutoresetMode.SAME_STEP)
-    )
-    action_space = envs.single_action_space
-    observation_space = envs.single_observation_space
-
-    is_continuous = isinstance(action_space, gym.spaces.Box)
-    is_multidiscrete = isinstance(action_space, gym.spaces.MultiDiscrete)
-    actions_dim = tuple(
-        action_space.shape
-        if is_continuous
-        else (action_space.nvec.tolist() if is_multidiscrete else [action_space.n])
-    )
-    clip_rewards_fn = (lambda r: np.tanh(r)) if cfg.env.clip_rewards else (lambda r: r)
-    if not isinstance(observation_space, gym.spaces.Dict):
-        raise RuntimeError(f"Unexpected observation type, should be of type Dict, got: {observation_space}")
-    obs_keys = cfg.algo.cnn_keys.encoder + cfg.algo.mlp_keys.encoder
-
-    world_model, actor, critic, ensemble, critics_cfg, params = build_agent(
+    # a fresh finetuning run starts its counters at zero whatever the
+    # exploration checkpoint holds, acts with the player from the first step
+    # on, and takes over the exploration ring only if asked to
+    train_loop(
         runtime,
-        actions_dim,
-        is_continuous,
         cfg,
-        observation_space,
-        state["world_model"],
-        state.get("ensembles"),
-        state["actor_task"],
-        state["critic_task"],
-        state["target_critic_task"],
-        state["actor_exploration"],
-        state.get("critics_exploration"),
+        partial(FinetuningLearner.from_state, runtime, cfg, state),
+        state if resume_from_checkpoint else None,
+        state if (resume_from_checkpoint or load_ring) and "rb" in state else None,
+        random_prefill=False,
     )
-    params = runtime.replicate(runtime.to_param_dtype(params, exclude=("target_critic_task",)))
-    precision = runtime.precision
-
-    wm_tx = _make_optimizer(cfg.algo.world_model.optimizer, cfg.algo.world_model.clip_gradients, precision)
-    actor_tx = _make_optimizer(cfg.algo.actor.optimizer, cfg.algo.actor.clip_gradients, precision)
-    critic_tx = _make_optimizer(cfg.algo.critic.optimizer, cfg.algo.critic.clip_gradients, precision)
-    saved_opt = state.get("opt_states", {})
-    opt_states = {
-        "world_model": (
-            restore_opt_states(saved_opt["world_model"], params["world_model"], runtime.precision)
-            if "world_model" in saved_opt
-            else runtime.replicate(wm_tx.init(params["world_model"]))
-        ),
-        "actor": (
-            restore_opt_states(saved_opt["actor_task"], params["actor_task"], runtime.precision)
-            if "actor_task" in saved_opt
-            else runtime.replicate(actor_tx.init(params["actor_task"]))
-        ),
-        "critic": (
-            restore_opt_states(saved_opt["critic_task"], params["critic_task"], runtime.precision)
-            if "critic_task" in saved_opt
-            else runtime.replicate(critic_tx.init(params["critic_task"]))
-        ),
-    }
-    moments_state = (
-        jax.tree_util.tree_map(jnp.asarray, state["moments_task"])
-        if "moments_task" in state
-        else runtime.replicate(init_moments())
-    )
-
-    # DV3-shaped param view for the task training step; the pytrees are
-    # shared, not copied
-    dv3_params = {
-        "world_model": params["world_model"],
-        "actor": params["actor_task"],
-        "critic": params["critic_task"],
-        "target_critic": params["target_critic_task"],
-    }
-
-    actor_type = str(cfg.algo.player.actor_type)
-    player = make_player(runtime, world_model, actor, params, actions_dim, total_envs, cfg, actor_type)
-
-    if runtime.is_global_zero:
-        save_configs(cfg, log_dir)
-
-    aggregator = None
-    if not MetricAggregator.disabled:
-        aggregator = instantiate(dict(cfg.metric.aggregator))
-
-    buffer_size = cfg.buffer.size // total_envs if not cfg.dry_run else 2
-    rb = EnvIndependentReplayBuffer(
-        max(buffer_size, 2),
-        n_envs=total_envs,
-        memmap=cfg.buffer.memmap,
-        memmap_dir=os.path.join(log_dir, "memmap_buffer", f"rank_{runtime.global_rank}"),
-        buffer_cls=SequentialReplayBuffer,
-    )
-    restored_rb = False
-    if (resume_from_checkpoint or cfg.buffer.get("load_from_exploration", False)) and "rb" in state:
-        rb = restore_buffer(state["rb"], memmap=cfg.buffer.memmap)
-        restored_rb = True
-
-    # HBM-resident replay window + on-device sampling (data/device_buffer.py)
-    device_cache = maybe_create_for(
-        cfg, runtime, rb, state if restored_rb else None
-    )
-    train_step = 0
-    last_train = 0
-    start_iter = (state["iter_num"] // world_size) + 1 if resume_from_checkpoint else 1
-    policy_step = state["iter_num"] * cfg.env.num_envs if resume_from_checkpoint else 0
-    last_log = state["last_log"] if resume_from_checkpoint else 0
-    last_checkpoint = state["last_checkpoint"] if resume_from_checkpoint else 0
-    policy_steps_per_iter = int(total_envs)
-    total_iters = int(cfg.algo.total_steps // policy_steps_per_iter) if not cfg.dry_run else 1
-    learning_starts = cfg.algo.learning_starts // policy_steps_per_iter if not cfg.dry_run else 0
-    prefill_steps = learning_starts - int(learning_starts > 0)
-    if resume_from_checkpoint:
-        cfg.algo.per_rank_batch_size = state["batch_size"] // world_size
-        learning_starts += start_iter
-        prefill_steps += start_iter
-
-    ratio = Ratio(cfg.algo.replay_ratio, pretrain_steps=cfg.algo.per_rank_pretrain_steps)
-    if resume_from_checkpoint:
-        ratio.load_state_dict(state["ratio"])
-
-    ckpt_mgr = CheckpointManager(
-        runtime, cfg, log_dir, observability=observability, last_checkpoint=last_checkpoint
-    )
-    train_fn = make_train_fn(
-        runtime, world_model, actor, critic, (wm_tx, actor_tx, critic_tx), cfg, is_continuous, actions_dim
-    )
-    health = train_fn.health.bind(
-        ckpt_mgr=ckpt_mgr,
-        select=("world_model", "actor_task", "critic_task", "opt_states", "moments_task"),
-    )
-    if health.enabled:
-        observability.health_stats = health.stats
-
-    @jax.jit
-    def _ema(critic_params, target_params, tau):
-        return optax.incremental_update(critic_params, target_params, tau)
-
-    step_data: Dict[str, np.ndarray] = {}
-    obs = envs.reset(seed=cfg.seed)[0]
-    for k in obs_keys:
-        step_data[k] = obs[k][np.newaxis]
-    step_data["rewards"] = np.zeros((1, total_envs, 1))
-    step_data["truncated"] = np.zeros((1, total_envs, 1))
-    step_data["terminated"] = np.zeros((1, total_envs, 1))
-    step_data["is_first"] = np.ones_like(step_data["terminated"])
-    player.init_states()
-
-    cumulative_per_rank_gradient_steps = 0
-    metric_fetch_gate = MetricFetchGate(cfg.metric.get("fetch_every", 1))
-    for iter_num in range(start_iter, total_iters + 1):
-        observability.on_iteration(policy_step)
-        policy_step += policy_steps_per_iter
-
-        with timer("Time/env_interaction_time", SumMetric, sync_on_compute=False):
-            prepared = prepare_obs(obs, cnn_keys=cfg.algo.cnn_keys.encoder, num_envs=total_envs)
-            mask = {k: v for k, v in prepared.items() if k.startswith("mask")} or None
-            action_list = player.get_actions(prepared, runtime.next_key(), mask=mask)
-            actions, real_actions = fetch_actions(
-                action_list, actions_dim, is_continuous, total_envs
-            )
-
-            step_data["actions"] = np.asarray(actions).reshape(1, total_envs, -1)
-            rb.add(step_data, validate_args=cfg.buffer.validate_args)
-            if device_cache is not None:
-                device_cache.add(step_data)
-
-            next_obs, rewards, terminated, truncated, infos = envs.step(
-                np.asarray(real_actions).reshape(envs.action_space.shape)
-            )
-            dones = np.logical_or(terminated, truncated).astype(np.uint8)
-
-        step_data["is_first"] = np.zeros_like(step_data["terminated"])
-
-        if cfg.metric.log_level > 0 and "final_info" in infos:
-            ep = infos["final_info"].get("episode")
-            if ep is not None:
-                for i in np.nonzero(infos["final_info"]["_episode"])[0]:
-                    if aggregator and not aggregator.disabled:
-                        aggregator.update("Rewards/rew_avg", float(ep["r"][i]))
-                        aggregator.update("Game/ep_len_avg", float(ep["l"][i]))
-                    runtime.print(f"Rank-0: policy_step={policy_step}, reward_env_{i}={float(ep['r'][i])}")
-
-        real_next_obs = {k: np.array(v) for k, v in next_obs.items()}
-        if "final_obs" in infos:
-            for idx in np.nonzero(infos["_final_obs"])[0]:
-                for k, v in infos["final_obs"][idx].items():
-                    real_next_obs[k][idx] = v
-
-        for k in obs_keys:
-            step_data[k] = next_obs[k][np.newaxis]
-        obs = next_obs
-
-        rewards = rewards.reshape((1, total_envs, -1))
-        step_data["terminated"] = terminated.reshape((1, total_envs, -1)).astype(np.float32)
-        step_data["truncated"] = truncated.reshape((1, total_envs, -1)).astype(np.float32)
-        step_data["rewards"] = clip_rewards_fn(rewards)
-
-        dones_idxes = dones.nonzero()[0].tolist()
-        reset_envs = len(dones_idxes)
-        if reset_envs > 0:
-            reset_data = {}
-            for k in obs_keys:
-                reset_data[k] = (real_next_obs[k][dones_idxes])[np.newaxis]
-            reset_data["terminated"] = step_data["terminated"][:, dones_idxes]
-            reset_data["truncated"] = step_data["truncated"][:, dones_idxes]
-            reset_data["actions"] = np.zeros((1, reset_envs, int(np.sum(actions_dim))))
-            reset_data["rewards"] = step_data["rewards"][:, dones_idxes]
-            reset_data["is_first"] = np.zeros_like(reset_data["terminated"])
-            rb.add(reset_data, dones_idxes, validate_args=cfg.buffer.validate_args)
-            if device_cache is not None:
-                device_cache.add(reset_data, dones_idxes)
-            step_data["rewards"][:, dones_idxes] = np.zeros_like(reset_data["rewards"])
-            step_data["terminated"][:, dones_idxes] = np.zeros_like(step_data["terminated"][:, dones_idxes])
-            step_data["truncated"][:, dones_idxes] = np.zeros_like(step_data["truncated"][:, dones_idxes])
-            step_data["is_first"][:, dones_idxes] = np.ones_like(step_data["is_first"][:, dones_idxes])
-            player.init_states(dones_idxes)
-
-        # ------------------------------------------------------ train
-        if iter_num >= learning_starts:
-            ratio_steps = policy_step - prefill_steps * policy_steps_per_iter
-            per_rank_gradient_steps = ratio(ratio_steps / world_size)
-            if per_rank_gradient_steps > 0:
-                if player.actor_type != "task":
-                    player.actor_type = "task"
-                    player.params = {
-                        "world_model": dv3_params["world_model"],
-                        "actor": dv3_params["actor"],
-                    }
-                with sequence_batches(
-                    rb, device_cache, runtime, per_rank_gradient_steps,
-                    cfg.algo.per_rank_batch_size * world_size,
-                    cfg.algo.per_rank_sequence_length, runtime.next_key(),
-                ) as feed:
-                    with timer("Time/train_time", SumMetric, sync_on_compute=cfg.metric.sync_on_compute):
-                        for batch in feed:
-                            if (
-                                cumulative_per_rank_gradient_steps
-                                % cfg.algo.critic.per_rank_target_network_update_freq
-                                == 0
-                            ):
-                                tau = 1.0 if cumulative_per_rank_gradient_steps == 0 else cfg.algo.critic.tau
-                                dv3_params["target_critic"] = _ema(
-                                    dv3_params["critic"], dv3_params["target_critic"], tau
-                                )
-                            dv3_params, opt_states, moments_state, train_metrics = train_fn(
-                                dv3_params, opt_states, moments_state, batch, runtime.next_key()
-                            )
-                            cumulative_per_rank_gradient_steps += 1
-                    train_step += world_size
-                rolled = health.tick()
-                if rolled is not None:
-                    for k_live, k_ckpt in (
-                        ("world_model", "world_model"), ("actor", "actor_task"), ("critic", "critic_task")
-                    ):
-                        dv3_params[k_live] = restore_like(dv3_params[k_live], rolled[k_ckpt])
-                        opt_states[k_live] = restore_like(
-                            opt_states[k_live], rolled["opt_states"][k_ckpt]
-                        )
-                    moments_state = restore_like(moments_state, rolled["moments_task"])
-                player.params = {
-                    "world_model": dv3_params["world_model"],
-                    "actor": dv3_params["actor"],
-                }
-                if aggregator and not aggregator.disabled and metric_fetch_gate():
-                    with trace_scope("block_until_ready"):
-                        fetched_metrics = device_get_metrics(train_metrics)
-                    for k, v in fetched_metrics.items():
-                        aggregator.update(k, v)
-
-        # ------------------------------------------------------ logging
-        if cfg.metric.log_level > 0 and (
-            policy_step - last_log >= cfg.metric.log_every or iter_num == total_iters
-        ):
-            observability.on_log(policy_step, train_step)
-            if logger:
-                if aggregator and not aggregator.disabled:
-                    logger.log_metrics(aggregator.compute(), policy_step)
-                    aggregator.reset()
-                logger.log_metrics(
-                    {"Params/replay_ratio": cumulative_per_rank_gradient_steps * world_size / policy_step},
-                    policy_step,
-                )
-                if not timer.disabled:
-                    timer_metrics = timer.compute()
-                    if timer_metrics.get("Time/train_time", 0) > 0:
-                        logger.log_metrics(
-                            {"Time/sps_train": (train_step - last_train) / timer_metrics["Time/train_time"]},
-                            policy_step,
-                        )
-                    if timer_metrics.get("Time/env_interaction_time", 0) > 0:
-                        logger.log_metrics(
-                            {
-                                "Time/sps_env_interaction": (
-                                    (policy_step - last_log) / world_size * cfg.env.action_repeat
-                                )
-                                / timer_metrics["Time/env_interaction_time"]
-                            },
-                            policy_step,
-                        )
-                    timer.reset()
-            last_log = policy_step
-            last_train = train_step
-
-        # ------------------------------------------------------ checkpoint
-        def _ckpt_state():
-            ckpt_state = {
-                "world_model": dv3_params["world_model"],
-                "actor_task": dv3_params["actor"],
-                "critic_task": dv3_params["critic"],
-                "target_critic_task": dv3_params["target_critic"],
-                "actor_exploration": params["actor_exploration"],
-                "opt_states": {
-                    "world_model": opt_states["world_model"],
-                    "actor_task": opt_states["actor"],
-                    "critic_task": opt_states["critic"],
-                },
-                "moments_task": moments_state,
-                "ratio": ratio.state_dict(),
-                "iter_num": iter_num * world_size,
-                "batch_size": cfg.algo.per_rank_batch_size * world_size,
-                "last_log": last_log,
-                "last_checkpoint": ckpt_mgr.last_checkpoint,
-            }
-            if cfg.buffer.checkpoint:
-                ckpt_state["rb"] = rb
-            return ckpt_state
-
-        ckpt_mgr.maybe_checkpoint(
-            policy_step=policy_step, is_last=iter_num == total_iters, state_fn=_ckpt_state
-        )
-        if ckpt_mgr.preempted:
-            runtime.print(
-                f"Preemption signal: emergency checkpoint written, stopping at iter {iter_num}"
-            )
-            break
-
-    ckpt_mgr.close()
-    envs.close()
-    observability.close()
-    # task test few-shot
-    if runtime.is_global_zero and cfg.algo.run_test:
-        player.actor_type = "task"
-        player.params = {"world_model": dv3_params["world_model"], "actor": dv3_params["actor"]}
-        test_rew = test(player, runtime, cfg, log_dir, "few-shot", greedy=False)
-        if logger:
-            logger.log_metrics({"Test/cumulative_reward": test_rew}, policy_step)
-    if logger:
-        logger.finalize()

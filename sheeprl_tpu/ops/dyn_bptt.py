@@ -50,7 +50,6 @@ bf16-mixed, pinned by ``tests/test_parallel/test_dyn_bptt.py``).
 from __future__ import annotations
 
 import functools
-import os
 from typing import NamedTuple
 
 import jax
@@ -70,14 +69,10 @@ __all__ = [
 
 
 def dyn_bptt_setting(cfg) -> bool:
-    """The ``algo.world_model.dyn_bptt`` config knob with its
-    ``SHEEPRL_DYN_BPTT`` env override (shared by every Dreamer-family
-    train fn; callers AND their own structural eligibility check, e.g.
-    :func:`rssm_dyn_bptt_eligible` or a supported-activation test)."""
-    enabled = bool(cfg.algo.world_model.get("dyn_bptt", False))
-    if os.environ.get("SHEEPRL_DYN_BPTT") is not None:
-        enabled = os.environ["SHEEPRL_DYN_BPTT"].lower() not in ("0", "false")
-    return enabled
+    """The ``algo.world_model.dyn_bptt`` config knob (shared by every
+    Dreamer-family train fn; callers AND their own structural eligibility
+    check, e.g. :func:`rssm_dyn_bptt_eligible` or a supported-activation test)."""
+    return bool(cfg.algo.world_model.get("dyn_bptt", False))
 
 
 class DynParams(NamedTuple):

@@ -432,29 +432,15 @@ def _plain(v: Any) -> Any:
 # (measured on DV3, see dreamer_v3.make_train_fn; the bodies are
 # latency-bound so remat policy + unroll matter identically everywhere)
 # ------------------------------------------------------------------ #
-def scan_remat(f, policy_name: Optional[str] = None):
-    """Wrap a scan body for rematerialized backward.
-
-    ``SHEEPRL_REMAT_POLICY``: "dots" (default — save matmul results,
-    recompute elementwise chains), "full" (save only carry/outputs),
-    "none" (disable).
-    """
-    p = policy_name or os.environ.get("SHEEPRL_REMAT_POLICY", "dots")
-    if p == "none":
-        return f
-    if p == "dots":
-        return jax.checkpoint(
-            f, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        )
-    return jax.checkpoint(f)
+def scan_remat(f):
+    """Wrap a scan body for a rematerialized backward pass that saves matmul
+    results and recomputes the elementwise chains."""
+    return jax.checkpoint(f, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
 
 
 def scan_unroll_setting(cfg=None, kind: str = "dyn") -> int:
     """Unroll factor for the dynamic ("dyn") / imagination ("img") scans:
-    env var > cfg.algo.{scan_unroll,imagination_unroll} > measured default."""
-    if kind == "img":
-        env, attr, default = "SHEEPRL_IMG_UNROLL", "imagination_unroll", 3
-    else:
-        env, attr, default = "SHEEPRL_SCAN_UNROLL", "scan_unroll", 8
+    cfg.algo.{scan_unroll,imagination_unroll}, else the measured default."""
+    attr, default = ("imagination_unroll", 3) if kind == "img" else ("scan_unroll", 8)
     cfg_val = getattr(getattr(cfg, "algo", None), attr, None) if cfg is not None else None
-    return int(os.environ.get(env, cfg_val or default))
+    return int(cfg_val or default)
