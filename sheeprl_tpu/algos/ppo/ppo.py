@@ -395,8 +395,9 @@ def make_episode_update_fn(runtime, policy, tx: optax.GradientTransformation, cf
     norm, the expert layer's counters); ``probe`` holds what each minibatch
     step produced (its episodes, log-probabilities, values, losses, the
     gradient's norm whole and leaf by leaf, the norm of every leaf's change
-    ``new - old`` in float32, routing choice and load per expert), stacked over
-    the call's steps, for whoever compares the update with the plain reference."""
+    ``new - old`` in float32, routing choice, load per expert and whether the
+    sorted buffer was the short one), stacked over the call's steps, for
+    whoever compares the update with the plain reference."""
     if runtime.world_size > 1:
         raise ValueError("the language-model policy updates on one device; set fabric.devices=1")
     update_epochs = int(cfg.algo.update_epochs)
@@ -447,7 +448,7 @@ def make_episode_update_fn(runtime, policy, tx: optax.GradientTransformation, cf
             probe = {"episodes": ids, "logprobs": logp, "values": values, "losses": losses,
                      "grad_norm": optax.global_norm(grads), "grad_leaf_norms": jax.tree_util.tree_map(leaf_norm, grads),
                      "moved_leaf_norms": moved, "load": aux["load"], "dropped": aux["dropped"].sum(),
-                     "top_i": aux["top_i"], "entropy": aux["entropy"].mean()}
+                     "top_i": aux["top_i"], "entropy": aux["entropy"].mean(), "short": aux["short"]}
             return (new_params, opt_state), probe
 
         def epoch_step(carry, ekey):
@@ -468,6 +469,7 @@ def make_episode_update_fn(runtime, policy, tx: optax.GradientTransformation, cf
             "MoE/load_max_over_mean": (load.max(-1) / jnp.maximum(load.mean(-1), 1.0)).max(),
             "MoE/held_share": (load.sum(-1) / assignments).mean(),
             "MoE/dropped": probe["dropped"].sum().astype(jnp.float32),
+            "MoE/short_buffer_share": probe["short"].astype(jnp.float32).mean(),  # of the call's layer passes
             "MoE/router_entropy": probe["entropy"].mean(),
             **{f"MoE/load_l{i}_e{e}": load[i, e] for i in range(load.shape[0]) for e in range(load.shape[1])},
         }

@@ -9,10 +9,15 @@ What is particular here:
   top-k, and computes its own experts' part of the result.  That is the layer
   expert parallelism needs; on one chip it runs without its exchange, and what
   absent experts would add is left out (``howto/language_model_policy.md``).
-  It is dropless: the sorted buffer has a row for every assignment a token
-  could make to a held expert (``tokens x min(top_k, experts_held)``), so
-  nothing is ever cut, whatever the imbalance.  The held experts run as one
-  grouped product (``jax.lax.ragged_dot``, tokens sorted by expert).
+  The held experts run as one grouped product (``jax.lax.ragged_dot``, tokens
+  sorted by expert) over a sorted buffer whose length follows the counted
+  load: ``short_buffer_rows`` rows (``SHORT_BUFFER_SHARES`` even shares) when
+  the assignments to held experts fit, else, by ``jax.lax.cond``, a row for
+  every assignment a token could make to a held expert (``tokens x min(top_k,
+  experts_held)``).  Both lengths hold every held assignment: it is dropless,
+  nothing is ever cut, whatever the imbalance.  Where the short length would
+  save nothing (all experts held, or few tokens) there is one length and no
+  conditional.
 - attention takes its mask as data (``ops.block_sparse_attention.SegmentMask``): one
   packed episode holds the clean sequence and every denoising step's noised
   copy of its block (``EpisodeLayout``), so that one forward pass scores a whole
@@ -164,8 +169,8 @@ def rope(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
 
 # ------------------------------------------------------- dropless dispatch
 # Assignments are (token, choice) pairs, N x k of them.  ``perm`` (rows,) lists the assignments in
-# sorted order (by held expert; the head of a permutation, long enough for every assignment a held
-# expert could get) and ``inv`` (N, k) is each assignment's row.  Both directions of both moves are
+# sorted order (by held expert; the head of a permutation, long enough for every assignment to a held
+# expert this call) and ``inv`` (N, k) is each assignment's row.  Both directions of both moves are
 # gathers: XLA's own transpose of a gather is a scatter-add, which a TPU serialises.
 def _rows_of(sorted_rows, inv, keep):
     """Every assignment's row (N, k, d); zeros where ``keep`` (N, k) is unset."""
@@ -213,6 +218,68 @@ def _combine_bwd(res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+# ------------------------------------------- the length of the sorted buffer
+# The worst case, a row for every assignment a token could make to a held expert, is N x min(k, held);
+# the even share of the held experts is N x k x held / experts, an eighth of it at one chip's share of
+# an 8-way expert-parallel layer.  Gathers, grouped products and SwiGLU's passes all walk the buffer,
+# padding included.  The short length is SHORT_BUFFER_SHARES even shares, set from chip readings
+# (PERF.md section 6, PR 29; ``benchmarks/sdar_load_readings.py``): under a router drawn at 0.02 a
+# layer's heaviest possible minibatch read up to 3.15 even shares over 40 seeds (over 2 on 60 of 160
+# layer readings, over 3 on 2), its mean minibatch at most 1.83; at 2 shares one layer pass in 16
+# fell back to the worst case and the steps' cost followed the seed.
+SHORT_BUFFER_SHARES = 3
+ROW_TILE = 512  # the short length is whole tiles of rows
+
+
+def short_buffer_rows(n: int, k: int, held_n: int, num_experts: int) -> int:
+    """Rows of the short sorted buffer for ``n`` tokens, never above the worst case."""
+    fit = -(-SHORT_BUFFER_SHARES * n * k * held_n // num_experts)
+    return min(-(-fit // ROW_TILE) * ROW_TILE, n * min(k, held_n))
+
+
+def _experts_at(rows, dtype, m, w, w_gate, w_up, w_down, order, inv, held, group_sizes):
+    """Dispatch -> SwiGLU experts -> combine over a sorted buffer of ``rows`` rows, which must hold
+    every held assignment (``group_sizes.sum() <= rows``); products in ``dtype``.  ``m`` (N, d) and
+    ``w`` (N, k) float32, ``w`` 0 where the assignment is not held."""
+    with jax.named_scope("moe_dispatch"):
+        perm = order[:rows]
+        x = _dispatch(m.astype(dtype), perm, inv, held)
+    with jax.named_scope("moe_experts"):
+        # gate and up as one grouped product: the sorted rows are read once, their gradient summed once
+        w_in = jnp.concatenate([w_gate, w_up], axis=-1).astype(dtype)
+        gate, up = jnp.split(jax.lax.ragged_dot(x, w_in, group_sizes), 2, axis=-1)
+        y_sorted = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_down.astype(dtype), group_sizes)
+    with jax.named_scope("moe_dispatch"):
+        return _combine(y_sorted, w, perm, inv)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _experts_tiered(tiers, dtype, fits, *data):
+    """``_experts_at`` at ``tiers[0]`` rows where ``fits``, else at ``tiers[1]``.  The conditional
+    stands in the forward pass and again in the backward rule, which computes the taken length's
+    forward anew: differentiating through one ``cond`` would give both lengths one set of residuals,
+    the long one's, and have the short branch write them as zeros.  What goes into either conditional
+    (the layer's input and parameters) and comes out (the output, their gradients) has one size."""
+    return jax.lax.cond(fits, *(functools.partial(_experts_at, rows, dtype) for rows in tiers), *data)
+
+
+def _experts_tiered_fwd(tiers, dtype, fits, *data):
+    return _experts_tiered(tiers, dtype, fits, *data), (fits, *data)
+
+
+def _experts_tiered_bwd(tiers, dtype, res, g):
+    fits, m, w, w_gate, w_up, w_down, *indices = res
+
+    def grads_at(rows, g, *diff):
+        return jax.vjp(lambda *d: _experts_at(rows, dtype, *d, *indices), *diff)[1](g)
+
+    grads = jax.lax.cond(fits, *(functools.partial(grads_at, rows) for rows in tiers), g, m, w, w_gate, w_up, w_down)
+    return (None, *grads, None, None, None, None)
+
+
+_experts_tiered.defvjp(_experts_tiered_fwd, _experts_tiered_bwd)
+
+
 class RoutedExperts(nn.Module):
     """Router over all experts, SwiGLU experts held here, dropless."""
 
@@ -243,22 +310,23 @@ class RoutedExperts(nn.Module):
             local = top_i - c.expert_offset
             held = (local >= 0) & (local < held_n)
             key = jnp.where(held, local, held_n).reshape(-1)
-            # every assignment a token could make to a held expert has a row: nothing is ever cut
+            # the worst case, a row for every assignment a token could make to a held expert, and the short
+            # length; the call takes the short one when its assignments fit, so nothing is ever cut
             rows = n * min(k, held_n)
+            rows_fit = short_buffer_rows(n, k, held_n, c.num_experts)
             order = jnp.argsort(key, stable=True)
             inv = jnp.argsort(order).reshape(n, k)
-            perm = order[:rows]
             group_sizes = (key[:, None] == jnp.arange(held_n)).sum(0).astype(jnp.int32)
-            dropped = jnp.maximum(held.sum() - rows, 0)
-            x = _dispatch(m.astype(self.dtype), perm, inv, held)
-        with jax.named_scope("moe_experts"):
-            # gate and up as one grouped product: the sorted rows are read once, their gradient summed once
-            w_in = jnp.concatenate([self.w_gate, self.w_up], axis=-1).astype(self.dtype)
-            gate, up = jnp.split(jax.lax.ragged_dot(x, w_in, group_sizes), 2, axis=-1)
-            y_sorted = jax.lax.ragged_dot(jax.nn.silu(gate) * up, self.w_down.astype(self.dtype), group_sizes)
-        with jax.named_scope("moe_dispatch"):
-            y = _combine(y_sorted, jnp.where(held, weights, 0.0), perm, inv)
-        aux = {"load": group_sizes, "dropped": dropped, "entropy": entropy, "top_i": top_i}
+            assigned = held.sum()
+            dropped = jnp.maximum(assigned - rows, 0)
+            data = (m, jnp.where(held, weights, 0.0), self.w_gate, self.w_up, self.w_down, order, inv, held, group_sizes)
+        if rows_fit < rows:
+            short = assigned <= rows_fit
+            y = _experts_tiered((rows_fit, rows), self.dtype, short, *data)
+        else:  # the short length reaches the worst case: one length, and no conditional in the program
+            short = jnp.zeros((), bool)
+            y = _experts_at(rows, self.dtype, *data)
+        aux = {"load": group_sizes, "dropped": dropped, "short": short, "entropy": entropy, "top_i": top_i}
         return y, aux
 
 

@@ -266,6 +266,105 @@ def test_dropless_under_skew(favoured):
     _assert_trees_close(M.reference_params(grads), rgrads, GRAD_RTOL)
 
 
+# --------------------- (d') the sorted buffer's length follows the counted load
+# 6 episodes = 528 packed positions, 16 experts top-2, 2 held: the even share is 132 assignments, the
+# short buffer 512 rows (3 shares, rounded up to whole tiles of rows), the worst case 1,056
+TIERS = dict(num_experts=16, experts_held=2, expert_offset=2)
+
+
+def _load_biased_to(policy, params, tokens, target):
+    """The parameters with layer 0's router pushed toward its held experts just as far as gives them
+    ``target`` assignments (every token flips at its own push: the load moves by single steps)."""
+    cfg = policy.cfg
+    direction = policy.model.apply(params, tokens, policy.layout, method=M.SdarMoE.hidden)[0]
+    direction = direction.reshape(-1, direction.shape[-1]).mean(0)
+    held = slice(cfg.expert_offset, cfg.expert_offset + cfg.experts_held)
+    router = params["params"]["layer_0"]["moe"]["router"]
+
+    def pushed(push):
+        moved = router.at[:, held].add(push * (direction / jnp.linalg.norm(direction))[:, None])
+        return {"params": {**params["params"], "layer_0": {**params["params"]["layer_0"], "moe": {
+            **params["params"]["layer_0"]["moe"], "router": moved}}}}
+
+    load = jax.jit(lambda push: policy.model.apply(pushed(push), tokens, policy.layout, method=M.SdarMoE.hidden)[1]["load"][0].sum())
+    lo, hi = 0.0, 64.0
+    assert int(load(lo)) < target < int(load(hi))
+    for _ in range(60):
+        mid = 0.5 * (lo + hi)
+        got = int(load(mid))
+        if got == target:
+            return pushed(mid)
+        lo, hi = (mid, hi) if got < target else (lo, mid)
+    raise AssertionError(f"no push gives layer 0's held experts {target} assignments")
+
+
+@pytest.mark.parametrize("over", [None, 0, 1], ids=["under", "exactly_at", "one_over"])
+def test_buffer_length_follows_the_counted_load(over):
+    """Layer 0's held load under, exactly at and one over the short buffer's rows (layer 1's stays
+    under): the short buffer is taken, taken, not taken, and on either the program is the reference's
+    layer, with nothing dropped."""
+    policy, cfg = _policy(TIERS)
+    n_eps = 6
+    prompt, response, order, actions, extras = _episodes(7, n_eps)
+    params = policy.init(jax.random.PRNGKey(7))
+    tokens, _ = policy.layout.pack(jnp.asarray(prompt), jnp.asarray(actions), cfg["mask_id"])
+    n = tokens.size
+    rows_fit = M.short_buffer_rows(n, cfg["num_experts_per_tok"], cfg["experts_held"], cfg["num_experts"])
+    assert rows_fit == 512 < n * cfg["experts_held"]  # both lengths exist at this shape
+    if over is not None:
+        params = _load_biased_to(policy, params, tokens, rows_fit + over)
+    rparams = M.reference_params(params)
+    (loss, (logp, values, aux)), grads = jax.value_and_grad(
+        lambda p: _program_loss(policy, p, prompt, actions, extras), has_aux=True)(params)
+    (rloss, routs), rgrads = jax.value_and_grad(
+        lambda p: _reference_loss(p, cfg, prompt, response, order, extras), has_aux=True)(rparams)
+    load = np.asarray(aux["load"])
+    if over is not None:
+        assert load[0].sum() == rows_fit + over
+    assert load[0].sum() <= rows_fit or over == 1
+    assert load[1].sum() < rows_fit
+    assert np.asarray(aux["short"]).tolist() == [over != 1, True]
+    assert int(aux["dropped"].sum()) == 0
+    np.testing.assert_array_equal(load, sum(np.stack([np.asarray(a["counts"]) for a in o["aux"]]) for o in routs))
+    for b in range(n_eps):
+        np.testing.assert_allclose(np.asarray(logp[b]), np.asarray(routs[b]["logp"]), atol=VALUE_ATOL)
+        np.testing.assert_allclose(np.asarray(values[b]), np.asarray(routs[b]["values"]), atol=VALUE_ATOL)
+    assert abs(float(loss) - float(rloss)) <= VALUE_ATOL
+    _assert_trees_close(M.reference_params(grads), rgrads, GRAD_RTOL)
+
+
+def _conditionals(lowered_text):
+    return lowered_text.count("stablehlo.case") + lowered_text.count("stablehlo.if")
+
+
+def test_one_length_where_the_short_buffer_saves_nothing():
+    """With all experts held, and at the collector's cached pass over a block of tokens an env, the
+    short length reaches the worst case: one length, and the lowered program holds no conditional
+    (where both lengths exist it holds one a pass)."""
+    assert M.short_buffer_rows(16896, 8, 16, 128) == 50688  # the published widths: one chip's share of 8
+    assert M.short_buffer_rows(16896, 8, 128, 128) == 16896 * 8  # all held
+    assert M.short_buffer_rows(12 * 4, 8, 16, 128) == 12 * 4 * 8  # the collector's pass over 12 envs
+
+    def layer_text(overrides, n):  # the routed layer alone: the attention kernel's interpreter has conditionals of its own
+        layer = M.RoutedExperts(M.SdarConfig.from_mapping({**TINY, **overrides}), jnp.float32)
+        m = jax.ShapeDtypeStruct((n, TINY["hidden_size"]), jnp.float32)
+        params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), m)
+        fwd = lambda p, m: layer.apply(p, m)[0].sum()  # noqa: E731
+        return jax.jit(fwd).lower(params, m).as_text() + jax.jit(jax.grad(fwd, argnums=(0, 1))).lower(params, m).as_text()
+
+    assert _conditionals(layer_text(TIERS, 528)) == 2  # one in the forward pass, one in the backward rule
+    assert _conditionals(layer_text({"num_experts": 16, "experts_held": 16, "expert_offset": 0}, 528)) == 0
+
+    policy, cfg = _policy(TIERS)
+    envs, seen = 12, P + RESP
+    kv = jax.ShapeDtypeStruct((envs, seen, cfg["num_key_value_heads"], cfg["head_dim"]), jnp.float32)
+    block = jax.ShapeDtypeStruct((envs, BLOCK), jnp.int32)
+    text = jax.jit(lambda p, t, pos, cache, length: policy.model.apply(p, t, pos, cache, length, method=M.SdarMoE.block)).lower(
+        jax.eval_shape(policy.init, jax.random.PRNGKey(0)), block, block, [(kv, kv)] * cfg["num_hidden_layers"],
+        jax.ShapeDtypeStruct((), jnp.int32)).as_text()
+    assert _conditionals(text) == 0
+
+
 # ---------------- (e) the collector's record equals the update's recomputation
 def _lm_overrides(tmp_path, precision="32-true"):
     return [
@@ -337,6 +436,7 @@ def test_cli_runs_two_iterations(tmp_path, precision):
     moe = [r["moe"] for r in records if "moe" in r]
     assert len(moe) == 2, records
     assert all(m["dropped"] == 0 and np.isfinite(m["router_entropy"]) and m["load_max_over_mean"] >= 1 for m in moe)
+    assert all(0.0 <= m["short_buffer_share"] <= 1.0 for m in moe)
     assert records[-1]["jaxenv"]["env"] == "TokenEnvJax" and records[-1]["jaxenv"]["env_steps"] == 2 * 3 * RESP
 
 
@@ -413,6 +513,7 @@ def test_episode_update_is_the_hand_loop_of_its_steps(tmp_path, precision):
         assert float(probe["grad_norm"][t]) == pytest.approx(float(optax.global_norm(grads)), rel=rtol)
         p = new
     assert float(metrics["Grads/agent"]) == pytest.approx(float(probe["grad_norm"].mean()))
+    assert float(metrics["MoE/short_buffer_share"]) == float(np.asarray(probe["short"]).mean())
     # the returned state is the last step's, not the one the call was given
     moved = float(optax.global_norm(jax.tree_util.tree_map(lambda a, b: a - b, got_params, start)))
     assert moved > 0.5 * float(jnp.sqrt(sum(jnp.square(v[-1]) for v in jax.tree_util.tree_leaves(probe["moved_leaf_norms"]))))

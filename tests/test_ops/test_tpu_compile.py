@@ -143,11 +143,18 @@ def test_block_diffusion_attention_compiles_for_v5e(chip, prompt_len, response_l
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# temporaries of the layer with ONE buffer length, the worst case (16 of 128 held; AOT here, PR 29): what two lengths may cost
+ONE_LENGTH_TEMP_BYTES = {"fwd": 1_264_930_304, "grad": 2_220_866_560}
+
+
 @pytest.mark.parametrize("direction", ["fwd", "grad"])
-def test_routed_experts_compile_for_v5e(chip, direction):
+@pytest.mark.parametrize("held", [16, 128], ids=["one_chip_of_8", "all_held"])
+def test_routed_experts_compile_for_v5e(chip, held, direction):
+    import re
+
     from sheeprl_tpu.models.sdar_moe import RoutedExperts, SdarConfig
 
-    cfg = SdarConfig(num_hidden_layers=1, experts_held=16, vocab_size=18992, mask_id=18991)
+    cfg = SdarConfig(num_hidden_layers=1, experts_held=held, vocab_size=18992, mask_id=18991)
     layer = RoutedExperts(cfg, jnp.bfloat16)
     m = jax.ShapeDtypeStruct((16896, 2048), jnp.float32, sharding=chip)
     params = jax.tree_util.tree_map(
@@ -160,5 +167,19 @@ def test_routed_experts_compile_for_v5e(chip, direction):
         return y.sum(), aux["load"]
 
     fn = fwd if direction == "fwd" else jax.grad(lambda p, m: fwd(p, m)[0], argnums=(0, 1))
-    text = jax.jit(fn).lower(params, m).compile().as_text()
+    compiled = jax.jit(fn).lower(params, m).compile()
+    text = compiled.as_text()
     assert "tpu_custom_call" in text  # the grouped products run as the TPU's ragged-dot kernel
+    conditionals = re.findall(r" conditional\(.*", text)
+    if held == cfg.num_experts:  # the short buffer would be the worst case: one length, no conditional
+        assert not conditionals
+        return
+    # two buffer lengths (50,688 rows when the counted load fits, else 135,168), the kernel in both
+    assert len(conditionals) == 1
+    branches = re.findall(r"%([\w.]+)", re.search(r"branch_computations=\{([^}]*)\}", conditionals[0]).group(1))
+    assert len(branches) == 2
+    for name in branches:
+        body = text[text.index(f"\n%{name} ("):]
+        assert "tpu_custom_call" in body[:body.index("\n}\n")], name
+    # the short branch writes no zeros the size of the long one's residuals: no more temporaries than one length took
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.05 * ONE_LENGTH_TEMP_BYTES[direction]
