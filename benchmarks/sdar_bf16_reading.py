@@ -7,7 +7,9 @@ before its window with ``"correct": false`` and exit code 1, and the earlier
 line ``compare_with_reference`` holds the readings.
 
 Run as the cell itself, on the chip:
-``python benchmarks/sdar_bf16_reading.py --workload sdar_ep8_train --seed <n> --seconds 4``."""
+``python benchmarks/sdar_bf16_reading.py --workload sdar_ep8_train --seed <n> --seconds 4``.
+It names no cell: ``--workload joyai_ep_train`` gives the same second reading for
+``chipbench/drivers/causal_lm_train.py`` (PR 30)."""
 import os
 import sys
 

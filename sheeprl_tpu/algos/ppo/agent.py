@@ -249,11 +249,13 @@ def build_agent(
     agent_state: Optional[Any] = None,
 ) -> Tuple[PPOAgentModule, Any]:
     """Create module + init params (optionally from a checkpoint state).
-    ``algo.policy=sdar_moe`` builds the language-model policy instead."""
-    from sheeprl_tpu.algos.ppo.sdar_policy import build_sdar_agent, is_language_model_policy
+    An ``algo.policy`` that names a language-model policy (``lm_policy.py``)
+    builds that instead."""
+    from sheeprl_tpu.algos.ppo.lm_policy import language_model_policy
 
-    if is_language_model_policy(cfg):
-        return build_sdar_agent(runtime, cfg, agent_state)
+    lm_kind = language_model_policy(cfg)
+    if lm_kind is not None:
+        return lm_kind.build(runtime, cfg, agent_state)
     distribution = cfg.distribution.get("type", "auto").lower()
     if distribution not in ("auto", "normal", "tanh_normal", "discrete"):
         raise ValueError(f"Unknown distribution: {distribution}")

@@ -33,7 +33,7 @@ import optax
 
 from sheeprl_tpu.algos.ppo.agent import build_agent, evaluate_actions, get_values, PPOPlayer, sample_actions
 from sheeprl_tpu.algos.ppo.loss import entropy_loss, policy_loss, value_loss
-from sheeprl_tpu.algos.ppo.sdar_policy import is_language_model_policy
+from sheeprl_tpu.algos.ppo.lm_policy import language_model_policy
 from sheeprl_tpu.algos.ppo.utils import normalize_obs, prepare_obs, test
 from sheeprl_tpu.algos.ppo.vtrace import vtrace
 from sheeprl_tpu.config import instantiate
@@ -120,9 +120,10 @@ def make_update_fn(
     itself and minibatches are rank-striped, so no rollout data ever
     crosses devices — exactly DDP semantics.
 
-    ``algo.policy=sdar_moe`` (the language-model policy) takes the episode
-    update instead: ``make_episode_update_fn``."""
-    if is_language_model_policy(cfg):
+    A language-model policy (``algo.policy=sdar_moe`` or ``mla_moe``:
+    ``lm_policy.py``) takes the episode update instead:
+    ``make_episode_update_fn``."""
+    if language_model_policy(cfg) is not None:
         return make_episode_update_fn(runtime, module, tx, cfg)
     cnn_keys = tuple(cfg.algo.cnn_keys.encoder)
     update_epochs = int(cfg.algo.update_epochs)
@@ -382,22 +383,28 @@ def make_update_fn(
 
 
 def make_episode_update_fn(runtime, policy, tx: optax.GradientTransformation, cfg: Dict[str, Any]):
-    """The PPO update of the language-model policy (``sdar_policy.SdarPolicy``):
-    the unit of a minibatch is a whole episode, because one forward pass over
-    an episode's packed denoising trajectory yields the log-probabilities and
-    values of all its steps.  GAE, the clipped losses and the optimizer are
-    the ones ``make_update_fn`` uses; the epoch shuffle permutes episodes.
+    """The PPO update of a language-model policy (``lm_policy.py``: the
+    block-diffusion ``SdarPolicy``, the causal ``CausalLmPolicy``): the unit of
+    a minibatch is a whole episode, because one forward pass over an episode
+    (its packed denoising trajectory, or its tokens under the causal mask)
+    yields the log-probabilities and values of all its steps.  GAE, the
+    clipped losses and the optimizer are the ones ``make_update_fn`` uses; the
+    epoch shuffle permutes episodes.  Where the policy's ``evaluate_episodes``
+    hands back an ``aux_loss`` among its counters (the causal policy's
+    multi-token-prediction cross-entropy), the loss gains ``policy.aux_coef x
+    aux_loss`` as a fourth term, and nothing otherwise.
 
     ``data``: ``prompt`` (1, E, P) and ``actions`` (T, E, 2) integers,
     ``logprobs`` / ``values`` / ``rewards`` / ``dones`` (T, E, 1), one whole
-    episode per env (``FusedDiffusionCollector``).  Returns ``(params,
+    episode per env (the kind's fused collector).  Returns ``(params,
     opt_state, metrics, probe)``: ``metrics`` are scalars (losses, gradient
-    norm, the expert layer's counters); ``probe`` holds what each minibatch
-    step produced (its episodes, log-probabilities, values, losses, the
-    gradient's norm whole and leaf by leaf, the norm of every leaf's change
-    ``new - old`` in float32, routing choice, load per expert and whether the
-    sorted buffer was the short one), stacked over the call's steps, for
-    whoever compares the update with the plain reference."""
+    norm, the expert layers' counters, the auxiliary loss's counters);
+    ``probe`` holds what each minibatch step produced (its episodes,
+    log-probabilities, values, losses, the gradient's norm whole and leaf by
+    leaf, the norm of every leaf's change ``new - old`` in float32, routing
+    choice, load per expert and whether the sorted buffer was the short one),
+    stacked over the call's steps, for whoever compares the update with the
+    plain reference."""
     if runtime.world_size > 1:
         raise ValueError("the language-model policy updates on one device; set fabric.devices=1")
     update_epochs = int(cfg.algo.update_epochs)
@@ -405,6 +412,7 @@ def make_episode_update_fn(runtime, policy, tx: optax.GradientTransformation, cf
     gamma, gae_lambda = float(cfg.algo.gamma), float(cfg.algo.gae_lambda)
     vf_coef, clip_vloss = float(cfg.algo.vf_coef), bool(cfg.algo.clip_vloss)
     reduction, normalize_adv = str(cfg.algo.loss_reduction), bool(cfg.algo.normalize_advantages)
+    aux_coef = float(getattr(policy, "aux_coef", 0.0))
 
     def loss_fn(p, mb, clip_coef, ent_coef):
         logp, entropy, values, aux = policy.evaluate_episodes(p, mb["prompt"], mb["actions"])
@@ -414,7 +422,11 @@ def make_episode_update_fn(runtime, policy, tx: optax.GradientTransformation, cf
             vl = value_loss(values, mb["values"], mb["returns"], clip_coef, clip_vloss, reduction)
             ent = entropy_loss(entropy, reduction)
             total = pg + vf_coef * vl + ent_coef * ent
-        return total, (jnp.stack([pg, vl, ent]), logp, values, aux)
+            terms = [pg, vl, ent]
+            if "aux_loss" in aux:  # the policy's own auxiliary loss: a fourth term
+                total = total + aux_coef * aux["aux_loss"]
+                terms.append(aux["aux_loss"])
+        return total, (jnp.stack(terms), logp, values, aux)
 
     grad_fn = jax.grad(loss_fn, has_aux=True)
 
@@ -448,7 +460,8 @@ def make_episode_update_fn(runtime, policy, tx: optax.GradientTransformation, cf
             probe = {"episodes": ids, "logprobs": logp, "values": values, "losses": losses,
                      "grad_norm": optax.global_norm(grads), "grad_leaf_norms": jax.tree_util.tree_map(leaf_norm, grads),
                      "moved_leaf_norms": moved, "load": aux["load"], "dropped": aux["dropped"].sum(),
-                     "top_i": aux["top_i"], "entropy": aux["entropy"].mean(), "short": aux["short"]}
+                     "top_i": aux["top_i"], "entropy": aux["entropy"].mean(), "short": aux["short"],
+                     "aux_counters": aux.get("aux_counters", {})}
             return (new_params, opt_state), probe
 
         def epoch_step(carry, ekey):
@@ -472,6 +485,7 @@ def make_episode_update_fn(runtime, policy, tx: optax.GradientTransformation, cf
             "MoE/short_buffer_share": probe["short"].astype(jnp.float32).mean(),  # of the call's layer passes
             "MoE/router_entropy": probe["entropy"].mean(),
             **{f"MoE/load_l{i}_e{e}": load[i, e] for i in range(load.shape[0]) for e in range(load.shape[1])},
+            **{name: v.mean() for name, v in probe["aux_counters"].items()},
         }
         return params, opt_state, metrics, probe
 
@@ -551,10 +565,11 @@ def main(runtime, cfg: Dict[str, Any]):
     clip_rewards_fn = (lambda r: np.tanh(r)) if cfg.env.clip_rewards else (lambda r: r)
 
     # ------------------------------------------------------------- agent
-    lm_policy = is_language_model_policy(cfg)
+    lm_kind = language_model_policy(cfg)
+    lm_policy = lm_kind is not None
     if lm_policy and (env_backend != "jax" or cfg.algo.run_test):
         raise ValueError(
-            "algo.policy=sdar_moe collects through the fused device collector only and has no test "
+            f"algo.policy={lm_kind.name} collects through the fused device collector only and has no test "
             "episode: set algo.env_backend=jax, env=jax_tokens and algo.run_test=False"
         )
     module, params = build_agent(
@@ -649,9 +664,9 @@ def main(runtime, cfg: Dict[str, Any]):
     if env_backend == "jax":
         # fused collect (envs/jax/collect.py): policy + env + append as
         # one lax.scan per rollout; the payload is born on device
-        from sheeprl_tpu.envs.jax.collect import FusedDiffusionCollector, FusedOnPolicyCollector
+        from sheeprl_tpu.envs.jax.collect import FusedOnPolicyCollector
 
-        collector = (FusedDiffusionCollector if lm_policy else FusedOnPolicyCollector)(
+        collector = (lm_kind.collector_class if lm_policy else FusedOnPolicyCollector)(
             envs=envs,
             module=module,
             params=params,
@@ -719,7 +734,9 @@ def main(runtime, cfg: Dict[str, Any]):
         adopt_params_fn=adopt_params_fn,
     )
     metric_fetch_gate = MetricFetchGate(cfg.metric.get("fetch_every", 1))
-    moe_counters: Dict[str, float] = {}
+    # the language-model policies' counters ride the telemetry record, a section a prefix
+    counter_sections = {"MoE/": "moe", "MTP/": "mtp"}
+    policy_counters: Dict[str, Dict[str, float]] = {}
 
     for iter_num, payload in pipeline:
         observability.on_iteration(policy_step)
@@ -754,15 +771,17 @@ def main(runtime, cfg: Dict[str, Any]):
                 fetched_metrics = device_get_metrics(train_metrics)
             for k, v in fetched_metrics.items():
                 aggregator.update(k, v)
-            # the expert layer's counters ride the telemetry record ("moe" section)
-            moe_counters = {k[len("MoE/"):]: float(v) for k, v in fetched_metrics.items() if k.startswith("MoE/")}
+            for prefix, section in counter_sections.items():
+                found = {k[len(prefix):]: float(v) for k, v in fetched_metrics.items() if k.startswith(prefix)}
+                if found:
+                    policy_counters[section] = found
 
         # ------------------------------------------------- logging
         if cfg.metric.log_level > 0 and logger:
             logger.log_metrics({"Info/learning_rate": current_lr}, policy_step)
             logger.log_metrics({"Info/clip_coef": current_clip, "Info/ent_coef": current_ent}, policy_step)
             if policy_step - last_log >= cfg.metric.log_every or iter_num == total_iters:
-                observability.on_log(policy_step, train_step, extra={"moe": moe_counters} if moe_counters else None)
+                observability.on_log(policy_step, train_step, extra=dict(policy_counters) or None)
                 if aggregator and not aggregator.disabled:
                     logger.log_metrics(aggregator.compute(), policy_step)
                     aggregator.reset()

@@ -1,4 +1,5 @@
-"""The language-model policy kind of PPO (``algo.policy=sdar_moe``): an
+"""The block-diffusion language-model policy kind of PPO (``algo.policy=sdar_moe``;
+``lm_policy.py`` holds the kinds): an
 SDAR-MoE model (``models/sdar_moe.py``) that acts by denoising one token of
 the block in progress per environment step (``envs/jax/tokens.py``).
 
@@ -18,13 +19,6 @@ import jax
 import jax.numpy as jnp
 
 from sheeprl_tpu.models.sdar_moe import EpisodeLayout, SdarConfig, SdarMoE
-
-POLICY_KIND = "sdar_moe"
-
-
-def is_language_model_policy(cfg: Dict[str, Any]) -> bool:
-    return str(cfg.algo.get("policy", "mlp")) == POLICY_KIND
-
 
 class SdarPolicy:
     """The model, the episode's layout and the functions PPO calls on them."""

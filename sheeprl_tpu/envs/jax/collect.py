@@ -348,10 +348,92 @@ class FusedRecurrentCollector(_FusedCollectorBase):
         return payload
 
 
-class FusedDiffusionCollector(_FusedCollectorBase):
-    """Fused collection for the language-model policy (``algos/ppo/sdar_policy.py``
-    over ``envs/jax/tokens.py``): one rollout is one whole episode per env, one
-    env step per denoising step.
+class _FusedEpisodeCollector(_FusedCollectorBase):
+    """What the language-model policies' collectors share (``algos/ppo/lm_policy.py``
+    names them): one rollout is one whole episode per env over
+    ``envs/jax/tokens.py``, the carry between rollouts is the env state alone,
+    and ``_rollout_fn`` returns ``(vstate, data, events)`` with the prompt
+    among the data."""
+
+    def _initial_carry(self, base):
+        return vector_reset(self.jax_env, base, self.total_envs)
+
+    def collect(self, iter_num: int, inline: bool, key_fn) -> RolloutPayload:
+        from sheeprl_tpu.utils.metric import SumMetric
+        from sheeprl_tpu.utils.timer import timer
+
+        payload = RolloutPayload(iter_num)
+        step_start = self.policy_step
+        with timer("Time/env_interaction_time", SumMetric, sync_on_compute=False):
+            self._carry, data, events = self._rollout(self.params, self._carry, key_fn(), self._env_base)
+        self._n_rollouts += 1
+        self.policy_step += self.rollout_steps * self.total_envs
+        self._apply_events(events, step_start)
+        payload.data = data
+        payload.next_obs = {k: self._carry["obs"][k] for k in self.obs_keys}
+        payload.policy_step_end = self.policy_step
+        return payload
+
+
+class FusedCausalCollector(_FusedEpisodeCollector):
+    """Fused collection for the causal language-model policy
+    (``algos/ppo/causal_lm_policy.py``): one env step appends one token.
+
+    One pass over the prompt under the causal mask (the full-sequence form of
+    the attention) fills the cache; then the scan runs over response tokens,
+    and its carry holds the env state, per block the latent cache (``ckv`` and
+    the shared rotary key of every position so far: 576 numbers a position and
+    block at the published sizes, never per-head keys or values) and the hidden
+    state at the newest position.  A step samples its token at temperature 1
+    from the full vocabulary slice, so that the recorded log-probability is
+    exactly the model's, steps the env, and makes ONE cached pass (the absorbed
+    form of the attention) over the token it has just appended.  The model's
+    multi-token-prediction module is not used to draft."""
+
+    def _rollout_fn(self, params, vstate, key, env_base):
+        from sheeprl_tpu.models.mla_moe import MlaMoE
+
+        policy, env = self.module, self.jax_env
+        model = policy.model
+        n_env, p_len, r_len = self.total_envs, env.prompt_len, env.response_len
+
+        prompt = vstate["obs"]["tokens"][:, :p_len]
+        u, _, latents = model.apply(params, prompt, True, method=MlaMoE.hidden)
+        cache = [
+            tuple(jnp.zeros((n_env, p_len + r_len) + x.shape[2:], x.dtype).at[:, :p_len].set(x) for x in lat)
+            for lat in latents
+        ]
+
+        def step_fn(carry, xs):
+            vstate, cache, last = carry
+            t, step_key = xs
+            logp_all, values = model.apply(params, last, method=MlaMoE.logits)
+            x = jax.random.categorical(step_key, logp_all, axis=-1)
+            action = jnp.stack([jnp.zeros_like(x), x], -1).astype(jnp.int32)
+            vstate, out = vector_step(env, vstate, action, env_base, None)
+            # the appended token's pass: its latents join the cache, its hidden state scores the next step
+            # (after the last token the env has reset, and what is written is never read)
+            u, cache = model.apply(params, x[:, None].astype(jnp.int32), cache, p_len + t, method=MlaMoE.step)
+            rec = {
+                "actions": action,
+                "logprobs": jnp.take_along_axis(logp_all, x[:, None], axis=-1),
+                "values": values[:, None],
+                "rewards": out["reward"][:, None],
+                "dones": out["done"][:, None].astype(jnp.float32),
+                "ev": {"done": out["done"], "ep_return": out["ep_return"], "ep_length": out["ep_length"]},
+            }
+            return (vstate, cache, u[:, 0]), rec
+
+        keys = jax.random.split(jnp.asarray(key), r_len)
+        (vstate, _, _), recs = jax.lax.scan(step_fn, (vstate, cache, u[:, -1]), (jnp.arange(r_len), keys))
+        events = recs.pop("ev")
+        recs["prompt"] = prompt[None]
+        return vstate, recs, events
+
+
+class FusedDiffusionCollector(_FusedEpisodeCollector):
+    """Fused collection for the block-diffusion language-model policy
+    (``algos/ppo/sdar_policy.py``): one env step per denoising step.
 
     The scan runs over response blocks, and its carry holds the env state (the
     block in progress lives in its tokens) and, per layer, the keys and values
@@ -365,9 +447,6 @@ class FusedDiffusionCollector(_FusedCollectorBase):
     temperature 1, so that a step's recorded log-probability is exactly the
     model's (SDAR's own low-confidence sampler ranks positions by the *sampled*
     token's probability, which a policy-gradient ratio cannot reproduce)."""
-
-    def _initial_carry(self, base):
-        return vector_reset(self.jax_env, base, self.total_envs)
 
     def _rollout_fn(self, params, vstate, key, env_base):
         from sheeprl_tpu.models.sdar_moe import SdarMoE
@@ -426,19 +505,3 @@ class FusedDiffusionCollector(_FusedCollectorBase):
         events = recs.pop("ev")
         recs["prompt"] = prompt[None]
         return vstate, recs, events
-
-    def collect(self, iter_num: int, inline: bool, key_fn) -> RolloutPayload:
-        from sheeprl_tpu.utils.metric import SumMetric
-        from sheeprl_tpu.utils.timer import timer
-
-        payload = RolloutPayload(iter_num)
-        step_start = self.policy_step
-        with timer("Time/env_interaction_time", SumMetric, sync_on_compute=False):
-            self._carry, data, events = self._rollout(self.params, self._carry, key_fn(), self._env_base)
-        self._n_rollouts += 1
-        self.policy_step += self.rollout_steps * self.total_envs
-        self._apply_events(events, step_start)
-        payload.data = data
-        payload.next_obs = {k: self._carry["obs"][k] for k in self.obs_keys}
-        payload.policy_step_end = self.policy_step
-        return payload

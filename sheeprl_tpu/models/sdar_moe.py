@@ -4,7 +4,8 @@ diffusion over blocks (``model_type: sdar_moe``; layer equations in
 
 What is particular here:
 
-- ``RoutedExperts`` is told which experts it holds (``experts_held`` from
+- ``RoutedExperts`` (also ``models/mla_moe.py``'s routed layer, under another
+  routing rule: ``RoutedSpec``) is told which experts it holds (``experts_held`` from
   ``expert_offset``): it routes over all ``num_experts``, keeps the published
   top-k, and computes its own experts' part of the result.  That is the layer
   expert parallelism needs; on one chip it runs without its exchange, and what
@@ -83,6 +84,11 @@ class SdarConfig:
         if not 0 <= self.mask_id < self.vocab_size:
             raise ValueError(f"mask_id {self.mask_id} lies outside the vocabulary slice of {self.vocab_size}")
         return self
+
+    @property
+    def routed_spec(self) -> "RoutedSpec":
+        return RoutedSpec(self.hidden_size, self.moe_intermediate_size, self.num_experts, self.num_experts_per_tok,
+                          self.experts_held, self.expert_offset, self.norm_topk_prob)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -280,16 +286,49 @@ def _experts_tiered_bwd(tiers, dtype, res, g):
 _experts_tiered.defvjp(_experts_tiered_fwd, _experts_tiered_bwd)
 
 
-class RoutedExperts(nn.Module):
-    """Router over all experts, SwiGLU experts held here, dropless."""
+@dataclasses.dataclass(frozen=True)
+class RoutedSpec:
+    """What the routed layer needs to know: its sizes, the experts it holds, and the routing rule.
 
-    cfg: SdarConfig
+    ``scoring="softmax"``: softmax over all experts, the top ``top_k`` probabilities, renormalised
+    where ``norm_topk_prob`` (SDAR-MoE).  ``scoring="sigmoid"``: a sigmoid score per expert; the
+    top ``top_k`` of ``score + bias`` are selected (``bias``: a parameter of the layer that enters
+    the selection only, takes no gradient and so no optimizer step), weighed by the unbiased score,
+    renormalised where ``norm_topk_prob`` and scaled by ``scale`` (the DeepSeek-V3 family's
+    ``noaux_tc`` without group limits; ``models/mla_moe.py``)."""
+
+    hidden_size: int
+    moe_intermediate_size: int
+    num_experts: int
+    top_k: int
+    experts_held: int
+    expert_offset: int = 0
+    norm_topk_prob: bool = True
+    scoring: str = "softmax"
+    scale: float = 1.0
+
+    @classmethod
+    def of(cls, cfg: Any) -> "RoutedSpec":
+        """``cfg`` itself if it is a spec, else the spec a model's configuration offers
+        (``routed_spec``: ``SdarConfig``, ``mla_moe.MlaMoeConfig``)."""
+        return cfg if isinstance(cfg, cls) else cfg.routed_spec
+
+
+class RoutedExperts(nn.Module):
+    """Router over all experts, SwiGLU experts held here, dropless: the one routed layer of both
+    language-model policies.  ``cfg``: a ``RoutedSpec``, or a model configuration that offers one."""
+
+    cfg: Any
     dtype: Dtype = jnp.float32
 
     def setup(self) -> None:
-        c = self.cfg
+        c = RoutedSpec.of(self.cfg)
         d, f, held = c.hidden_size, c.moe_intermediate_size, c.experts_held
+        if c.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring is softmax or sigmoid, got {c.scoring!r}")
         self.router = self.param("router", _INIT, (d, c.num_experts), jnp.float32)
+        if c.scoring == "sigmoid":
+            self.bias = self.param("bias", _INIT, (c.num_experts,), jnp.float32)
         self.w_gate = self.param("w_gate", _INIT, (held, d, f), jnp.float32)
         self.w_up = self.param("w_up", _INIT, (held, d, f), jnp.float32)
         self.w_down = self.param("w_down", _INIT, (held, f, d), jnp.float32)
@@ -298,14 +337,22 @@ class RoutedExperts(nn.Module):
         """``m``: (N, hidden) float32, already normed.  Returns the held
         experts' part of the layer's output (N, hidden) float32 and the
         counters."""
-        c = self.cfg
-        n, k, held_n = m.shape[0], c.num_experts_per_tok, c.experts_held
+        c = RoutedSpec.of(self.cfg)
+        n, k, held_n = m.shape[0], c.top_k, c.experts_held
         with jax.named_scope("moe_router"):
             logits = jnp.dot(m, self.router, precision=jax.lax.Precision.HIGHEST)
-            probs = jax.nn.softmax(logits, axis=-1)
-            top_p, top_i = jax.lax.top_k(probs, k)
+            if c.scoring == "softmax":
+                probs = dist = jax.nn.softmax(logits, axis=-1)
+                top_p, top_i = jax.lax.top_k(probs, k)
+            else:  # selected with the bias, weighed without it
+                probs = jax.nn.sigmoid(logits)
+                _, top_i = jax.lax.top_k(probs + jax.lax.stop_gradient(self.bias), k)
+                top_p = jnp.take_along_axis(probs, top_i, axis=-1)
+                dist = probs / probs.sum(-1, keepdims=True)
             weights = top_p / top_p.sum(-1, keepdims=True) if c.norm_topk_prob else top_p
-            entropy = -(probs * jnp.log(jnp.maximum(probs, 1e-30))).sum(-1).mean()
+            if c.scale != 1.0:
+                weights = weights * c.scale
+            entropy = -(dist * jnp.log(jnp.maximum(dist, 1e-30))).sum(-1).mean()
         with jax.named_scope("moe_dispatch"):
             local = top_i - c.expert_offset
             held = (local >= 0) & (local < held_n)

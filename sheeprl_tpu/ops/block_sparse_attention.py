@@ -2,7 +2,9 @@
 (``jax.experimental.pallas.ops.tpu.splash_attention``): scores, online softmax
 and the weighted sum stay in fast memory, tiles the mask empties are skipped,
 forward and backward.  This is the blocked attention of the language-model
-policy (``models/sdar_moe.py``); ``ring_attention.blockwise_attention`` is the
+policies: grouped-query attention under the block-diffusion mask
+(``models/sdar_moe.py``) and latent attention with keys wider than values,
+causal (``models/mla_moe.py``).  ``ring_attention.blockwise_attention`` is the
 plain-XLA causal op of ``MultiHeadSelfAttention`` and takes no mask.
 
 Off a TPU the kernel runs only through Pallas' interpreter
@@ -18,7 +20,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-_LANES = 128  # the kernel's tiles and head size come in multiples of this
+_LANES = 128  # the kernel's tiles come in multiples of this
+
+
+def _head_width(d: int) -> int:
+    """The width a head of ``d`` numbers is handed to the kernel at: the one place heads are padded.
+    A multiple of half a lane tile goes as it is (MLA's 192-wide queries and keys: the compiler lays
+    them over two lane tiles in fast memory, so the products that contract over them cost the MXU
+    256 / 192 of their FLOPs, and memory holds 192); any other width is zero-padded to whole tiles,
+    in memory too."""
+    return d if d >= _LANES and d % (_LANES // 2) == 0 else -(-d // _LANES) * _LANES
 
 
 class SegmentMask(NamedTuple):
@@ -78,33 +89,36 @@ def _splash_kernel(mask_bytes: Tuple[bytes, ...], padded: Tuple[int, int], rep: 
 def block_sparse_flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, mask: SegmentMask, block_size: int = 512, interpret: bool = False
 ) -> jax.Array:
-    """q: (..., Sq, Hq, D); k, v: (..., Sk, Hkv, D) with ``Hq`` a multiple of
-    ``Hkv`` (each key-value head serves ``Hq / Hkv`` query heads).  Returns
-    (..., Sq, Hq, D).  Tiles are ``block_size`` wide (a multiple of 128, or
-    the whole padded sequence where that is shorter); sequences that are no
-    multiple of the tile and heads that are no multiple of 128 wide are padded
-    with positions nothing sees and with zeros.  Only reverse-mode
-    differentiation is supported."""
+    """q: (..., Sq, Hq, D); k: (..., Sk, Hkv, D); v: (..., Sk, Hkv, Dv) with
+    ``Hq`` a multiple of ``Hkv`` (each key-value head serves ``Hq / Hkv`` query
+    heads; ``Hq == Hkv``: heads that share nothing) and ``Dv`` any width of its
+    own.  Scores are scaled by ``1 / sqrt(D)``.  Returns (..., Sq, Hq, Dv).
+    Tiles are ``block_size`` wide (a multiple of 128, or the whole padded
+    sequence where that is shorter); sequences that are no multiple of the
+    tile are padded with positions nothing sees, head widths with zeros by
+    ``_head_width``.  Only reverse-mode differentiation is supported."""
     s_q, h_q, d = q.shape[-3:]
-    s_k, h_kv = k.shape[-3], k.shape[-2]
+    s_k, h_kv, d_v = k.shape[-3], k.shape[-2], v.shape[-1]
+    if k.shape[-1] != d:
+        raise ValueError(f"queries are {d} wide and keys {k.shape[-1]}")
     if h_q % h_kv:
         raise ValueError(f"{h_q} query heads cannot share {h_kv} key-value heads")
     if block_size % _LANES:
         raise ValueError(f"the kernel's tiles are multiples of {_LANES} wide, got {block_size}")
     rep = h_q // h_kv
     block = min(block_size, -(-max(s_q, s_k) // _LANES) * _LANES)
-    p_q, p_k, p_d = -(-s_q // block) * block, -(-s_k // block) * block, -(-d // _LANES) * _LANES
+    p_q, p_k = -(-s_q // block) * block, -(-s_k // block) * block
     kernel = _splash_kernel(tuple(np.asarray(a).astype(np.int64).tobytes() for a in mask), (p_q, p_k), rep, block,
                             interpret)
     batch = q.shape[:-3]
 
-    def padded(x, s):  # (..., S, H, D) -> (B, s, H, p_d)
+    def padded(x, s):  # (..., S, H, D) -> (B, s, H, padded D)
         x = x.reshape(-1, *x.shape[-3:])
-        return jnp.pad(x, ((0, 0), (0, s - x.shape[1]), (0, 0), (0, p_d - d)))
+        return jnp.pad(x, ((0, 0), (0, s - x.shape[1]), (0, 0), (0, _head_width(x.shape[-1]) - x.shape[-1])))
 
     scale = 1.0 / np.sqrt(d)  # the kernel takes queries already scaled
-    qg = padded((q.astype(jnp.float32) * scale).astype(q.dtype), p_q).reshape(-1, p_q, h_kv, rep, p_d)
+    qg = padded((q.astype(jnp.float32) * scale).astype(q.dtype), p_q).reshape(-1, p_q, h_kv, rep, _head_width(d))
     qg = jnp.moveaxis(qg, 1, 3)  # (B, H_kv, rep, S, D)
     kg, vg = (jnp.moveaxis(padded(x, p_k), 1, 2) for x in (k, v))  # (B, H_kv, S, D)
     out = jax.vmap(jax.vmap(kernel))(qg, kg, vg)  # over episodes, over key-value heads
-    return jnp.moveaxis(out, 3, 1).reshape(*batch, p_q, h_q, p_d)[..., :s_q, :, :d]
+    return jnp.moveaxis(out, 3, 1).reshape(*batch, p_q, h_q, _head_width(d_v))[..., :s_q, :, :d_v]

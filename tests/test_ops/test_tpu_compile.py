@@ -183,3 +183,49 @@ def test_routed_experts_compile_for_v5e(chip, held, direction):
         assert "tpu_custom_call" in body[:body.index("\n}\n")], name
     # the short branch writes no zeros the size of the long one's residuals: no more temporaries than one length took
     assert compiled.memory_analysis().temp_size_in_bytes < 1.05 * ONE_LENGTH_TEMP_BYTES[direction]
+
+
+# ---- the causal language-model policy's device paths at the published widths (one v5e's share of 16: one
+# episode of 8,192 positions, 32 heads that share nothing, q / k 192 wide and v 128; 16 experts held of 256)
+@pytest.mark.parametrize("direction", ["fwd", "grad"])
+@pytest.mark.parametrize("length", [8192, 8000], ids=["whole_tiles", "padded"])
+def test_latent_attention_kernel_compiles_for_v5e(chip, length, direction):
+    """Queries and keys go to the kernel 192 wide, unpadded in memory (``_head_width``), values 128."""
+    from sheeprl_tpu.ops.block_sparse_attention import SegmentMask, block_sparse_flash_attention
+
+    mask = SegmentMask.causal(length, length)
+
+    def fwd(q, k, v):
+        return block_sparse_flash_attention(q, k, v, mask, block_size=512).astype(jnp.float32).sum()
+
+    qk = jax.ShapeDtypeStruct((1, length, 32, 192), jnp.bfloat16, sharding=chip)
+    v = jax.ShapeDtypeStruct((1, length, 32, 128), jnp.bfloat16, sharding=chip)
+    fn = fwd if direction == "fwd" else jax.grad(fwd, argnums=(0, 1, 2))
+    compiled = jax.jit(fn).lower(qk, qk, v).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "bf16[32,8192,192]" in text and "bf16[32,8192,256]" not in text
+    # blocked: far from the 32 x 8192^2 x 4 bytes = 8.6 GB a materialised score matrix would take
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+@pytest.mark.parametrize("direction", ["fwd", "grad"])
+def test_routed_experts_under_the_sigmoid_rule_compile_for_v5e(chip, direction):
+    from sheeprl_tpu.models.mla_moe import MlaMoeConfig
+    from sheeprl_tpu.models.sdar_moe import RoutedExperts, short_buffer_rows
+
+    cfg = MlaMoeConfig(experts_held=16)
+    layer = RoutedExperts(cfg, jnp.bfloat16)
+    m = jax.ShapeDtypeStruct((8192, 2048), jnp.float32, sharding=chip)
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), jnp.zeros((8, 2048), jnp.float32)),
+    )
+    assert params["params"]["bias"].shape == (256,) and short_buffer_rows(8192, 8, 16, 256) == 12288
+
+    def fwd(p, m):
+        y, aux = layer.apply(p, m)
+        return y.sum(), aux["load"]
+
+    fn = fwd if direction == "fwd" else jax.grad(lambda p, m: fwd(p, m)[0], argnums=(0, 1))
+    text = jax.jit(fn).lower(params, m).compile().as_text()
+    assert "tpu_custom_call" in text and text.count(" conditional(") == 1  # 12,288 rows when the load fits, else 65,536
