@@ -2,8 +2,10 @@
 """The update of a language-model policy, lowered (and on request compiled) for
 a described, unattached TPU v5e at a benchmark cell's sizes, without a chip and
 without a single full-size array: what the program would hold on the chip
-(``compiled.memory_analysis()``) and a hash of its lowered text, to hold two
-checkouts' programs against each other.
+(``compiled.memory_analysis()``), a hash of its lowered text, to hold two
+checkouts' programs against each other, and how often it calls each of the
+attention's kernels (``attention_forward_kernels``: once a block where the
+rematerialised blocks keep the kernel's output, twice where they do not).
 
     JAX_PLATFORMS=cpu python benchmarks/lm_update_aot.py --workload sdar_ep8_train            # hash only
     JAX_PLATFORMS=cpu python benchmarks/lm_update_aot.py --workload joyai_ep_train --compile  # + bytes, ~2-4 min
@@ -45,6 +47,26 @@ def location_free(text: str) -> str:
         return "kernel:" + hashlib.sha256(asm.encode()).hexdigest()
 
     return re.sub(r'(?<=\\22body\\22: \\22)([A-Za-z0-9+/=]+)(?=\\22)', body_hash, text)
+
+
+def kernel_calls(text: str, kernel: str) -> int:
+    """Calls of the Pallas kernels whose name holds ``kernel`` in a program's text, every function
+    inlined.  Compiled text names a custom call after its kernel; lowered text holds a kernel once, in
+    a private function, and the count is that of the call sites that reach it from ``main``."""
+    import re
+
+    if text.startswith("HloModule"):
+        return len(re.findall(rf"^\s*(?:ROOT )?%?[\w.\-]*{kernel}[\w.\-]* = .*custom-call\(", text, re.M))
+    bodies = dict(re.findall(r"func\.func \w+ @(\w+)\((.*?)(?=\n  func\.func |\Z)", text, re.S))
+    counts: dict = {}
+
+    def reach(fn: str) -> int:
+        if fn not in counts:
+            counts[fn] = len(re.findall(rf'kernel_name = "[^"]*{kernel}', bodies[fn])) + sum(
+                reach(callee) for callee in re.findall(r"\bcall @(\w+)\(", bodies[fn]))
+        return counts[fn]
+
+    return reach("main")
 
 
 def main(argv=None) -> int:
@@ -104,6 +126,7 @@ def main(argv=None) -> int:
     out = {"workload": args.workload, "parameters": sum(int(x.size) for x in jax.tree_util.tree_leaves(params)),
            "lowered_lines": text.count("\n"), "lowered_sha256": hashlib.sha256(text.encode()).hexdigest(),
            "lower_s": round(time.perf_counter() - t0, 1)}
+    program = text
     if args.text_out:
         with open(args.text_out, "w") as f:
             f.write(text)
@@ -111,14 +134,17 @@ def main(argv=None) -> int:
         t0 = time.perf_counter()
         compiled = lowered.compile()
         m = compiled.memory_analysis()
+        program = compiled.as_text()
         if args.hlo_out:
             with open(args.hlo_out, "w") as f:
-                f.write(compiled.as_text())
+                f.write(program)
         out.update(compile_s=round(time.perf_counter() - t0, 1), argument_bytes=m.argument_size_in_bytes,
                    output_bytes=m.output_size_in_bytes, alias_bytes=m.alias_size_in_bytes, temp_bytes=m.temp_size_in_bytes,
                    generated_code_bytes=m.generated_code_size_in_bytes,
                    arguments_plus_temporaries_gb=(m.argument_size_in_bytes + m.temp_size_in_bytes) / 1e9,
-                   conditionals=compiled.as_text().count(" conditional("))
+                   conditionals=program.count(" conditional("))
+    out.update({f"attention_{name}_kernels": kernel_calls(program, "splash_mqa_" + kernel)
+                for name, kernel in (("forward", "fwd"), ("dkv", "dkv"), ("dq", "dq"))})
     print(json.dumps(out))
     return 0
 

@@ -33,7 +33,8 @@ What is particular here:
 Norm statistics, router scores, sigmoid, top-k and every softmax run in
 float32 (the router's product at ``highest`` precision); products elsewhere in
 ``dtype``.  The blocks are unrolled (a scan would hide them from the
-profiler's scopes) and each is rematerialised in the backward pass.
+profiler's scopes) and each is rematerialised in the backward pass, keeping
+the attention kernel's output (``sdar_moe.remat_block``).
 """
 
 from __future__ import annotations
@@ -45,7 +46,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from sheeprl_tpu.models.sdar_moe import RoutedExperts, RoutedSpec, rms_norm
+from sheeprl_tpu.models.sdar_moe import RoutedExperts, RoutedSpec, remat_block, rms_norm
 from sheeprl_tpu.ops.block_sparse_attention import SegmentMask, block_sparse_flash_attention
 
 Dtype = Any
@@ -264,7 +265,7 @@ class MtpModule(nn.Module):
         self.hnorm = self.param("hnorm", nn.initializers.ones, (d,), jnp.float32)
         self.eh_proj = self.param("eh_proj", _INIT, (2 * d, d), jnp.float32)
         self.norm = self.param("norm", nn.initializers.ones, (d,), jnp.float32)
-        self.block = (nn.remat(MlaBlock) if self.remat else MlaBlock)(self.cfg, True, self.dtype)
+        self.block = (remat_block(MlaBlock) if self.remat else MlaBlock)(self.cfg, True, self.dtype)
 
     def __call__(self, emb_next: jax.Array, u: jax.Array, pos: jax.Array):
         eps = self.cfg.rms_norm_eps
@@ -292,7 +293,7 @@ class MlaMoE(nn.Module):
         self.head = self.param("head", _INIT, (c.hidden_size, c.vocab_size), jnp.float32)
         self.value = self.param("value", _INIT, (c.hidden_size, 1), jnp.float32)
         self.final_norm = self.param("final_norm", nn.initializers.ones, (c.hidden_size,), jnp.float32)
-        block = nn.remat(MlaBlock) if self.remat else MlaBlock
+        block = remat_block(MlaBlock) if self.remat else MlaBlock
         self.layers = [block(c, i >= c.first_k_dense_replace, self.dtype, name=f"layer_{i}")
                        for i in range(c.num_hidden_layers)]
         if c.num_nextn_predict_layers:

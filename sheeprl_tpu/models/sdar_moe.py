@@ -28,7 +28,9 @@ What is particular here:
   blocks (a denoising pass, or the pass that writes a finished block).
 
 Router logits, softmax, top-k and every norm run in float32 (the router's
-product at ``highest`` precision); products elsewhere in ``dtype``.
+product at ``highest`` precision); products elsewhere in ``dtype``.  With
+``remat`` each layer is rematerialised in the backward pass, keeping the
+attention kernel's output (``remat_block``).
 """
 
 from __future__ import annotations
@@ -42,7 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from sheeprl_tpu.ops.block_sparse_attention import SegmentMask, block_sparse_flash_attention
+from sheeprl_tpu.ops.block_sparse_attention import KERNEL_RESIDUALS, SegmentMask, block_sparse_flash_attention
 
 Dtype = Any
 _INIT = nn.initializers.normal(0.02)
@@ -154,6 +156,14 @@ class EpisodeLayout:
         tokens = jnp.concatenate([prompt, response.reshape(bsz, -1), copies.reshape(bsz, -1)], axis=1)
         base = self.n_clean + (jnp.arange(self.n_blocks)[:, None] * self.steps + jnp.arange(self.steps)) * self.block
         return tokens.astype(jnp.int32), (base + order).reshape(bsz, -1).astype(jnp.int32)
+
+
+def remat_block(block, **kwargs):
+    """``block`` (a module class) rematerialised in the backward pass, keeping beside its input the
+    attention kernel's output and log-sum-exp (``KERNEL_RESIDUALS``): all that the kernel's backward
+    rule wants of its forward, so the rematerialised block does not run the forward kernel again.
+    Everything else in the block is computed a second time."""
+    return nn.remat(block, policy=jax.checkpoint_policies.save_only_these_names(KERNEL_RESIDUALS), **kwargs)
 
 
 def rms_norm(x: jax.Array, g: jax.Array, eps: float) -> jax.Array:
@@ -482,7 +492,7 @@ class SdarMoE(nn.Module):
         self.head = self.param("head", _INIT, (c.hidden_size, c.vocab_size), jnp.float32)
         self.value = self.param("value", _INIT, (c.hidden_size, 1), jnp.float32)
         self.final_norm = self.param("final_norm", nn.initializers.ones, (c.hidden_size,), jnp.float32)
-        layer = nn.remat(SdarLayer, static_argnums=(3,)) if self.remat else SdarLayer
+        layer = remat_block(SdarLayer, static_argnums=(3,)) if self.remat else SdarLayer
         self.layers = [layer(c, self.dtype, name=f"layer_{i}") for i in range(c.num_hidden_layers)]
 
     def _finish(self, h, auxes):

@@ -7,6 +7,13 @@ policies: grouped-query attention under the block-diffusion mask
 causal (``models/mla_moe.py``).  ``ring_attention.blockwise_attention`` is the
 plain-XLA causal op of ``MultiHeadSelfAttention`` and takes no mask.
 
+The forward kernel's two results, its output and its log-sum-exp, carry the
+checkpoint name ``KERNEL_RESIDUALS``: they are all the kernel's backward rule
+wants of its forward.  A caller that rematerialises the block around the op
+under ``jax.checkpoint_policies.save_only_these_names(KERNEL_RESIDUALS)`` keeps
+them and does not run the forward kernel a second time in the backward pass
+(the models' ``remat``); where no policy asks for the name it is the identity.
+
 Off a TPU the kernel runs only through Pallas' interpreter
 (``interpret=True``: the CPU tests); without it a CPU refuses the call.
 """
@@ -21,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 _LANES = 128  # the kernel's tiles come in multiples of this
+KERNEL_RESIDUALS = "blocked_attention_out"  # the name on the forward kernel's output and log-sum-exp
 
 
 def _head_width(d: int) -> int:
@@ -82,7 +90,8 @@ def _splash_kernel(mask_bytes: Tuple[bytes, ...], padded: Tuple[int, int], rep: 
     # tables as arrays: they must be concrete, not values of whichever trace first asked for the kernel
     with jax.ensure_compile_time_eval():
         return sk.make_splash_mqa_single_device(
-            sm.MultiHeadMask([sm.NumpyMask(dense)] * rep), block_sizes=sizes, interpret=interpret
+            sm.MultiHeadMask([sm.NumpyMask(dense)] * rep), block_sizes=sizes,
+            residual_checkpoint_name=KERNEL_RESIDUALS, interpret=interpret
         )
 
 

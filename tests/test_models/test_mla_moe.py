@@ -11,6 +11,7 @@ grouped products vs a loop over experts), so values of order 1 agree to a few
 leaf's largest entry.
 """
 
+import collections
 import dataclasses
 import filecmp
 import glob
@@ -26,6 +27,8 @@ from sheeprl_tpu.models import mla_moe as M
 from sheeprl_tpu.models import mla_moe_reference as R
 from sheeprl_tpu.models import sdar_moe as S
 from sheeprl_tpu.ops.block_sparse_attention import SegmentMask, _head_width, block_sparse_flash_attention
+
+from . import test_sdar_moe as sdar_tests
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 P, RESP = 8, 16
@@ -130,6 +133,40 @@ def test_program_matches_reference(seed, remat):
             assert not np.asarray(block["bias"]).any()
     assert float(jnp.abs(M.reference_params(grads)["mtp"]["eh_proj"]).max()) > 0  # the MTP loss reaches its module
     assert float(jnp.abs(grads["params"]["layer_0"]["mlp"]["w_down"]).max()) > 0
+
+
+def _kernel_calls(jaxpr):
+    """How often each Pallas kernel is called in ``jaxpr``, by kernel name, every inner jaxpr walked."""
+    calls = collections.Counter()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            calls[eqn.params["name"]] += 1
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)  # a ClosedJaxpr holds its jaxpr
+                if hasattr(inner, "eqns"):
+                    calls += _kernel_calls(inner)
+    return calls
+
+
+@pytest.mark.parametrize("remat", [True, False])
+@pytest.mark.parametrize("model", ["mla_moe", "sdar_moe"])
+def test_backward_pass_runs_the_forward_kernel_once_a_block(model, remat):
+    """A rematerialised block keeps the attention kernel's output and log-sum-exp (``remat_block``), so
+    the gradient of the update's loss calls the forward kernel once a block, as without ``remat``, and
+    not a second time to hand the kernel's backward rule what the first call wrote."""
+    if model == "mla_moe":
+        policy, cfg = _policy(remat=remat)
+        prompt, _, actions, extras = _episodes(0, 2)
+        loss, blocks = _program_loss, cfg["num_hidden_layers"] + cfg["num_nextn_predict_layers"]
+    else:
+        policy, cfg = sdar_tests._policy(remat=remat)
+        prompt, _, _, actions, extras = sdar_tests._episodes(0, 2)
+        loss, blocks = sdar_tests._program_loss, cfg["num_hidden_layers"]
+    params = policy.init(jax.random.PRNGKey(0))
+    grad = jax.make_jaxpr(jax.grad(lambda p: loss(policy, p, prompt, actions, extras)[0]))(params)
+    assert _kernel_calls(grad.jaxpr) == {
+        "splash_mqa_fwd_residuals": blocks, "splash_mqa_dkv_no_residuals": blocks, "splash_mqa_dq_no_residuals": blocks}
 
 
 def test_mtp_gradient_reaches_the_trunk():

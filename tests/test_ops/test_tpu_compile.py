@@ -229,3 +229,43 @@ def test_routed_experts_under_the_sigmoid_rule_compile_for_v5e(chip, direction):
     fn = fwd if direction == "fwd" else jax.grad(lambda p, m: fwd(p, m)[0], argnums=(0, 1))
     text = jax.jit(fn).lower(params, m).compile().as_text()
     assert "tpu_custom_call" in text and text.count(" conditional(") == 1  # 12,288 rows when the load fits, else 65,536
+
+
+# ---- one rematerialised block of each language-model policy, forward + backward, at the published widths and
+# the cells' sizes.  Temporaries of the block that keeps the kernel's output and log-sum-exp (AOT here, PR 31;
+# rematerialised whole, with the forward kernel called twice: 1,784,745,984 and 3,365,251,072)
+REMAT_BLOCK_TEMP_BYTES = {"mla_moe": 1_932_135_936, "sdar_moe": 3_628_106_240}
+
+
+@pytest.mark.parametrize("model", ["mla_moe", "sdar_moe"])
+def test_rematerialised_block_calls_the_forward_kernel_once_on_v5e(chip, model):
+    """``remat_block`` keeps the attention kernel's output and log-sum-exp: the compiled forward + backward of a
+    block holds one forward kernel, not a second one for the backward pass, and what it keeps (68 MB a causal
+    block, 141 MB a block-diffusion one) stays what it cost the temporaries when this was written."""
+    from benchmarks.lm_update_aot import kernel_calls
+    from sheeprl_tpu.models import mla_moe, sdar_moe
+
+    if model == "mla_moe":
+        block = sdar_moe.remat_block(mla_moe.MlaBlock)(mla_moe.MlaMoeConfig(experts_held=16), True, jnp.bfloat16)
+        rows, length, extra = 1, 8192, ()
+    else:
+        layout = sdar_moe.EpisodeLayout(512, 1024, 4, 4)
+        block = sdar_moe.remat_block(sdar_moe.SdarLayer, static_argnums=(3,))(
+            sdar_moe.SdarConfig(experts_held=16), jnp.bfloat16)
+        rows, length, extra = 3, layout.length, (layout,)
+    pos = jnp.arange(length)
+    h = jax.ShapeDtypeStruct((rows, length, 2048), jnp.float32, sharding=chip)
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        jax.eval_shape(lambda: block.init(jax.random.PRNGKey(0), jnp.zeros((1, length, 2048), jnp.float32), pos, *extra)),
+    )
+
+    def loss(p, h):
+        return block.apply(p, h, pos, *extra)[0].sum()
+
+    # the value too: a gradient alone needs no forward pass but the rematerialised one
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(params, h).compile()
+    text = compiled.as_text()
+    for kernel in ("splash_mqa_fwd", "splash_mqa_dkv", "splash_mqa_dq"):
+        assert kernel_calls(text, kernel) == 1, kernel
+    assert compiled.memory_analysis().temp_size_in_bytes <= REMAT_BLOCK_TEMP_BYTES[model] * 1.02
