@@ -683,7 +683,7 @@ def main(runtime, cfg: Dict[str, Any]):
 
         def _pack(payload):
             # already device arrays; only the mesh layout is (re)applied
-            with trace_scope("host_to_device"):
+            with timer("Time/pack"), trace_scope("host_to_device"):
                 payload.data = runtime.shard_batch(dict(payload.data), axis=1)
                 payload.next_obs = runtime.shard_batch(dict(payload.next_obs), axis=0)
 
@@ -756,10 +756,10 @@ def main(runtime, cfg: Dict[str, Any]):
                 jnp.float32(current_ent),
                 jnp.float32(current_lr),
             )
-        pipeline.publish(iter_num, params)
+        with timer("Time/publish"):
+            pipeline.publish(iter_num, params)
+            rolled = health.tick()
         train_step += world_size
-
-        rolled = health.tick()
         if rolled is not None:
             params = restore_like(params, rolled["agent"])
             opt_state = restore_like(opt_state, rolled["optimizer"])
@@ -778,33 +778,37 @@ def main(runtime, cfg: Dict[str, Any]):
 
         # ------------------------------------------------- logging
         if cfg.metric.log_level > 0 and logger:
-            logger.log_metrics({"Info/learning_rate": current_lr}, policy_step)
-            logger.log_metrics({"Info/clip_coef": current_clip, "Info/ent_coef": current_ent}, policy_step)
-            if policy_step - last_log >= cfg.metric.log_every or iter_num == total_iters:
-                observability.on_log(policy_step, train_step, extra=dict(policy_counters) or None)
-                if aggregator and not aggregator.disabled:
-                    logger.log_metrics(aggregator.compute(), policy_step)
-                    aggregator.reset()
-                if not timer.disabled:
-                    timer_metrics = timer.compute()
-                    if timer_metrics.get("Time/train_time", 0) > 0:
-                        logger.log_metrics(
-                            {"Time/sps_train": (train_step - last_train) / timer_metrics["Time/train_time"]},
-                            policy_step,
-                        )
-                    if timer_metrics.get("Time/env_interaction_time", 0) > 0:
-                        logger.log_metrics(
-                            {
-                                "Time/sps_env_interaction": (
-                                    (policy_step - last_log) / world_size * cfg.env.action_repeat
-                                )
-                                / timer_metrics["Time/env_interaction_time"]
-                            },
-                            policy_step,
-                        )
-                    timer.reset()
-                last_log = policy_step
-                last_train = train_step
+            # where an interval ends, on_log reads the sums before this span closes and
+            # timer.reset() drops the registry it was opened under: that region's time
+            # lands in the next interval's record (utils/timer.py)
+            with timer("Time/log"):
+                logger.log_metrics({"Info/learning_rate": current_lr}, policy_step)
+                logger.log_metrics({"Info/clip_coef": current_clip, "Info/ent_coef": current_ent}, policy_step)
+                if policy_step - last_log >= cfg.metric.log_every or iter_num == total_iters:
+                    observability.on_log(policy_step, train_step, extra=dict(policy_counters) or None)
+                    if aggregator and not aggregator.disabled:
+                        logger.log_metrics(aggregator.compute(), policy_step)
+                        aggregator.reset()
+                    if not timer.disabled:
+                        timer_metrics = timer.compute()
+                        if timer_metrics.get("Time/train_time", 0) > 0:
+                            logger.log_metrics(
+                                {"Time/sps_train": (train_step - last_train) / timer_metrics["Time/train_time"]},
+                                policy_step,
+                            )
+                        if timer_metrics.get("Time/env_interaction_time", 0) > 0:
+                            logger.log_metrics(
+                                {
+                                    "Time/sps_env_interaction": (
+                                        (policy_step - last_log) / world_size * cfg.env.action_repeat
+                                    )
+                                    / timer_metrics["Time/env_interaction_time"]
+                                },
+                                policy_step,
+                            )
+                        timer.reset()
+                    last_log = policy_step
+                    last_train = train_step
 
         # ------------------------------------------------- annealing
         if cfg.algo.anneal_lr:

@@ -44,7 +44,7 @@ from sheeprl_tpu.envs.jax.vector import JaxVectorEnv
 from sheeprl_tpu.parallel.pipeline import RolloutPayload
 from sheeprl_tpu.utils.utils import MetricFetchGate
 
-__all__ = ["FusedDiffusionCollector", "FusedOnPolicyCollector", "FusedRecurrentCollector"]
+__all__ = ["FusedCausalCollector", "FusedDiffusionCollector", "FusedOnPolicyCollector", "FusedRecurrentCollector"]
 
 
 class _FusedCollectorBase:
@@ -87,7 +87,12 @@ class _FusedCollectorBase:
         # first carry would make collect #2 a different arg-sharding
         # signature — one extra compile, breaking the flat-counter contract
         self._carry = jax.device_put(self._jinit(self._env_base), runtime.replicated)
-        self._rollout = jax.jit(self._rollout_fn)
+
+        # a name a trace can be filtered by (``jit_collect_rollout``), whichever collector runs
+        def collect_rollout(params, carry, key, env_base):
+            return self._rollout_fn(params, carry, key, env_base)
+
+        self._rollout = jax.jit(collect_rollout)
         # device->host episode-event fetch cadence (metric.fetch_every)
         self._event_gate = MetricFetchGate(cfg.metric.get("fetch_every", 1))
         self._log_events = int(cfg.metric.get("log_level", 1)) > 0
@@ -95,6 +100,11 @@ class _FusedCollectorBase:
         self._n_rollouts = 0
         self._n_episodes = 0
         self._n_event_fetches = 0
+        # update calls between the weights a rollout acts with and the newest (``params_age``): the loop makes
+        # one update call an iteration and hands the result over through ``adopt``
+        self._first_iter = None
+        self._n_adopts = 0
+        self._params_age = 0
 
     # subclasses implement
     def _initial_carry(self, base):
@@ -108,34 +118,60 @@ class _FusedCollectorBase:
         the fused program acts on whatever was last adopted (serial path:
         exactly the previous iteration's update, the host loops' order)."""
         self.params = params
+        self._n_adopts += 1
+
+    def _dispatch_rollout(self, iter_num: int, key_fn):
+        """Dispatches one rollout under ``Time/env_interaction_time`` (the span
+        holds the dispatch alone: the device works on after it) and counts it;
+        returns what ``_rollout_fn`` returns."""
+        from sheeprl_tpu.utils.metric import SumMetric
+        from sheeprl_tpu.utils.timer import timer
+
+        if self._first_iter is None:
+            self._first_iter = iter_num
+        self._params_age = max(0, iter_num - self._first_iter - self._n_adopts)
+        with timer("Time/env_interaction_time", SumMetric, sync_on_compute=False):
+            out = self._rollout(self.params, self._carry, key_fn(), self._env_base)
+        self._n_rollouts += 1
+        self.policy_step += self.rollout_steps * self.total_envs
+        return out
 
     def _apply_events(self, events: Dict[str, Any], step_start: int) -> None:
-        """Fetch + emit on-device episode events at the fetch cadence."""
+        """Fetch + emit on-device episode events at the fetch cadence.  The
+        fetch is where the host first waits for the rollout
+        (``Time/collect_wait``; where the event gate is closed nothing is
+        fetched here and the loop waits later, in its own spans); the host loop
+        over the episodes that ended is ``Time/collect_events``."""
         if not self._log_events or self.aggregator is None:
             return
         if not self._event_gate():
             return
+        from sheeprl_tpu.utils.timer import timer
+
         self._n_event_fetches += 1
-        done = np.asarray(events["done"])  # (T, N)
-        if not done.any():
-            return
-        ep_ret = np.asarray(events["ep_return"])
-        ep_len = np.asarray(events["ep_length"])
+        with timer("Time/collect_wait"):
+            done = np.asarray(events["done"])  # (T, N)
+            if not done.any():
+                return
+            ep_ret = np.asarray(events["ep_return"])
+            ep_len = np.asarray(events["ep_length"])
         per_step = self.total_envs  # policy steps per scan step (global)
-        for t, i in zip(*np.nonzero(done)):
-            self._n_episodes += 1
-            ep_rew = float(ep_ret[t, i])
-            if self.aggregator and "Rewards/rew_avg" in self.aggregator:
-                self.aggregator.update("Rewards/rew_avg", ep_rew)
-            if self.aggregator and "Game/ep_len_avg" in self.aggregator:
-                self.aggregator.update("Game/ep_len_avg", float(ep_len[t, i]))
-            self.runtime.print(
-                f"Rank-0: policy_step={step_start + (int(t) + 1) * per_step}, "
-                f"reward_env_{int(i)}={ep_rew}"
-            )
+        with timer("Time/collect_events"):
+            for t, i in zip(*np.nonzero(done)):
+                self._n_episodes += 1
+                ep_rew = float(ep_ret[t, i])
+                if self.aggregator and "Rewards/rew_avg" in self.aggregator:
+                    self.aggregator.update("Rewards/rew_avg", ep_rew)
+                if self.aggregator and "Game/ep_len_avg" in self.aggregator:
+                    self.aggregator.update("Game/ep_len_avg", float(ep_len[t, i]))
+                self.runtime.print(
+                    f"Rank-0: policy_step={step_start + (int(t) + 1) * per_step}, "
+                    f"reward_env_{int(i)}={ep_rew}"
+                )
 
     def stats(self) -> Dict[str, Any]:
-        """Telemetry provider (``jaxenv`` key in telemetry.jsonl)."""
+        """Telemetry provider (``jaxenv`` key in telemetry.jsonl); the counts
+        are cumulative over the run."""
         return {
             "backend": "jax",
             "fused": True,
@@ -146,6 +182,7 @@ class _FusedCollectorBase:
             "env_steps": self._n_rollouts * self.rollout_steps * self.total_envs,
             "episodes_reported": self._n_episodes,
             "event_fetches": self._n_event_fetches,
+            "params_age": self._params_age,
         }
 
 
@@ -212,15 +249,9 @@ class FusedOnPolicyCollector(_FusedCollectorBase):
         return carry, data, events
 
     def collect(self, iter_num: int, inline: bool, key_fn) -> RolloutPayload:
-        from sheeprl_tpu.utils.metric import SumMetric
-        from sheeprl_tpu.utils.timer import timer
-
         payload = RolloutPayload(iter_num)
         step_start = self.policy_step
-        with timer("Time/env_interaction_time", SumMetric, sync_on_compute=False):
-            self._carry, data, events = self._rollout(self.params, self._carry, key_fn(), self._env_base)
-        self._n_rollouts += 1
-        self.policy_step += self.rollout_steps * self.total_envs
+        self._carry, data, events = self._dispatch_rollout(iter_num, key_fn)
         self._apply_events(events, step_start)
         payload.data = data
         payload.next_obs = {k: self._carry["obs"][k] for k in self.obs_keys}
@@ -329,17 +360,9 @@ class FusedRecurrentCollector(_FusedCollectorBase):
         return carry, data, events, next_values
 
     def collect(self, iter_num: int, inline: bool, key_fn) -> RolloutPayload:
-        from sheeprl_tpu.utils.metric import SumMetric
-        from sheeprl_tpu.utils.timer import timer
-
         payload = RolloutPayload(iter_num)
         step_start = self.policy_step
-        with timer("Time/env_interaction_time", SumMetric, sync_on_compute=False):
-            self._carry, data, events, next_values = self._rollout(
-                self.params, self._carry, key_fn(), self._env_base
-            )
-        self._n_rollouts += 1
-        self.policy_step += self.rollout_steps * self.total_envs
+        self._carry, data, events, next_values = self._dispatch_rollout(iter_num, key_fn)
         self._apply_events(events, step_start)
         payload.data = data
         payload.next_obs = {k: self._carry["vstate"]["obs"][k] for k in self.obs_keys}
@@ -353,21 +376,32 @@ class _FusedEpisodeCollector(_FusedCollectorBase):
     names them): one rollout is one whole episode per env over
     ``envs/jax/tokens.py``, the carry between rollouts is the env state alone,
     and ``_rollout_fn`` returns ``(vstate, data, events)`` with the prompt
-    among the data."""
+    among the data.
+
+    The rollout program names its phases for a trace (``jax.named_scope``,
+    metadata only; the model's own scopes lie beneath them): ``collect_prefill``
+    (the prompt's pass and the cache's first fill), the cached passes
+    (``collect_denoise`` and ``collect_commit``, or ``collect_decode``),
+    ``collect_score`` (head and value), ``collect_sample`` (the position's and
+    the token's choice, the recorded log-probability and value) and
+    ``collect_env`` (``vector_step``).  ``stats()`` counts what the rollouts
+    did, exactly and from shapes alone: forward ``passes`` of the model and the
+    ``positions`` those ran over (what a trace's time per pass is taken over)."""
 
     def _initial_carry(self, base):
         return vector_reset(self.jax_env, base, self.total_envs)
 
-    def collect(self, iter_num: int, inline: bool, key_fn) -> RolloutPayload:
-        from sheeprl_tpu.utils.metric import SumMetric
-        from sheeprl_tpu.utils.timer import timer
+    def _rollout_work(self) -> Dict[str, int]:
+        """``passes`` and ``positions`` of ONE rollout."""
+        raise NotImplementedError
 
+    def stats(self) -> Dict[str, Any]:
+        return {**super().stats(), **{k: v * self._n_rollouts for k, v in self._rollout_work().items()}}
+
+    def collect(self, iter_num: int, inline: bool, key_fn) -> RolloutPayload:
         payload = RolloutPayload(iter_num)
         step_start = self.policy_step
-        with timer("Time/env_interaction_time", SumMetric, sync_on_compute=False):
-            self._carry, data, events = self._rollout(self.params, self._carry, key_fn(), self._env_base)
-        self._n_rollouts += 1
-        self.policy_step += self.rollout_steps * self.total_envs
+        self._carry, data, events = self._dispatch_rollout(iter_num, key_fn)
         self._apply_events(events, step_start)
         payload.data = data
         payload.next_obs = {k: self._carry["obs"][k] for k in self.obs_keys}
@@ -390,6 +424,10 @@ class FusedCausalCollector(_FusedEpisodeCollector):
     form of the attention) over the token it has just appended.  The model's
     multi-token-prediction module is not used to draft."""
 
+    def _rollout_work(self) -> Dict[str, int]:
+        n_env, p_len, r_len = self.total_envs, self.jax_env.prompt_len, self.jax_env.response_len
+        return {"passes": 1 + r_len, "positions": n_env * (p_len + r_len)}
+
     def _rollout_fn(self, params, vstate, key, env_base):
         from sheeprl_tpu.models.mla_moe import MlaMoE
 
@@ -397,26 +435,32 @@ class FusedCausalCollector(_FusedEpisodeCollector):
         model = policy.model
         n_env, p_len, r_len = self.total_envs, env.prompt_len, env.response_len
 
-        prompt = vstate["obs"]["tokens"][:, :p_len]
-        u, _, latents = model.apply(params, prompt, True, method=MlaMoE.hidden)
-        cache = [
-            tuple(jnp.zeros((n_env, p_len + r_len) + x.shape[2:], x.dtype).at[:, :p_len].set(x) for x in lat)
-            for lat in latents
-        ]
+        with jax.named_scope("collect_prefill"):
+            prompt = vstate["obs"]["tokens"][:, :p_len]
+            u, _, latents = model.apply(params, prompt, True, method=MlaMoE.hidden)
+            cache = [
+                tuple(jnp.zeros((n_env, p_len + r_len) + x.shape[2:], x.dtype).at[:, :p_len].set(x) for x in lat)
+                for lat in latents
+            ]
 
         def step_fn(carry, xs):
             vstate, cache, last = carry
             t, step_key = xs
-            logp_all, values = model.apply(params, last, method=MlaMoE.logits)
-            x = jax.random.categorical(step_key, logp_all, axis=-1)
-            action = jnp.stack([jnp.zeros_like(x), x], -1).astype(jnp.int32)
-            vstate, out = vector_step(env, vstate, action, env_base, None)
+            with jax.named_scope("collect_score"):
+                logp_all, values = model.apply(params, last, method=MlaMoE.logits)
+            with jax.named_scope("collect_sample"):
+                x = jax.random.categorical(step_key, logp_all, axis=-1)
+                action = jnp.stack([jnp.zeros_like(x), x], -1).astype(jnp.int32)
+                logprob = jnp.take_along_axis(logp_all, x[:, None], axis=-1)
+            with jax.named_scope("collect_env"):
+                vstate, out = vector_step(env, vstate, action, env_base, None)
             # the appended token's pass: its latents join the cache, its hidden state scores the next step
             # (after the last token the env has reset, and what is written is never read)
-            u, cache = model.apply(params, x[:, None].astype(jnp.int32), cache, p_len + t, method=MlaMoE.step)
+            with jax.named_scope("collect_decode"):
+                u, cache = model.apply(params, x[:, None].astype(jnp.int32), cache, p_len + t, method=MlaMoE.step)
             rec = {
                 "actions": action,
-                "logprobs": jnp.take_along_axis(logp_all, x[:, None], axis=-1),
+                "logprobs": logprob,
                 "values": values[:, None],
                 "rewards": out["reward"][:, None],
                 "dones": out["done"][:, None].astype(jnp.float32),
@@ -448,6 +492,12 @@ class FusedDiffusionCollector(_FusedEpisodeCollector):
     model's (SDAR's own low-confidence sampler ranks positions by the *sampled*
     token's probability, which a policy-gradient ratio cannot reproduce)."""
 
+    def _rollout_work(self) -> Dict[str, int]:
+        n_env, p_len, r_len = self.total_envs, self.jax_env.prompt_len, self.jax_env.response_len
+        block, steps = self.module.cfg.block_length, self.module.cfg.denoise_steps
+        passes = self.module.layout.n_blocks * (steps + 1)  # a block's denoising passes and the one that commits it
+        return {"passes": 1 + passes, "positions": n_env * (p_len + passes * block)}
+
     def _rollout_fn(self, params, vstate, key, env_base):
         from sheeprl_tpu.models.sdar_moe import SdarMoE
 
@@ -458,11 +508,12 @@ class FusedDiffusionCollector(_FusedEpisodeCollector):
         s_max = policy.layout.n_clean
 
         # the prompt's keys and values: one clean pass under the block-causal mask
-        prompt = vstate["obs"]["tokens"][:, :p_len]
-        _, _, kvs = model.apply(params, prompt, policy.prefill_layout, True, method=SdarMoE.hidden)
-        cache = [
-            tuple(jnp.zeros((n_env, s_max) + x.shape[2:], x.dtype).at[:, :p_len].set(x) for x in kv) for kv in kvs
-        ]
+        with jax.named_scope("collect_prefill"):
+            prompt = vstate["obs"]["tokens"][:, :p_len]
+            _, _, kvs = model.apply(params, prompt, policy.prefill_layout, True, method=SdarMoE.hidden)
+            cache = [
+                tuple(jnp.zeros((n_env, s_max) + x.shape[2:], x.dtype).at[:, :p_len].set(x) for x in kv) for kv in kvs
+            ]
 
         def current(vstate, length):
             return jax.lax.dynamic_slice_in_dim(vstate["obs"]["tokens"], length, block, axis=1)
@@ -474,29 +525,37 @@ class FusedDiffusionCollector(_FusedEpisodeCollector):
             pos = length + jnp.arange(block)
             recs = []
             for j in range(steps):
-                tokens = current(vstate, length)
-                hidden, _, _ = model.apply(params, tokens, pos, cache, length, method=SdarMoE.block)
-                logp_all, values = model.apply(params, hidden, method=SdarMoE.score)
-                masked = tokens == mcfg.mask_id
-                u = jnp.argmax(jnp.where(masked, logp_all.max(-1), -jnp.inf), axis=-1)
-                logp_u = jnp.take_along_axis(logp_all, u[:, None, None], axis=1)[:, 0]
-                x = jax.random.categorical(keys[j], logp_u, axis=-1)
-                vstate, out = vector_step(env, vstate, jnp.stack([u, x], -1).astype(jnp.int32), env_base, None)
+                with jax.named_scope("collect_denoise"):
+                    tokens = current(vstate, length)
+                    hidden, _, _ = model.apply(params, tokens, pos, cache, length, method=SdarMoE.block)
+                with jax.named_scope("collect_score"):
+                    logp_all, values = model.apply(params, hidden, method=SdarMoE.score)
+                with jax.named_scope("collect_sample"):
+                    masked = tokens == mcfg.mask_id
+                    u = jnp.argmax(jnp.where(masked, logp_all.max(-1), -jnp.inf), axis=-1)
+                    logp_u = jnp.take_along_axis(logp_all, u[:, None, None], axis=1)[:, 0]
+                    x = jax.random.categorical(keys[j], logp_u, axis=-1)
+                    action = jnp.stack([u, x], -1).astype(jnp.int32)
+                    logprob = jnp.take_along_axis(logp_u, x[:, None], axis=-1)
+                    value = jnp.take_along_axis(values, u[:, None], axis=1)
+                with jax.named_scope("collect_env"):
+                    vstate, out = vector_step(env, vstate, action, env_base, None)
                 recs.append({
-                    "actions": jnp.stack([u, x], -1).astype(jnp.int32),
-                    "logprobs": jnp.take_along_axis(logp_u, x[:, None], axis=-1),
-                    "values": jnp.take_along_axis(values, u[:, None], axis=1),
+                    "actions": action,
+                    "logprobs": logprob,
+                    "values": value,
                     "rewards": out["reward"][:, None],
                     "dones": out["done"][:, None].astype(jnp.float32),
                     "ev": {"done": out["done"], "ep_return": out["ep_return"], "ep_length": out["ep_length"]},
                 })
             # the finished block's keys and values join the cache (after the last
             # block the env has reset, and what is written is never read)
-            _, _, kvs = model.apply(params, current(vstate, length), pos, cache, length, method=SdarMoE.block)
-            cache = [
-                tuple(jax.lax.dynamic_update_slice_in_dim(c, x, length, axis=1) for c, x in zip(kv_cache, kv))
-                for kv_cache, kv in zip(cache, kvs)
-            ]
+            with jax.named_scope("collect_commit"):
+                _, _, kvs = model.apply(params, current(vstate, length), pos, cache, length, method=SdarMoE.block)
+                cache = [
+                    tuple(jax.lax.dynamic_update_slice_in_dim(c, x, length, axis=1) for c, x in zip(kv_cache, kv))
+                    for kv_cache, kv in zip(cache, kvs)
+                ]
             return (vstate, cache), jax.tree_util.tree_map(lambda *x: jnp.stack(x), *recs)
 
         keys = jax.random.split(jnp.asarray(key), n_blocks * steps).reshape(n_blocks, steps, -1)
