@@ -6,9 +6,17 @@ without a single full-size array: what the program would hold on the chip
 checkouts' programs against each other, and how often it calls each of the
 attention's kernels (``attention_forward_kernels``: once a block where the
 rematerialised blocks keep the kernel's output, twice where they do not).
+Compiled, it also counts the routed layers' arrays with a hidden-wide row for
+every (token, choice) pair, by the conditionals' branches
+(``pair_arrays_short_branches``: none since PR 33, whose short branch reads a
+token's held choices only).  ``--rollout`` lowers the kind's fused collector's
+rollout in place of the update, at the cell's envs and lengths, for its hash
+and its conditionals (the prefill's routed layers have both buffer lengths,
+the cached passes one; that one builds the parameters: 2-3 GB on the host).
 
     JAX_PLATFORMS=cpu python benchmarks/lm_update_aot.py --workload sdar_ep8_train            # hash only
     JAX_PLATFORMS=cpu python benchmarks/lm_update_aot.py --workload joyai_ep_train --compile  # + bytes, ~2-4 min
+    JAX_PLATFORMS=cpu python benchmarks/lm_update_aot.py --workload sdar_ep8_loop --rollout   # the collector's hash
 
 Everything is built as ``ppo.main`` builds it (``build_agent``,
 ``build_ppo_optimizer``, ``make_update_fn``), under ``jax.eval_shape``.  Takes
@@ -69,10 +77,33 @@ def kernel_calls(text: str, kernel: str) -> int:
     return reach("main")
 
 
+def computation(text: str, name: str) -> str:
+    """The body of a compiled program's computation ``name``: its own ops, one a line."""
+    body = text[text.index(f"\n%{name} ("):]
+    return body[:body.index("\n}\n")]
+
+
+def pair_arrays(text: str, rows: int, width: int) -> dict:
+    """Arrays of ``rows`` x ``width`` that the branches of a compiled program's two-way conditionals
+    define, by branch: 0 is taken where the predicate is false (the routed layer's worst-case buffer),
+    1 where it is true (its short one)."""
+    import re
+
+    found = {"long": 0, "short": 0}
+    for branches in re.findall(r" conditional\(.*branch_computations=\{([^}]*)\}", text):
+        names = re.findall(r"%([\w.\-]+)", branches)
+        if len(names) != 2:
+            continue
+        for key, name in zip(found, names):
+            found[key] += len(re.findall(rf"^\s*(?:ROOT )?%[\w.\-]+ = \w+\[{rows},{width}\]", computation(text, name), re.M))
+    return found
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__, formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("--workload", required=True, help="a cell of chipbench/workloads whose driver trains a language-model policy")
     ap.add_argument("--compile", action="store_true", help="compile for the described chip and print its memory analysis")
+    ap.add_argument("--rollout", action="store_true", help="lower the fused collector's rollout, not the update (hash only)")
     ap.add_argument("--override", action="append", default=[], help="a further override of the program's configuration")
     ap.add_argument("--text-out", help="write the lowered text here")
     ap.add_argument("--hlo-out", help="with --compile: write the compiled program's text here (scopes in op_name metadata)")
@@ -101,6 +132,23 @@ def main(argv=None) -> int:
     chip = SingleDeviceSharding(topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices[0])
     on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)
+
+    if args.rollout:
+        from sheeprl_tpu.algos.ppo.lm_policy import language_model_policy
+        from sheeprl_tpu.utils.env import make_train_envs
+
+        runtime.seed_everything(0)
+        envs = make_train_envs(cfg, runtime, None)
+        policy, params = build_agent(runtime, (), False, cfg, envs.single_observation_space)
+        collector = language_model_policy(cfg).collector_class(
+            envs=envs, module=policy, params=params, cfg=cfg, runtime=runtime, obs_keys=["tokens"],
+            total_envs=int(cfg.env.num_envs), world_size=1, aggregator=None)
+        text = location_free(collector._rollout.lower(
+            *on_chip((collector.params, collector._carry, runtime.next_key(), collector._env_base))).as_text())
+        print(json.dumps({"workload": args.workload, "program": type(collector).__name__ + " rollout",
+                          "lowered_lines": text.count("\n"), "lowered_sha256": hashlib.sha256(text.encode()).hexdigest(),
+                          "conditionals": text.count("stablehlo.case") + text.count("stablehlo.if")}))
+        return 0
 
     built = {}
 
@@ -143,6 +191,10 @@ def main(argv=None) -> int:
                    generated_code_bytes=m.generated_code_size_in_bytes,
                    arguments_plus_temporaries_gb=(m.argument_size_in_bytes + m.temp_size_in_bytes) / 1e9,
                    conditionals=program.count(" conditional("))
+        spec = policy.cfg.routed_spec
+        positions = policy.layout.length if hasattr(policy, "layout") else int(cfg.env.wrapper.prompt_len) + int(cfg.env.wrapper.response_len)
+        pairs = pair_arrays(program, int(cfg.algo.per_rank_batch_size) * positions * spec.top_k, spec.hidden_size)
+        out.update(pair_arrays_long_branches=pairs["long"], pair_arrays_short_branches=pairs["short"])
     out.update({f"attention_{name}_kernels": kernel_calls(program, "splash_mqa_" + kernel)
                 for name, kernel in (("forward", "fwd"), ("dkv", "dkv"), ("dq", "dq"))})
     print(json.dumps(out))

@@ -402,7 +402,8 @@ def make_episode_update_fn(runtime, policy, tx: optax.GradientTransformation, cf
     ``probe`` holds what each minibatch step produced (its episodes,
     log-probabilities, values, losses, the gradient's norm whole and leaf by
     leaf, the norm of every leaf's change ``new - old`` in float32, routing
-    choice, load per expert and whether the sorted buffer was the short one),
+    choice, load per expert, whether the sorted buffer was the short one with
+    the compact token side, and the tokens that hold more choices than it reads),
     stacked over the call's steps, for whoever compares the update with the
     plain reference."""
     if runtime.world_size > 1:
@@ -461,6 +462,7 @@ def make_episode_update_fn(runtime, policy, tx: optax.GradientTransformation, cf
                      "grad_norm": optax.global_norm(grads), "grad_leaf_norms": jax.tree_util.tree_map(leaf_norm, grads),
                      "moved_leaf_norms": moved, "load": aux["load"], "dropped": aux["dropped"].sum(),
                      "top_i": aux["top_i"], "entropy": aux["entropy"].mean(), "short": aux["short"],
+                     "overflow": aux["overflow"].max(),
                      "aux_counters": aux.get("aux_counters", {})}
             return (new_params, opt_state), probe
 
@@ -482,7 +484,10 @@ def make_episode_update_fn(runtime, policy, tx: optax.GradientTransformation, cf
             "MoE/load_max_over_mean": (load.max(-1) / jnp.maximum(load.mean(-1), 1.0)).max(),
             "MoE/held_share": (load.sum(-1) / assignments).mean(),
             "MoE/dropped": probe["dropped"].sum().astype(jnp.float32),
-            "MoE/short_buffer_share": probe["short"].astype(jnp.float32).mean(),  # of the call's layer passes
+            # of the call's layer passes: those that took the short buffer with the compact token side
+            "MoE/short_buffer_share": probe["short"].astype(jnp.float32).mean(),
+            # the longest list a layer pass of the call would have needed (models/sdar_moe.py compact_slots)
+            "MoE/overflow_tokens": probe["overflow"].max().astype(jnp.float32),
             "MoE/router_entropy": probe["entropy"].mean(),
             **{f"MoE/load_l{i}_e{e}": load[i, e] for i in range(load.shape[0]) for e in range(load.shape[1])},
             **{name: v.mean() for name, v in probe["aux_counters"].items()},

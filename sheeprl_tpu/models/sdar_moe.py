@@ -15,10 +15,17 @@ What is particular here:
   load: ``short_buffer_rows`` rows (``SHORT_BUFFER_SHARES`` even shares) when
   the assignments to held experts fit, else, by ``jax.lax.cond``, a row for
   every assignment a token could make to a held expert (``tokens x min(top_k,
-  experts_held)``).  Both lengths hold every held assignment: it is dropless,
-  nothing is ever cut, whatever the imbalance.  Where the short length would
-  save nothing (all experts held, or few tokens) there is one length and no
-  conditional.
+  experts_held)``).  Where the short buffer is large (``COMPACT_OVER_BYTES``:
+  SDAR's update, not the causal model's nor a collector's prefill) the token
+  side follows the counted load with it: beside the short buffer the combine,
+  the routing weights' gradient and the dispatch's backward read a token's
+  held choices only (``compact_slots``: a few rows a token, and a short list
+  of the tokens that hold more), beside the worst-case buffer a row for every
+  (token, choice) pair; the one conditional then takes the short form only if
+  the load fits the buffer AND that list.  Both branches hold every held
+  assignment: it is dropless, nothing is ever cut, whatever the imbalance.
+  Where the short length would save nothing (all experts held, or few tokens)
+  there is one length, the pair-wide token side and no conditional.
 - attention takes its mask as data (``ops.block_sparse_attention.SegmentMask``): one
   packed episode holds the clean sequence and every denoising step's noised
   copy of its block (``EpisodeLayout``), so that one forward pass scores a whole
@@ -37,7 +44,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Mapping, Tuple
+from typing import Any, Dict, Mapping, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -188,47 +195,101 @@ def rope(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
 # sorted order (by held expert; the head of a permutation, long enough for every assignment to a held
 # expert this call) and ``inv`` (N, k) is each assignment's row.  Both directions of both moves are
 # gathers: XLA's own transpose of a gather is a scatter-add, which a TPU serialises.
+#
+# The token side sums, for every token, the rows of its held assignments (the combine's forward, the
+# dispatch's backward).  Its k-wide form reads a row for EVERY pair and zeroes those not held
+# (``_rows_of``: an (N, k, d) array, 553 MB at the published sizes, 81-93 % of it zeros where a chip
+# holds an eighth of the experts); its compact form (``slots`` given) reads ``c`` rows a token, the
+# token's first ``c`` held choices, and the few tokens that hold more get the k-wide form over a
+# fixed list of ``r`` of them, merged back by a gather (``compact_slots``, ``_slots``).  The routing
+# weights' gradient needs no rows of its own in row space (``_combine_bwd``).
 def _rows_of(sorted_rows, inv, keep):
     """Every assignment's row (N, k, d); zeros where ``keep`` (N, k) is unset."""
     rows = sorted_rows[jnp.minimum(inv, sorted_rows.shape[0] - 1)]
     return jnp.where(keep[..., None], rows, 0)
 
 
-@jax.custom_vjp
-def _dispatch(m, perm, inv, held):
+def _slots(held, c, r):
+    """What the compact token-side sums need of ``held`` (N, k): ``slot_of`` (N, k), the place of a
+    held choice among its token's held choices (k where not held); ``over`` (r,), the tokens with more
+    than ``c`` held choices (N from the list's end on) and ``over_at`` (N,), such a token's place in
+    that list (-1 for every other token); and how many such tokens there are: the list holds them all
+    only if that is at most ``r``."""
+    k = held.shape[1]
+    slot_of = jnp.where(held, jnp.cumsum(held, axis=1) - 1, k).astype(jnp.int32)
+    more = held.sum(1) > c
+    listed = jnp.cumsum(more)
+    over = jnp.searchsorted(listed, jnp.arange(1, r + 1), method="compare_all").astype(jnp.int32)
+    return (slot_of, over, jnp.where(more, listed - 1, -1).astype(jnp.int32)), listed[-1]
+
+
+def _held_sums(sorted_rows, w, inv, slots, c):
+    """``out[t] = sum_j w[t, j] * sorted_rows[inv[t, j]]`` over token ``t``'s held choices, float32:
+    ``c`` rows a token, and the listed tokens' further rows on top."""
+    slot_of, over, over_at = slots
+    n, k = inv.shape
+    # a gather of (N, d) a slot: as one (N, c, d) array c is padded to a whole tile of rows in every token, and as
+    # (c, N, d) the rows are first converted to float32 by an op of their own (415 MB at the published sizes)
+    hit = slot_of[None] == jnp.arange(c)[:, None, None]  # (c, N, k): the choice that is its token's held choice number s
+    at_slot, w_slot = (jnp.where(hit, x[None], 0).sum(-1) for x in (inv, w.astype(jnp.float32)))
+    out = sum(_rows_of(sorted_rows, at_slot[s], hit[s].any(-1)).astype(jnp.float32) * w_slot[s][:, None] for s in range(c))
+    at = jnp.minimum(over, n - 1)
+    rest = (slot_of[at] >= c) & (slot_of[at] < k) & (over < n)[:, None]  # (r, k): the listed tokens' further held choices
+    extra = jnp.einsum("rkd,rk->rd", _rows_of(sorted_rows, inv[at], rest), jnp.where(rest, w[at], 0),
+                       preferred_element_type=jnp.float32)
+    return out + jnp.where((over_at >= 0)[:, None], extra[jnp.clip(over_at, 0, over.shape[0] - 1)], 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dispatch(c, m, perm, inv, held, slots):
     """Rows of ``m`` (N, d) in sorted order: row ``r`` is the token of assignment ``perm[r]``."""
     return m[perm // inv.shape[1]]
 
 
-def _dispatch_fwd(m, perm, inv, held):
-    return _dispatch(m, perm, inv, held), (inv, held)
+def _dispatch_fwd(c, m, perm, inv, held, slots):
+    return _dispatch(c, m, perm, inv, held, slots), (inv, held, slots)
 
 
-def _dispatch_bwd(res, g):
-    inv, held = res
-    return _rows_of(g, inv, held).sum(1).astype(g.dtype), None, None, None
+def _dispatch_bwd(c, res, g):
+    inv, held, slots = res
+    if slots is None:
+        gm = _rows_of(g, inv, held).sum(1).astype(g.dtype)
+    else:
+        gm = _held_sums(g, held.astype(g.dtype), inv, slots, c).astype(g.dtype)
+    return gm, None, None, None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@jax.custom_vjp
-def _combine(y_sorted, w, perm, inv):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _combine(c, y_sorted, w, perm, inv, slots):
     """``out[t] = sum_j w[t, j] * y_sorted[inv[t, j]]``, float32; ``w`` (N, k) is 0 where the
     assignment is not held."""
+    if slots is not None:
+        return _held_sums(y_sorted, w, inv, slots, c)
     return jnp.einsum("nkd,nk->nd", _rows_of(y_sorted, inv, w != 0), w, preferred_element_type=jnp.float32)
 
 
-def _combine_fwd(y_sorted, w, perm, inv):
-    return _combine(y_sorted, w, perm, inv), (y_sorted, w, perm, inv)
+def _combine_fwd(c, y_sorted, w, perm, inv, slots):
+    return _combine(c, y_sorted, w, perm, inv, slots), (y_sorted, w, perm, inv, slots)
 
 
-def _combine_bwd(res, g):
-    y_sorted, w, perm, inv = res
-    gw = jnp.einsum("nkd,nd->nk", _rows_of(y_sorted, inv, w != 0), g, preferred_element_type=jnp.float32)
-    # gathered in the rows' dtype: in f32 this gather alone wrote 1.1 GB a layer at the published sizes
-    gy = g.astype(y_sorted.dtype)[perm // w.shape[1]] * w.reshape(-1)[perm][:, None].astype(y_sorted.dtype)
-    return gy, gw, None, None
+def _combine_bwd(c, res, g):
+    y_sorted, w, perm, inv, slots = res
+    k = w.shape[1]
+    if slots is None:
+        gw = jnp.einsum("nkd,nd->nk", _rows_of(y_sorted, inv, w != 0), g, preferred_element_type=jnp.float32)
+        # gathered in the rows' dtype: in f32 this gather alone wrote 1.1 GB a layer at the published sizes
+        gy = g.astype(y_sorted.dtype)[perm // k] * w.reshape(-1)[perm][:, None].astype(y_sorted.dtype)
+    else:
+        # in row space: row r belongs to token perm[r] // k, so <y_sorted[inv[t, j]], g[t]> is row inv[t, j] of the
+        # rows' own products with their tokens' g (float32, as the k-wide form's), and gw a gather of scalars
+        g_rows = g[perm // k]
+        gw_rows = (y_sorted.astype(jnp.float32) * g_rows).sum(-1)
+        gw = jnp.where(w != 0, gw_rows[jnp.minimum(inv, gw_rows.shape[0] - 1)], 0)
+        gy = g_rows.astype(y_sorted.dtype) * w.reshape(-1)[perm][:, None].astype(y_sorted.dtype)
+    return gy, gw, None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -245,6 +306,25 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 # fell back to the worst case and the steps' cost followed the seed.
 SHORT_BUFFER_SHARES = 3
 ROW_TILE = 512  # the short length is whole tiles of rows
+# Beside the short buffer the token side reads ``c`` rows a token, one more than the buffer's own rows a
+# token, and lists the tokens that hold more: a sixteenth of the tokens at most, else the call falls
+# back with the buffer.  Set from the same readings taken per token (PERF.md section 6, PR 33): a
+# random router sends its tokens to held experts far less evenly than an even draw would (0.9 % of
+# the tokens over 3 of 16 held among 128): in the heaviest minibatch of a layer (160 readings: 40
+# seeds x 4 layers, 16,896 tokens) the tokens that hold more than 3 choices read 218 at the median,
+# 2,061 at the ninth decile and 5,897 at most, those that hold more than 4 read 3, 79 and 1,305 (over
+# 1,056 on that one reading); at the causal cell's sizes (8,192 tokens, 16 of 256 held, 200 readings)
+# 432 tokens at most hold more than 2 choices and 51 more than 3, of a list of 512.
+OVERFLOW_LIST_SHARE = 16
+# Where the short buffer is small the pair-wide form stays: what a row-wide gather costs follows the size of
+# the array it reads from.  From the causal cell's short buffer (12,288 rows of 2,048 in bf16, 50 MB) a
+# row for every pair (65,536) is gathered in 0.415 ms, 6 ns a row, and the compact form won 1.6 ms a step
+# of dispatch there while the program around it lost 4.5 (469.2 against 466.3 ms a step); from SDAR's
+# (50,688 rows, 208 MB) the 135,168 rows take 4.75 ms, 35 ns a row, and the compact form wins 35 ms a
+# step (my chip runs, PR 33; the compiled programs keep a pool of 112 MiB on the chip, which the first
+# source can live in and the second cannot).  So the compact form is taken where the short buffer's bytes
+# pass this bound, and below it both lengths read every pair: those programs lower as they did.
+COMPACT_OVER_BYTES = 128 * 2**20
 
 
 def short_buffer_rows(n: int, k: int, held_n: int, num_experts: int) -> int:
@@ -253,30 +333,44 @@ def short_buffer_rows(n: int, k: int, held_n: int, num_experts: int) -> int:
     return min(-(-fit // ROW_TILE) * ROW_TILE, n * min(k, held_n))
 
 
-def _experts_at(rows, dtype, m, w, w_gate, w_up, w_down, order, inv, held, group_sizes):
+def compact_slots(n: int, k: int, held_n: int, num_experts: int, row_bytes: int) -> Optional[Tuple[int, int]]:
+    """``(c, r)`` of the compact token-side sums beside the short buffer for ``n`` tokens, rows of
+    ``row_bytes``: the rows a token reads, one more than the short buffer's own rows a token rounded
+    up, and the length of the list of tokens that hold more than ``c`` choices.  None where the short
+    buffer is too small for the compact form to pay (``COMPACT_OVER_BYTES``)."""
+    rows_fit = short_buffer_rows(n, k, held_n, num_experts)
+    if rows_fit * row_bytes <= COMPACT_OVER_BYTES:
+        return None
+    return -(-rows_fit // n) + 1, -(-n // OVERFLOW_LIST_SHARE)
+
+
+def _experts_at(rows, c, dtype, m, w, w_gate, w_up, w_down, order, inv, held, group_sizes, *slots):
     """Dispatch -> SwiGLU experts -> combine over a sorted buffer of ``rows`` rows, which must hold
     every held assignment (``group_sizes.sum() <= rows``); products in ``dtype``.  ``m`` (N, d) and
-    ``w`` (N, k) float32, ``w`` 0 where the assignment is not held."""
+    ``w`` (N, k) float32, ``w`` 0 where the assignment is not held.  The token side reads every
+    (token, choice) pair where ``c`` is None, else ``c`` rows a token through ``slots``, whose list
+    must hold every token with more than ``c`` held choices."""
+    slots = None if c is None else slots
     with jax.named_scope("moe_dispatch"):
         perm = order[:rows]
-        x = _dispatch(m.astype(dtype), perm, inv, held)
+        x = _dispatch(c, m.astype(dtype), perm, inv, held, slots)
     with jax.named_scope("moe_experts"):
         # gate and up as one grouped product: the sorted rows are read once, their gradient summed once
         w_in = jnp.concatenate([w_gate, w_up], axis=-1).astype(dtype)
         gate, up = jnp.split(jax.lax.ragged_dot(x, w_in, group_sizes), 2, axis=-1)
         y_sorted = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_down.astype(dtype), group_sizes)
     with jax.named_scope("moe_dispatch"):
-        return _combine(y_sorted, w, perm, inv)
+        return _combine(c, y_sorted, w, perm, inv, slots)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _experts_tiered(tiers, dtype, fits, *data):
-    """``_experts_at`` at ``tiers[0]`` rows where ``fits``, else at ``tiers[1]``.  The conditional
+    """``_experts_at`` at ``tiers[0]`` (rows, c) where ``fits``, else at ``tiers[1]``.  The conditional
     stands in the forward pass and again in the backward rule, which computes the taken length's
     forward anew: differentiating through one ``cond`` would give both lengths one set of residuals,
     the long one's, and have the short branch write them as zeros.  What goes into either conditional
     (the layer's input and parameters) and comes out (the output, their gradients) has one size."""
-    return jax.lax.cond(fits, *(functools.partial(_experts_at, rows, dtype) for rows in tiers), *data)
+    return jax.lax.cond(fits, *(functools.partial(_experts_at, *tier, dtype) for tier in tiers), *data)
 
 
 def _experts_tiered_fwd(tiers, dtype, fits, *data):
@@ -286,11 +380,11 @@ def _experts_tiered_fwd(tiers, dtype, fits, *data):
 def _experts_tiered_bwd(tiers, dtype, res, g):
     fits, m, w, w_gate, w_up, w_down, *indices = res
 
-    def grads_at(rows, g, *diff):
-        return jax.vjp(lambda *d: _experts_at(rows, dtype, *d, *indices), *diff)[1](g)
+    def grads_at(tier, g, *diff):
+        return jax.vjp(lambda *d: _experts_at(*tier, dtype, *d, *indices), *diff)[1](g)
 
-    grads = jax.lax.cond(fits, *(functools.partial(grads_at, rows) for rows in tiers), g, m, w, w_gate, w_up, w_down)
-    return (None, *grads, None, None, None, None)
+    grads = jax.lax.cond(fits, *(functools.partial(grads_at, tier) for tier in tiers), g, m, w, w_gate, w_up, w_down)
+    return (None, *grads, *(None for _ in indices))
 
 
 _experts_tiered.defvjp(_experts_tiered_fwd, _experts_tiered_bwd)
@@ -377,13 +471,20 @@ class RoutedExperts(nn.Module):
             assigned = held.sum()
             dropped = jnp.maximum(assigned - rows, 0)
             data = (m, jnp.where(held, weights, 0.0), self.w_gate, self.w_up, self.w_down, order, inv, held, group_sizes)
-        if rows_fit < rows:
+        short, overflow, slot_c, slots = jnp.zeros((), bool), jnp.zeros((), jnp.int32), None, ()
+        if rows_fit < rows:  # both lengths, one conditional: the short one where the counted load fits it
             short = assigned <= rows_fit
-            y = _experts_tiered((rows_fit, rows), self.dtype, short, *data)
+            compact = compact_slots(n, k, held_n, c.num_experts, c.hidden_size * jnp.dtype(self.dtype).itemsize)
+            if compact is not None:  # its token side reads held choices only, and the load must fit its list too
+                slot_c, slot_r = compact
+                with jax.named_scope("moe_dispatch"):
+                    slots, overflow = _slots(held, slot_c, slot_r)
+                short &= overflow <= slot_r
+            y = _experts_tiered(((rows_fit, slot_c), (rows, None)), self.dtype, short, *data, *slots)
         else:  # the short length reaches the worst case: one length, and no conditional in the program
-            short = jnp.zeros((), bool)
-            y = _experts_at(rows, self.dtype, *data)
-        aux = {"load": group_sizes, "dropped": dropped, "short": short, "entropy": entropy, "top_i": top_i}
+            y = _experts_at(rows, None, self.dtype, *data)
+        aux = {"load": group_sizes, "dropped": dropped, "short": short, "overflow": overflow, "entropy": entropy,
+               "top_i": top_i}
         return y, aux
 
 

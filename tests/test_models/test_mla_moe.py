@@ -406,10 +406,11 @@ def test_softmax_rule_is_bit_equal_to_the_rule_written_out(held, offset):
         inv = jnp.argsort(order).reshape(n, k)
         group_sizes = (key[:, None] == jnp.arange(held)).sum(0).astype(jnp.int32)
         data = (m, jnp.where(is_held, weights, 0.0), p["w_gate"], p["w_up"], p["w_down"], order, inv, is_held, group_sizes)
-        if rows_fit < rows:
-            y = S._experts_tiered((rows_fit, rows), jnp.float32, is_held.sum() <= rows_fit, *data)
+        if rows_fit < rows:  # (a short buffer this small keeps the pair-wide token side in both branches)
+            assert S.compact_slots(n, k, held, 8, 64 * 4) is None
+            y = S._experts_tiered(((rows_fit, None), (rows, None)), jnp.float32, is_held.sum() <= rows_fit, *data)
         else:
-            y = S._experts_at(rows, jnp.float32, *data)
+            y = S._experts_at(rows, None, jnp.float32, *data)
         entropy = -(probs * jnp.log(jnp.maximum(probs, 1e-30))).sum(-1).mean()
         return y, {"load": group_sizes, "top_i": top_i, "entropy": entropy}
 
@@ -569,7 +570,7 @@ def test_episode_update_adds_the_auxiliary_loss(tmp_path, precision):
     assert float(probe["grad_leaf_norms"]["params"]["mtp"]["eh_proj"][0]) == pytest.approx(eh_proj, rel=rtol)
     assert float(metrics["MTP/loss"]) == pytest.approx(float(probe["losses"][:, 3].mean()))  # the fourth loss
     assert 3.0 < float(metrics["MTP/loss"]) < 5.5 and 0.0 <= float(metrics["MTP/top1_match"]) <= 1.0
-    assert {"MoE/dropped", "MoE/short_buffer_share", "MoE/load_l2_e3"} <= set(metrics)
+    assert {"MoE/dropped", "MoE/short_buffer_share", "MoE/overflow_tokens", "MoE/load_l2_e3"} <= set(metrics)
     flat_new, flat_old = (dict(jax.tree_util.tree_leaves_with_path(t)) for t in (got_params, start))
     for path, new in flat_new.items():
         moved = float(jnp.abs(new.astype(jnp.float32) - flat_old[path].astype(jnp.float32)).max())

@@ -298,11 +298,15 @@ def _load_biased_to(policy, params, tokens, target):
     raise AssertionError(f"no push gives layer 0's held experts {target} assignments")
 
 
+@pytest.mark.parametrize("token_side", ["pairs", "compact"])
 @pytest.mark.parametrize("over", [None, 0, 1], ids=["under", "exactly_at", "one_over"])
-def test_buffer_length_follows_the_counted_load(over):
+def test_buffer_length_follows_the_counted_load(over, token_side, monkeypatch):
     """Layer 0's held load under, exactly at and one over the short buffer's rows (layer 1's stays
     under): the short buffer is taken, taken, not taken, and on either the program is the reference's
-    layer, with nothing dropped."""
+    layer, with nothing dropped; with the short branch's token side reading every pair (a buffer this
+    small) and reading held choices only (as beside a large one)."""
+    if token_side == "compact":
+        monkeypatch.setattr(M, "COMPACT_OVER_BYTES", 0)
     policy, cfg = _policy(TIERS)
     n_eps = 6
     prompt, response, order, actions, extras = _episodes(7, n_eps)
@@ -333,17 +337,113 @@ def test_buffer_length_follows_the_counted_load(over):
     _assert_trees_close(M.reference_params(grads), rgrads, GRAD_RTOL)
 
 
+# ------------- (d'') the token side reads held choices only where the short buffer is taken
+# 512 tokens, 64 experts top-8, 8 held: the short buffer 1,536 of 4,096 rows, 4 rows a token, a list of 32 tokens
+COMPACT = dict(hidden_size=32, moe_intermediate_size=16, num_experts=64, top_k=8, experts_held=8, expert_offset=8)
+COMPACT_CASES = {  # (tokens sent to all eight held experts, the routing rule)
+    "even": (0, "softmax"), "all_held": (20, "softmax"), "over_the_list": (40, "softmax"), "sigmoid": (20, "sigmoid")}
+
+
+def _plain_routed_layer(p, m, spec):
+    """The held experts' part of the layer, one expert at a time (``sdar_moe_reference.layer``'s loop),
+    under either routing rule as the two references write them."""
+    from sheeprl_tpu.models import mla_moe_reference as RM
+
+    with jax.default_matmul_precision("highest"):
+        if spec.scoring == "softmax":
+            _, top_i, weights = R.route(m, p["router"], spec.top_k, spec.norm_topk_prob)
+        else:
+            _, top_i, weights = RM.route(m, p["router"], p["bias"], {
+                "num_experts_per_tok": spec.top_k, "norm_topk_prob": spec.norm_topk_prob, "routed_scaling_factor": spec.scale})
+        y = jnp.zeros_like(m)
+        for e in range(spec.experts_held):
+            w_e = jnp.where(top_i == spec.expert_offset + e, weights, 0.0).sum(-1)
+            y = y + w_e[:, None] * R.expert(m, p["w_gate"][e], p["w_up"][e], p["w_down"][e])
+    return y
+
+
+@pytest.mark.parametrize("case", list(COMPACT_CASES))
+def test_compact_token_side_is_the_k_wide_sum(case, monkeypatch):
+    """Where both lengths exist the short branch sums ``c`` rows a token and lists the tokens that hold
+    more: output and the gradients of the input, the router (through the routing weights) and the three
+    expert matrices equal the k-wide form's (the same layer beside a small buffer) and the plain reference's,
+    under even routing, with tokens whose eight choices are all held (the list in use), with more such
+    tokens than the list holds (the fallback is taken and nothing is dropped) and under sigmoid scoring."""
+    sent, scoring = COMPACT_CASES[case]
+    n, spec = 512, M.RoutedSpec(**COMPACT, scoring=scoring, scale=2.5 if scoring == "sigmoid" else 1.0)
+    rows_fit = M.short_buffer_rows(n, spec.top_k, spec.experts_held, spec.num_experts)
+    assert M.compact_slots(n, spec.top_k, spec.experts_held, spec.num_experts, 4 * spec.hidden_size) is None  # a small buffer
+    monkeypatch.setattr(M, "COMPACT_OVER_BYTES", 0)
+    slot_c, slot_r = M.compact_slots(n, spec.top_k, spec.experts_held, spec.num_experts, 4 * spec.hidden_size)
+    assert (rows_fit, slot_c, slot_r) == (1536, 4, 32) and rows_fit < n * spec.experts_held
+    layer = M.RoutedExperts(spec, jnp.float32)
+    m = jax.random.normal(jax.random.PRNGKey(0), (n, spec.hidden_size)).at[:, -1].set(0.0).at[:sent, -1].set(8.0)
+    params = layer.init(jax.random.PRNGKey(1), m)
+    held_ids = spec.expert_offset + jnp.arange(spec.experts_held)
+    params = {"params": {**params["params"], "router": params["params"]["router"].at[-1, held_ids].set(4.0)}}
+    weight = jax.random.normal(jax.random.PRNGKey(2), m.shape)
+
+    def run(apply):
+        fn = lambda p, m: (apply(p, m)[0] * weight).sum()  # noqa: E731
+        (y, aux), grads = jax.jit(apply)(params, m), jax.jit(jax.grad(fn, argnums=(0, 1)))(params, m)
+        return y, aux, grads
+
+    y, aux, grads = run(layer.apply)
+    held_choices = np.asarray(((aux["top_i"] >= spec.expert_offset) & (aux["top_i"] < spec.expert_offset + spec.experts_held)).sum(-1))
+    assert (held_choices[:sent] == 8).all() and int(aux["load"].sum()) == held_choices.sum() <= rows_fit
+    assert int(aux["overflow"]) == (held_choices > slot_c).sum() >= sent
+    assert bool(aux["short"]) == (int(aux["overflow"]) <= slot_r) == (case != "over_the_list")
+    assert int(aux["dropped"]) == 0
+    monkeypatch.undo()  # a buffer this small reads every pair in both branches: the k-wide form
+    wide_y, wide_aux, wide_grads = run(layer.apply)
+    assert bool(wide_aux["short"]) and int(wide_aux["overflow"]) == 0
+    plain_y, _, plain_grads = run(lambda p, m: (_plain_routed_layer(p["params"], m, spec), None))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(wide_y), atol=VALUE_ATOL)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(plain_y), atol=VALUE_ATOL)
+    assert set(grads[0]["params"]) >= {"router", "w_gate", "w_up", "w_down"}
+    _assert_trees_close(grads, wide_grads, 1e-5)
+    _assert_trees_close(grads, plain_grads, GRAD_RTOL)
+
+
+def test_compact_sums_read_no_row_beyond_the_held_ones():
+    """Rows of the sorted buffer past the counted load are whatever the grouped product left there: a
+    token with fewer held choices than the compact side reads, or with none, must not touch them (a
+    layer that held NO assignment of an episode once turned the next layer into NaN on the chip)."""
+    n, k, c, r, d = 64, 8, 3, 4, 16
+    rng = np.random.default_rng(0)
+    held = rng.random((n, k)) < 0.2
+    held[:3] = [[True] * 8, [True] * 5 + [False] * 3, [False] * 8]  # two tokens for the list, one that holds nothing
+    assigned = int(held.sum())
+    inv = np.full((n, k), 10_000)  # a pair that is not held points past the buffer
+    inv[held] = rng.permutation(assigned)
+    rows = np.full((assigned + 40, d), np.nan, np.float32)
+    rows[:assigned] = rng.normal(size=(assigned, d))
+    w = np.where(held, rng.random((n, k)), 0).astype(np.float32)
+    slots, overflow = M._slots(jnp.asarray(held), c, r)
+    got = M._held_sums(jnp.asarray(rows), jnp.asarray(w), jnp.asarray(inv), slots, c)
+    want = np.stack([sum(w[t, j] * rows[inv[t, j]] for j in range(k) if held[t, j]) + np.zeros(d, np.float32) for t in range(n)])
+    assert int(overflow) == (held.sum(1) > c).sum() <= r
+    np.testing.assert_allclose(np.asarray(got), want, atol=1e-6)
+    nothing_held = M._slots(jnp.zeros((n, k), bool), c, r)[0]  # then every row of the buffer is such a leftover
+    assert not np.asarray(M._held_sums(jnp.full((8, d), jnp.nan), jnp.zeros((n, k)), jnp.asarray(inv), nothing_held, c)).any()
+
+
 def _conditionals(lowered_text):
     return lowered_text.count("stablehlo.case") + lowered_text.count("stablehlo.if")
 
 
-def test_one_length_where_the_short_buffer_saves_nothing():
+def test_one_length_where_the_short_buffer_saves_nothing(monkeypatch):
     """With all experts held, and at the collector's cached pass over a block of tokens an env, the
     short length reaches the worst case: one length, and the lowered program holds no conditional
     (where both lengths exist it holds one a pass)."""
     assert M.short_buffer_rows(16896, 8, 16, 128) == 50688  # the published widths: one chip's share of 8
     assert M.short_buffer_rows(16896, 8, 128, 128) == 16896 * 8  # all held
     assert M.short_buffer_rows(12 * 4, 8, 16, 128) == 12 * 4 * 8  # the collector's pass over 12 envs
+    # beside the short buffer the token side reads 4 (3) rows a token and lists a sixteenth of the tokens
+    # where it is large (SDAR's update: 50,688 rows of 4 KB), and beside a small one every pair (the causal update's
+    # 12,288 rows, the collector's prefill over 12 x 512 positions: 18,432)
+    assert M.compact_slots(16896, 8, 16, 128, 4096) == (4, 1056)
+    assert M.compact_slots(8192, 8, 16, 256, 4096) is None and M.compact_slots(12 * 512, 8, 16, 128, 4096) is None
 
     def layer_text(overrides, n):  # the routed layer alone: the attention kernel's interpreter has conditionals of its own
         layer = M.RoutedExperts(M.SdarConfig.from_mapping({**TINY, **overrides}), jnp.float32)
@@ -353,6 +453,8 @@ def test_one_length_where_the_short_buffer_saves_nothing():
         return jax.jit(fwd).lower(params, m).as_text() + jax.jit(jax.grad(fwd, argnums=(0, 1))).lower(params, m).as_text()
 
     assert _conditionals(layer_text(TIERS, 528)) == 2  # one in the forward pass, one in the backward rule
+    # (one length reads every pair and builds no slots: from here on nothing may ask for them)
+    monkeypatch.setattr(M, "_slots", lambda *a: pytest.fail("the one-length path built the compact side's slots"))
     assert _conditionals(layer_text({"num_experts": 16, "experts_held": 16, "expert_offset": 0}, 528)) == 0
 
     policy, cfg = _policy(TIERS)
@@ -436,7 +538,7 @@ def test_cli_runs_two_iterations(tmp_path, precision):
     moe = [r["moe"] for r in records if "moe" in r]
     assert len(moe) == 2, records
     assert all(m["dropped"] == 0 and np.isfinite(m["router_entropy"]) and m["load_max_over_mean"] >= 1 for m in moe)
-    assert all(0.0 <= m["short_buffer_share"] <= 1.0 for m in moe)
+    assert all(0.0 <= m["short_buffer_share"] <= 1.0 and m["overflow_tokens"] == 0 for m in moe)  # one length at these sizes
     assert records[-1]["jaxenv"]["env"] == "TokenEnvJax" and records[-1]["jaxenv"]["env_steps"] == 2 * 3 * RESP
 
 
@@ -514,6 +616,7 @@ def test_episode_update_is_the_hand_loop_of_its_steps(tmp_path, precision):
         p = new
     assert float(metrics["Grads/agent"]) == pytest.approx(float(probe["grad_norm"].mean()))
     assert float(metrics["MoE/short_buffer_share"]) == float(np.asarray(probe["short"]).mean())
+    assert float(metrics["MoE/overflow_tokens"]) == float(np.asarray(probe["overflow"]).max())
     # the returned state is the last step's, not the one the call was given
     moved = float(optax.global_norm(jax.tree_util.tree_map(lambda a, b: a - b, got_params, start)))
     assert moved > 0.5 * float(jnp.sqrt(sum(jnp.square(v[-1]) for v in jax.tree_util.tree_leaves(probe["moved_leaf_norms"]))))

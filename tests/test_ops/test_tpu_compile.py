@@ -49,6 +49,9 @@ def chip():
     cc.reset_cache()
 
 
+PAIR_ROWS = "[{},2048]"  # an array of the routed layer with a 2,048-wide row a (token, choice) pair
+
+
 def _compiles_with_kernel(chip, fn, *shapes):
     args = [jax.ShapeDtypeStruct(dims, jnp.float32, sharding=chip) for dims in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
@@ -152,6 +155,7 @@ ONE_LENGTH_TEMP_BYTES = {"fwd": 1_264_930_304, "grad": 2_220_866_560}
 def test_routed_experts_compile_for_v5e(chip, held, direction):
     import re
 
+    from benchmarks.lm_update_aot import computation
     from sheeprl_tpu.models.sdar_moe import RoutedExperts, SdarConfig
 
     cfg = SdarConfig(num_hidden_layers=1, experts_held=held, vocab_size=18992, mask_id=18991)
@@ -178,9 +182,11 @@ def test_routed_experts_compile_for_v5e(chip, held, direction):
     assert len(conditionals) == 1
     branches = re.findall(r"%([\w.]+)", re.search(r"branch_computations=\{([^}]*)\}", conditionals[0]).group(1))
     assert len(branches) == 2
-    for name in branches:
-        body = text[text.index(f"\n%{name} ("):]
-        assert "tpu_custom_call" in body[:body.index("\n}\n")], name
+    bodies = [computation(text, name) for name in branches]
+    assert all("tpu_custom_call" in body for body in bodies), branches
+    # a row for every (token, choice) pair only where the worst-case buffer is taken (branch 0): the short
+    # branch's token side reads 4 rows a token (PR 33; until then 1 such array forward, 3 in the gradient)
+    assert PAIR_ROWS.format(16896 * 8) in bodies[0] and PAIR_ROWS.format(16896 * 8) not in bodies[1]
     # the short branch writes no zeros the size of the long one's residuals: no more temporaries than one length took
     assert compiled.memory_analysis().temp_size_in_bytes < 1.05 * ONE_LENGTH_TEMP_BYTES[direction]
 
@@ -210,6 +216,9 @@ def test_latent_attention_kernel_compiles_for_v5e(chip, length, direction):
 
 @pytest.mark.parametrize("direction", ["fwd", "grad"])
 def test_routed_experts_under_the_sigmoid_rule_compile_for_v5e(chip, direction):
+    import re
+
+    from benchmarks.lm_update_aot import computation
     from sheeprl_tpu.models.mla_moe import MlaMoeConfig
     from sheeprl_tpu.models.sdar_moe import RoutedExperts, short_buffer_rows
 
@@ -229,6 +238,9 @@ def test_routed_experts_under_the_sigmoid_rule_compile_for_v5e(chip, direction):
     fn = fwd if direction == "fwd" else jax.grad(lambda p, m: fwd(p, m)[0], argnums=(0, 1))
     text = jax.jit(fn).lower(params, m).compile().as_text()
     assert "tpu_custom_call" in text and text.count(" conditional(") == 1  # 12,288 rows when the load fits, else 65,536
+    # a short buffer of 50 MB: gathers from it are cheap, and both branches read every (token, choice) pair (PR 33)
+    branches = re.findall(r"%([\w.]+)", re.search(r"branch_computations=\{([^}]*)\}", text).group(1))
+    assert all(PAIR_ROWS.format(8192 * 8) in computation(text, name) for name in branches)
 
 
 # ---- one rematerialised block of each language-model policy, forward + backward, at the published widths and
