@@ -8,9 +8,12 @@ ends with ``"correct": false`` and exit code 1, and the earlier line
 - ``--control bf16_true``: the program in the nearest precision below the
   configuration's, ``fabric.precision=bf16-true`` (parameters stored in bf16):
   ``benchmarks/sdar_bf16_reading.py``, which this calls.
-- ``--control cache_shift``: the block-diffusion collector writes every
-  finished block's keys and values one block late in the cache (the write's
-  index, shifted while the rollout is traced; nothing else changes).
+- ``--control cache_shift``: the collector's cache write lands one update's
+  length late (the write's index, shifted while the rollout is traced; nothing
+  else changes): the block-diffusion collector writes every finished block's
+  keys and values one block late, the causal one every token's latent and
+  rotary key one token late (a pass then misses its own token and sees an
+  empty place at the first response position).
 
 Run as the cell itself, on the chip:
 ``python benchmarks/ppo_loop_controls.py --control cache_shift --workload sdar_ep8_loop --seed <n> --seconds 4``
@@ -23,19 +26,21 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.join(ROOT, "chipbench"))
 
-COLLECT = "sheeprl_tpu.envs.jax.collect"
+# the modules whose one use of ``dynamic_update_slice_in_dim`` is collection's cache write: the block-diffusion
+# collector's own, and the causal model's cached attention (``LatentAttention.cached``, which collection alone runs)
+CACHE_WRITERS = ("sheeprl_tpu.envs.jax.collect", "sheeprl_tpu.models.mla_moe")
 
 
 @contextlib.contextmanager
 def cache_written_one_block_late():
-    """``jax.lax.dynamic_update_slice_in_dim`` as the collector's module calls
-    it (its one use there is the cache write) lands one update's length later."""
+    """``jax.lax.dynamic_update_slice_in_dim`` as ``CACHE_WRITERS`` call it
+    lands one update's length later."""
     import jax
 
     inner = jax.lax.dynamic_update_slice_in_dim
 
     def shifted(operand, update, start_index, axis):
-        if sys._getframe(1).f_globals.get("__name__") == COLLECT:
+        if sys._getframe(1).f_globals.get("__name__") in CACHE_WRITERS:
             start_index = start_index + update.shape[axis]
         return inner(operand, update, start_index, axis)
 

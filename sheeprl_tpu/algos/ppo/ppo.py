@@ -761,6 +761,11 @@ def main(runtime, cfg: Dict[str, Any]):
                 jnp.float32(current_ent),
                 jnp.float32(current_lr),
             )
+        # the host's wait for the update, under its own name: ``publish`` keeps its barrier and finds the
+        # parameters ready.  Where a rollout's events were not fetched (``metric.fetch_every`` > 1) the
+        # rollout has not been waited for either, and its wait lands here too
+        with timer("Time/update_wait"), trace_scope("block_until_ready"):
+            jax.block_until_ready(params)
         with timer("Time/publish"):
             pipeline.publish(iter_num, params)
             rolled = health.tick()

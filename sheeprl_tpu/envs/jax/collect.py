@@ -140,8 +140,9 @@ class _FusedCollectorBase:
         """Fetch + emit on-device episode events at the fetch cadence.  The
         fetch is where the host first waits for the rollout
         (``Time/collect_wait``; where the event gate is closed nothing is
-        fetched here and the loop waits later, in its own spans); the host loop
-        over the episodes that ended is ``Time/collect_events``."""
+        fetched here and the loop waits later, in ``ppo.main``'s
+        ``Time/update_wait``); the host loop over the episodes that ended is
+        ``Time/collect_events``."""
         if not self._log_events or self.aggregator is None:
             return
         if not self._event_gate():
@@ -157,6 +158,7 @@ class _FusedCollectorBase:
             ep_len = np.asarray(events["ep_length"])
         per_step = self.total_envs  # policy steps per scan step (global)
         with timer("Time/collect_events"):
+            self._count_fetched(events)
             for t, i in zip(*np.nonzero(done)):
                 self._n_episodes += 1
                 ep_rew = float(ep_ret[t, i])
@@ -168,6 +170,10 @@ class _FusedCollectorBase:
                     f"Rank-0: policy_step={step_start + (int(t) + 1) * per_step}, "
                     f"reward_env_{int(i)}={ep_rew}"
                 )
+
+    def _count_fetched(self, events: Dict[str, Any]) -> None:
+        """What a collector counts on the device rides ``events`` and is fetched
+        here, after the wait for the rollout has ended (nothing by default)."""
 
     def stats(self) -> Dict[str, Any]:
         """Telemetry provider (``jaxenv`` key in telemetry.jsonl); the counts
@@ -371,6 +377,12 @@ class FusedRecurrentCollector(_FusedCollectorBase):
         return payload
 
 
+def _reached(load: jax.Array) -> jax.Array:
+    """The distinct held experts one cached pass's rows reached, summed over its routed layers, from
+    their ``load`` (layers, held experts): int32."""
+    return (load > 0).sum().astype(jnp.int32)
+
+
 class _FusedEpisodeCollector(_FusedCollectorBase):
     """What the language-model policies' collectors share (``algos/ppo/lm_policy.py``
     names them): one rollout is one whole episode per env over
@@ -386,7 +398,20 @@ class _FusedEpisodeCollector(_FusedCollectorBase):
     the token's choice, the recorded log-probability and value) and
     ``collect_env`` (``vector_step``).  ``stats()`` counts what the rollouts
     did, exactly and from shapes alone: forward ``passes`` of the model and the
-    ``positions`` those ran over (what a trace's time per pass is taken over)."""
+    ``positions`` those ran over (what a trace's time per pass is taken over).
+
+    What shapes cannot say is counted on the device, from the routed layers'
+    own ``load`` (rows per held expert, which the dispatch needs anyway), over
+    every cached pass (not the prefill) and every routed layer of the trunk:
+    ``experts_reached``, the distinct held experts a pass's rows reached, i.e.
+    the expert weights the pass had to read.  A rollout returns the sum beside
+    its episode events as ``events["reach"]``; it is fetched with the events
+    and dropped with them (``metric.fetch_every``): the total is over the
+    ``event_fetches`` rollouts whose events were fetched."""
+
+    def __init__(self, **kwargs):
+        super().__init__(**kwargs)
+        self._experts_reached = 0
 
     def _initial_carry(self, base):
         return vector_reset(self.jax_env, base, self.total_envs)
@@ -395,8 +420,12 @@ class _FusedEpisodeCollector(_FusedCollectorBase):
         """``passes`` and ``positions`` of ONE rollout."""
         raise NotImplementedError
 
+    def _count_fetched(self, events: Dict[str, Any]) -> None:
+        self._experts_reached += int(np.asarray(events["reach"]))
+
     def stats(self) -> Dict[str, Any]:
-        return {**super().stats(), **{k: v * self._n_rollouts for k, v in self._rollout_work().items()}}
+        return {**super().stats(), **{k: v * self._n_rollouts for k, v in self._rollout_work().items()},
+                "experts_reached": self._experts_reached}
 
     def collect(self, iter_num: int, inline: bool, key_fn) -> RolloutPayload:
         payload = RolloutPayload(iter_num)
@@ -444,7 +473,7 @@ class FusedCausalCollector(_FusedEpisodeCollector):
             ]
 
         def step_fn(carry, xs):
-            vstate, cache, last = carry
+            vstate, cache, last, reach = carry
             t, step_key = xs
             with jax.named_scope("collect_score"):
                 logp_all, values = model.apply(params, last, method=MlaMoE.logits)
@@ -457,7 +486,7 @@ class FusedCausalCollector(_FusedEpisodeCollector):
             # the appended token's pass: its latents join the cache, its hidden state scores the next step
             # (after the last token the env has reset, and what is written is never read)
             with jax.named_scope("collect_decode"):
-                u, cache = model.apply(params, x[:, None].astype(jnp.int32), cache, p_len + t, method=MlaMoE.step)
+                u, aux, cache = model.apply(params, x[:, None].astype(jnp.int32), cache, p_len + t, method=MlaMoE.step)
             rec = {
                 "actions": action,
                 "logprobs": logprob,
@@ -466,11 +495,12 @@ class FusedCausalCollector(_FusedEpisodeCollector):
                 "dones": out["done"][:, None].astype(jnp.float32),
                 "ev": {"done": out["done"], "ep_return": out["ep_return"], "ep_length": out["ep_length"]},
             }
-            return (vstate, cache, u[:, 0]), rec
+            return (vstate, cache, u[:, 0], reach + _reached(aux["load"])), rec
 
         keys = jax.random.split(jnp.asarray(key), r_len)
-        (vstate, _, _), recs = jax.lax.scan(step_fn, (vstate, cache, u[:, -1]), (jnp.arange(r_len), keys))
-        events = recs.pop("ev")
+        carry = (vstate, cache, u[:, -1], jnp.int32(0))
+        (vstate, _, _, reach), recs = jax.lax.scan(step_fn, carry, (jnp.arange(r_len), keys))
+        events = {**recs.pop("ev"), "reach": reach}
         recs["prompt"] = prompt[None]
         return vstate, recs, events
 
@@ -519,7 +549,7 @@ class FusedDiffusionCollector(_FusedEpisodeCollector):
             return jax.lax.dynamic_slice_in_dim(vstate["obs"]["tokens"], length, block, axis=1)
 
         def block_fn(carry, xs):
-            vstate, cache = carry
+            vstate, cache, reach = carry
             b, keys = xs
             length = p_len + b * block
             pos = length + jnp.arange(block)
@@ -527,7 +557,8 @@ class FusedDiffusionCollector(_FusedEpisodeCollector):
             for j in range(steps):
                 with jax.named_scope("collect_denoise"):
                     tokens = current(vstate, length)
-                    hidden, _, _ = model.apply(params, tokens, pos, cache, length, method=SdarMoE.block)
+                    hidden, aux, _ = model.apply(params, tokens, pos, cache, length, method=SdarMoE.block)
+                    reach = reach + _reached(aux["load"])
                 with jax.named_scope("collect_score"):
                     logp_all, values = model.apply(params, hidden, method=SdarMoE.score)
                 with jax.named_scope("collect_sample"):
@@ -551,16 +582,18 @@ class FusedDiffusionCollector(_FusedEpisodeCollector):
             # the finished block's keys and values join the cache (after the last
             # block the env has reset, and what is written is never read)
             with jax.named_scope("collect_commit"):
-                _, _, kvs = model.apply(params, current(vstate, length), pos, cache, length, method=SdarMoE.block)
+                aux, kvs = model.apply(params, current(vstate, length), pos, cache, length, method=SdarMoE.commit)
+                reach = reach + _reached(aux["load"])
                 cache = [
                     tuple(jax.lax.dynamic_update_slice_in_dim(c, x, length, axis=1) for c, x in zip(kv_cache, kv))
                     for kv_cache, kv in zip(cache, kvs)
                 ]
-            return (vstate, cache), jax.tree_util.tree_map(lambda *x: jnp.stack(x), *recs)
+            return (vstate, cache, reach), jax.tree_util.tree_map(lambda *x: jnp.stack(x), *recs)
 
         keys = jax.random.split(jnp.asarray(key), n_blocks * steps).reshape(n_blocks, steps, -1)
-        (vstate, _), recs = jax.lax.scan(block_fn, (vstate, cache), (jnp.arange(n_blocks), keys))
+        carry = (vstate, cache, jnp.int32(0))
+        (vstate, _, reach), recs = jax.lax.scan(block_fn, carry, (jnp.arange(n_blocks), keys))
         recs = jax.tree_util.tree_map(lambda x: x.reshape(n_blocks * steps, *x.shape[2:]), recs)
-        events = recs.pop("ev")
+        events = {**recs.pop("ev"), "reach": reach}
         recs["prompt"] = prompt[None]
         return vstate, recs, events

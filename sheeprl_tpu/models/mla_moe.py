@@ -319,14 +319,17 @@ class MlaMoE(nn.Module):
     def step(self, tokens: jax.Array, cache, length: jax.Array):
         """One token an env (B, 1) at position ``length`` against the latent cache (per block
         ``(ckv, kr)``, (B, S, kv_lora_rank) and (B, S, rope)): the cached pass collection makes.
-        Returns the final-norm hidden state (B, 1, hidden) and the caches with the token written."""
+        Returns the final-norm hidden state (B, 1, hidden), the routed blocks' counters stacked over
+        them (the trunk's: the MTP module does not run here) and the caches with the token written."""
         h = self.embed[tokens]
         pos = length + jnp.arange(1)
-        new_cache = []
+        auxes, new_cache = [], []
         for layer, (ckv_cache, kr_cache) in zip(self.layers, cache):
-            h, _, caches = layer.cached(h, pos, ckv_cache, kr_cache, length)
+            h, aux, caches = layer.cached(h, pos, ckv_cache, kr_cache, length)
             new_cache.append(caches)
-        return rms_norm(h, self.final_norm, self.cfg.rms_norm_eps), new_cache
+            if aux is not None:
+                auxes.append(aux)
+        return rms_norm(h, self.final_norm, self.cfg.rms_norm_eps), _stack(auxes), new_cache
 
     def _head_terms(self, at: jax.Array, taken: jax.Array):
         """(log-probability of ``taken``, entropy, the top-1 id) at hidden states ``at`` (..., hidden),

@@ -598,7 +598,8 @@ class SdarMoE(nn.Module):
 
     def _finish(self, h, auxes):
         aux = {k: jnp.stack([a[k] for a in auxes]) for k in auxes[0]}
-        return rms_norm(h, self.final_norm, self.cfg.rms_norm_eps), aux
+        with jax.named_scope("sdar_head"):
+            return rms_norm(h, self.final_norm, self.cfg.rms_norm_eps), aux
 
     def hidden(self, tokens: jax.Array, layout: EpisodeLayout, with_kv: bool = False):
         """Final-norm hidden states (B, N, hidden) of packed episodes
@@ -627,6 +628,15 @@ class SdarMoE(nn.Module):
             auxes.append(aux)
             kvs.append(kv)
         return (*self._finish(h, auxes), kvs)
+
+    def commit(self, tokens: jax.Array, pos: jax.Array, cache, length):
+        """The pass that writes a finished block, for what it is run for: the
+        block's keys and values per layer, and the counters of the routed
+        layers they depend on.  That is every layer but the last, whose routed
+        part feeds the hidden states alone: a caller that takes no hidden
+        states does not run it, and what never ran is not counted."""
+        _, aux, kvs = self.block(tokens, pos, cache, length)
+        return {k: v[:-1] for k, v in aux.items()}, kvs
 
     def score(self, at: jax.Array) -> Tuple[jax.Array, jax.Array]:
         """Hidden states at action positions (..., hidden) -> the policy's

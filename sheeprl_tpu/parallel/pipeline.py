@@ -439,7 +439,11 @@ class PipelinedCollector:
         payload that published ``version`` is dropped — without the
         barrier, freeing them mid-update lets the allocator hand their
         memory to the next rollout's pack, scribbling the tensors the
-        in-flight update is reading."""
+        in-flight update is reading.
+
+        ``ppo.main`` waits for the update before it calls this, under a span
+        that says so (``Time/update_wait``): the barrier here then returns at
+        once and ``Time/publish`` holds the hand-over alone."""
         if self.overlap:
             params = _copy_tree_for_publish(params)
         else:

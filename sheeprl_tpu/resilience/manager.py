@@ -35,6 +35,7 @@ from typing import Any, Callable, Dict, Optional
 from sheeprl_tpu.resilience.async_writer import AsyncCheckpointWriter
 from sheeprl_tpu.resilience.preemption import PreemptionHandler
 from sheeprl_tpu.utils.callback import CheckpointCallback
+from sheeprl_tpu.utils.timer import timer
 
 
 class NonFiniteCheckpointError(RuntimeError):
@@ -168,7 +169,9 @@ class CheckpointManager:
 
         path = self.ckpt_path(policy_step)
         t0 = time.perf_counter()
-        with flight.span("ckpt_write", step=policy_step, async_save=self.async_save):
+        # the loop's stall for a save (the snapshot's device-to-host fetch, then the hand-over or the write) as a
+        # root span of the iteration that saves, for every loop: ``timers_s`` and, under a profiler, the trace
+        with timer("Time/checkpoint"), flight.span("ckpt_write", step=policy_step, async_save=self.async_save):
             host_state = self.cb.snapshot(state_fn())
             if not self.allow_nonfinite and "agent" in host_state:
                 bad = _nonfinite_leaves(host_state["agent"])

@@ -1,7 +1,9 @@
-"""The language-model policies' fused collectors (ISSUE 32), both kinds at
-tiny widths on the CPU: the counters are exact, the episode events' fetch is a
-span of its own and no fetch was added, the rollout program names its phases
-and the names are metadata only, and a seeded rollout is the bits the parent
+"""The language-model policies' fused collectors (ISSUE 32, 34), both kinds at
+tiny widths on the CPU: the counters are exact (those from shapes, and the one
+the rollout counts on the device against a count by hand over a full pass's
+routing), the episode events' fetch is a span of its own and one fetch a
+rollout carries the device's count, the rollout program names its phases and
+the names are metadata only, and a seeded rollout is the bits the parent
 commit gave (``lm_golden.json``)."""
 
 import contextlib
@@ -64,10 +66,13 @@ def test_a_rollout_behind_the_newest_weights_says_so(kind, tmp_path):
         assert collector.stats()["params_age"] == i
 
 
-def test_no_fetch_was_added(kind, tmp_path, monkeypatch):
-    """A rollout whose events are due costs the three fetches it always did,
-    one that is skipped (``metric.fetch_every=2``) none: the loop then waits
-    later, in its own spans."""
+def test_one_fetch_was_added(kind, tmp_path, monkeypatch):
+    """A rollout whose events are due costs the three fetches it always did and
+    ONE more since ISSUE 34, the count the rollout made on the device
+    (``experts_reached``: one scalar, fetched after the wait for the rollout
+    has ended); one that is skipped (``metric.fetch_every=2``) none: the loop
+    then waits later, in its own spans, and that rollout's count is dropped
+    with its events."""
     fetched = []
     counting = types.SimpleNamespace(**{k: getattr(np, k) for k in ("nonzero",)},
                                      asarray=lambda x: (fetched.append(type(x).__name__), np.asarray(x))[1])
@@ -81,8 +86,56 @@ def test_no_fetch_was_added(kind, tmp_path, monkeypatch):
         before = len(fetched)
         collector.collect(i + 1, True, runtime.next_key)
         per_rollout.append(len(fetched) - before)
-    assert per_rollout == [3, 0, 3, 0]
+    assert per_rollout == [4, 0, 4, 0]
     assert collector.stats()["event_fetches"] == 2
+
+
+def _cached_passes(kind, policy):
+    """[(the packed positions of an episode that one cached pass ran, how many of the routed layers' routed
+    parts ran in it)] of one rollout, by hand.  Block diffusion: copy ``j`` of block ``b`` is its denoising pass
+    ``j``, the block's clean positions are the pass that commits it (``SdarMoE.commit``: keys and values alone,
+    which the last layer's routed part does not feed).  Causal: response token ``t`` is cached pass ``t``, every
+    layer runs."""
+    if kind == "mla_moe":
+        return [(np.array([P + t]), None) for t in range(RESP)]
+    steps, out = policy.cfg.denoise_steps, []
+    for b in range(RESP // BLOCK):
+        out += [(P + RESP + (b * steps + j) * BLOCK + np.arange(BLOCK), None) for j in range(steps)]
+        out.append((P + b * BLOCK + np.arange(BLOCK), -1))
+    return out
+
+
+def test_the_device_counts_equal_a_count_by_hand(kind, tmp_path):
+    """``experts_reached`` of a rollout against numpy over the routing that a FULL pass gives the recorded
+    rollout (float32 at the tiny widths: the cached passes choose as the full pass does)."""
+    from sheeprl_tpu.models.sdar_moe import RoutedSpec
+
+    collector, policy, params, runtime, _ = lm_tiny.build_collector(kind, str(tmp_path), aggregator=_aggregator())
+    data = collector.collect(1, True, runtime.next_key).data
+    *_, aux = policy.evaluate_episodes(params, data["prompt"][0], np.asarray(data["actions"]).swapaxes(0, 1))
+    spec = RoutedSpec.of(policy.cfg)
+    trunk = policy.cfg.num_hidden_layers - getattr(policy.cfg, "first_k_dense_replace", 0)  # (the MTP block's routing is last)
+    top_i = np.asarray(aux["top_i"])[:trunk].reshape(trunk, ENVS, -1, spec.top_k) - spec.expert_offset
+    if kind == "sdar_moe":
+        # the pass that commits the LAST block runs after the env's auto-reset, over the new episode's tokens in
+        # that place (what it writes is never read): its routing is a clean pass's over the episode so changed
+        from sheeprl_tpu.models.sdar_moe import EpisodeLayout, SdarMoE
+
+        clean = np.array(policy.layout.pack(data["prompt"][0], np.asarray(data["actions"]).swapaxes(0, 1), policy.cfg.mask_id)[0])
+        clean = clean[:, : P + RESP]
+        clean[:, -BLOCK:] = np.asarray(collector._carry["obs"]["tokens"])[:, P + RESP - BLOCK: P + RESP]
+        layout = EpisodeLayout(P + RESP, 0, BLOCK, policy.cfg.denoise_steps)
+        _, last = policy.model.apply(params, clean, layout, method=SdarMoE.hidden)
+        last = np.asarray(last["top_i"]).reshape(trunk, ENVS, -1, spec.top_k) - spec.expert_offset
+        top_i[:, :, P + RESP - BLOCK: P + RESP] = last[:, :, -BLOCK:]
+    reached = 0
+    for positions, layers in _cached_passes(kind, policy):
+        local = top_i[:layers, :, positions]
+        ok = (local >= 0) & (local < spec.experts_held)
+        reached += sum(np.unique(layer[keep]).size for layer, keep in zip(local, ok))
+    assert collector.stats()["experts_reached"] == reached > 0
+    collector.collect(2, True, runtime.next_key)  # cumulative, like ``passes``
+    assert collector.stats()["experts_reached"] > reached
 
 
 def test_a_seeded_rollout_is_bit_equal_to_the_parents(kind, tmp_path):

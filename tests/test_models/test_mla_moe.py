@@ -278,7 +278,7 @@ def test_cached_absorbed_decoding_equals_the_full_pass(seed):
     step = jax.jit(lambda tok, cache, length: model.apply(params, tok, cache, length, method=M.MlaMoE.step))
     rows = [model.apply(params, u[:, -1], method=M.MlaMoE.logits)[0]]
     for i in range(P, P + RESP):
-        ui, cache = step(tokens[:, i:i + 1], cache, jnp.int32(i))
+        ui, _, cache = step(tokens[:, i:i + 1], cache, jnp.int32(i))
         rows.append(model.apply(params, ui[:, 0], method=M.MlaMoE.logits)[0])
     got = jnp.stack(rows, 1)  # positions P - 1 .. P + RESP - 1
     np.testing.assert_allclose(np.asarray(got), np.asarray(full[:, P - 1:]), atol=VALUE_ATOL)
