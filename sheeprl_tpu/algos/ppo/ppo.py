@@ -40,6 +40,7 @@ from sheeprl_tpu.config import instantiate
 from sheeprl_tpu.config.compose import _locate
 from sheeprl_tpu.data.buffers import ReplayBuffer
 from sheeprl_tpu.obs import setup_observability, trace_scope
+from sheeprl_tpu.ops.block_sparse_attention import take_engaged
 from sheeprl_tpu.parallel.pipeline import OnPolicyCollector, PipelinedCollector, detach_copy, resolve_overlap_setting
 from sheeprl_tpu.resilience import CheckpointManager
 from sheeprl_tpu.resilience.sentinel import guard_update, restore_like
@@ -739,7 +740,8 @@ def main(runtime, cfg: Dict[str, Any]):
         adopt_params_fn=adopt_params_fn,
     )
     metric_fetch_gate = MetricFetchGate(cfg.metric.get("fetch_every", 1))
-    # the language-model policies' counters ride the telemetry record, a section a prefix
+    # the language-model policies' counters ride the telemetry record, a section a prefix; how their blocked
+    # attention's kernels engaged (tiles, fused or split backward) rides the first record after they were built
     counter_sections = {"MoE/": "moe", "MTP/": "mtp"}
     policy_counters: Dict[str, Dict[str, float]] = {}
 
@@ -795,7 +797,11 @@ def main(runtime, cfg: Dict[str, Any]):
                 logger.log_metrics({"Info/learning_rate": current_lr}, policy_step)
                 logger.log_metrics({"Info/clip_coef": current_clip, "Info/ent_coef": current_ent}, policy_step)
                 if policy_step - last_log >= cfg.metric.log_every or iter_num == total_iters:
-                    observability.on_log(policy_step, train_step, extra=dict(policy_counters) or None)
+                    sections: Dict[str, Any] = dict(policy_counters)
+                    attention_kernels = take_engaged()
+                    if attention_kernels:
+                        sections["attention"] = attention_kernels
+                    observability.on_log(policy_step, train_step, extra=sections or None)
                     if aggregator and not aggregator.disabled:
                         logger.log_metrics(aggregator.compute(), policy_step)
                         aggregator.reset()

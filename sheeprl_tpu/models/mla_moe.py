@@ -40,7 +40,7 @@ the attention kernel's output (``sdar_moe.remat_block``).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, List, Mapping, Tuple
+from typing import Any, Dict, List, Mapping, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -80,7 +80,9 @@ class MlaMoeConfig:
     vocab_size: int = 129280
     experts_held: int = 256
     expert_offset: int = 0
-    attention_block: int = 512  # tile of the causal attention (the TPU's block-sparse flash kernel)
+    # tiles of the causal attention (the TPU's block-sparse flash kernel): None, by the call's shapes
+    # (``ops.block_sparse_attention._tiles``); an integer makes every tile of every kernel that wide
+    attention_block: Optional[int] = None
     attention_interpret: bool = False  # run that kernel through Pallas' interpreter: off a TPU, for the tests
 
     @classmethod

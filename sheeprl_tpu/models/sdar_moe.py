@@ -79,7 +79,9 @@ class SdarConfig:
     block_length: int = 4
     denoise_steps: int = 4
     mask_id: int = 151935
-    attention_block: int = 512  # tile of the masked attention (the TPU's block-sparse flash kernel)
+    # tiles of the masked attention (the TPU's block-sparse flash kernel): None, by the call's shapes and mask
+    # (``ops.block_sparse_attention._tiles``); an integer makes every tile of every kernel that wide
+    attention_block: Optional[int] = None
     attention_interpret: bool = False  # run that kernel through Pallas' interpreter: off a TPU, for the tests
 
     @classmethod

@@ -478,11 +478,18 @@ def test_reference_imports_nothing_of_the_repository():
 @pytest.mark.parametrize("precision", ["32-true", "bf16-mixed"])
 def test_cli_runs_two_iterations(tmp_path, precision):
     from sheeprl_tpu.cli import run
+    from sheeprl_tpu.ops import block_sparse_attention as op
 
+    op._splash_kernel.cache_clear()  # an earlier test's kernels would be this run's: a hit of the cache says nothing
+    op.take_engaged()
     run(_lm_overrides(tmp_path, precision))
     records = [json.loads(line) for path in glob.glob(f"{tmp_path}/joyai/*/telemetry.jsonl") for line in open(path)]
     moe, mtp = [r["moe"] for r in records if "moe" in r], [r["mtp"] for r in records if "mtp" in r]
     assert len(moe) == len(mtp) == 2, records
+    # how the blocked attention's kernels engaged rides the first record, once: the prefill's and the update's
+    (attention,) = [r["attention"] for r in records if "attention" in r]
+    assert "attention" in records[0] and sorted(k["s_q"] for k in attention) == [P, P + RESP]
+    assert all(k["backward"] == "split" and k["block_q"] == k["block_kv_dkv"] == k["block_kv_dq"] == 128 for k in attention)
     assert all(m["dropped"] == 0 and np.isfinite(m["router_entropy"]) and m["load_max_over_mean"] >= 1 for m in moe)
     assert all(np.isfinite(m["loss"]) and 3.0 < m["loss"] < 5.5 and 0.0 <= m["top1_match"] <= 1.0 for m in mtp)  # ln 64 = 4.16
     assert "load_l2_e3" in moe[0] and "load_l3_e0" not in moe[0]  # two routed blocks and the MTP module's

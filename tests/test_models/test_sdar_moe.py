@@ -205,14 +205,14 @@ def test_blocked_masked_attention_matches_dense(sizes):
 def test_blocked_attention_skips_unseen_tiles():
     """The kernel's block tables hold only tiles in which some query sees some
     key: under a third of all at an episode's shape."""
-    from sheeprl_tpu.ops.block_sparse_attention import _splash_kernel
+    from sheeprl_tpu.ops.block_sparse_attention import _as_bytes, _one_tile, _splash_kernel
 
     episode = M.EpisodeLayout(256, 512, BLOCK, BLOCK)  # 768 clean + 2,048 noised positions, tiles of 128
     dense = episode.mask.dense()
     n = dense.shape[0]
     n_tiles = n // 128
     needed = sum(dense[a * 128:(a + 1) * 128, b * 128:(b + 1) * 128].any() for a in range(n_tiles) for b in range(n_tiles))
-    kernel = _splash_kernel(tuple(np.asarray(a).astype(np.int64).tobytes() for a in episode.mask), (n, n), 1, 128, True)
+    kernel = _splash_kernel(_as_bytes(episode.mask), 1, _one_tile(128), False, True)
     visited = np.asarray(kernel.fwd_mask_info.block_mask) != 0  # (heads, query tiles, steps): the grid a query tile walks
     assert int(visited.sum()) == needed < n_tiles * n_tiles // 3
     assert visited.shape[-1] < n_tiles // 2  # no query tile walks more than its own keys' tiles

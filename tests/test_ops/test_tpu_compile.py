@@ -214,6 +214,29 @@ def test_latent_attention_kernel_compiles_for_v5e(chip, length, direction):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
+@pytest.mark.parametrize("shape", ["joyai_update", "joyai_loop_update", "joyai_prefill", "sdar_update", "sdar_prefill"])
+def test_tiles_chosen_by_shape_compile_for_v5e(chip, shape):
+    """What ``_tiles`` returns for the benchmark cells' shapes (``block_size=None``: the models' default) is
+    within the compiler's 16 MiB of scoped VMEM, forward and backward (what it returns:
+    ``test_block_sparse_attention.py::test_chooser_gives_the_table``)."""
+    from benchmarks.attention_tile_readings import SHAPES, chooser_arguments
+    from sheeprl_tpu.ops import block_sparse_attention as op
+
+    batch, length, h_q, h_kv, d, d_v, _, backward, _ = SHAPES[shape]
+    mask = chooser_arguments(shape)[-1]
+    sizes, _ = op._tiles(*chooser_arguments(shape))
+
+    def fwd(q, k, v):
+        return op.block_sparse_flash_attention(q, k, v, mask).astype(jnp.float32).sum()
+
+    q, k, v = (jax.ShapeDtypeStruct((batch, length, heads, width), jnp.bfloat16, sharding=chip)
+               for heads, width in ((h_q, d), (h_kv, d), (h_kv, d_v)))
+    text = jax.jit(jax.grad(fwd, argnums=(0, 1, 2)) if backward else fwd).lower(q, k, v).compile().as_text()
+    assert "tpu_custom_call" in text
+    if backward:  # the fused backward has no dQ kernel
+        assert ("splash_mqa_dq" in text) == (not sizes.use_fused_bwd_kernel) and "splash_mqa_dkv" in text
+
+
 @pytest.mark.parametrize("direction", ["fwd", "grad"])
 def test_routed_experts_under_the_sigmoid_rule_compile_for_v5e(chip, direction):
     import re
@@ -245,8 +268,11 @@ def test_routed_experts_under_the_sigmoid_rule_compile_for_v5e(chip, direction):
 
 # ---- one rematerialised block of each language-model policy, forward + backward, at the published widths and
 # the cells' sizes.  Temporaries of the block that keeps the kernel's output and log-sum-exp (AOT here, PR 31;
-# rematerialised whole, with the forward kernel called twice: 1,784,745,984 and 3,365,251,072)
-REMAT_BLOCK_TEMP_BYTES = {"mla_moe": 1_932_135_936, "sdar_moe": 3_628_106_240}
+# rematerialised whole, with the forward kernel called twice: 1,784,745,984 and 3,365,251,072; since PR 35 the
+# causal block's backward is fused and its four bf16 parts of ``dq`` live no longer than the scratch the dQ
+# kernel's call took: 1,932,135,936 -> 1,930,781,184; the block-diffusion layer compiles to 3,367,830,016 since
+# PR 33's compact token side, on the parent too, where 3,628,106,240 stood)
+REMAT_BLOCK_TEMP_BYTES = {"mla_moe": 1_930_781_184, "sdar_moe": 3_367_830_016}
 
 
 @pytest.mark.parametrize("model", ["mla_moe", "sdar_moe"])
@@ -278,6 +304,8 @@ def test_rematerialised_block_calls_the_forward_kernel_once_on_v5e(chip, model):
     # the value too: a gradient alone needs no forward pass but the rematerialised one
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(params, h).compile()
     text = compiled.as_text()
-    for kernel in ("splash_mqa_fwd", "splash_mqa_dkv", "splash_mqa_dq"):
-        assert kernel_calls(text, kernel) == 1, kernel
+    # the causal block's backward is fused at 8,192 positions (``_tiles``: dK/dV writes ``dq`` in four parts, no dQ
+    # kernel); the block-diffusion layer keeps the split form at tiles of 512
+    for kernel, calls in (("splash_mqa_fwd", 1), ("splash_mqa_dkv", 1), ("splash_mqa_dq", 0 if model == "mla_moe" else 1)):
+        assert kernel_calls(text, kernel) == calls, kernel
     assert compiled.memory_analysis().temp_size_in_bytes <= REMAT_BLOCK_TEMP_BYTES[model] * 1.02
