@@ -5,7 +5,9 @@ without a single full-size array: what the program would hold on the chip
 (``compiled.memory_analysis()``), a hash of its lowered text, to hold two
 checkouts' programs against each other, and how often it calls each of the
 attention's kernels (``attention_forward_kernels``: once a block where the
-rematerialised blocks keep the kernel's output, twice where they do not).
+rematerialised blocks keep the kernel's output, twice where they do not) and
+the q/k norm's (``qk_norm_fwd_kernels``: q and k of every block, twice, the
+forward and the rematerialised one; ``qk_norm_bwd_kernels`` once).
 Compiled, it also counts the routed layers' arrays with a hidden-wide row for
 every (token, choice) pair, by the conditionals' branches
 (``pair_arrays_short_branches``: none since PR 33, whose short branch reads a
@@ -190,6 +192,8 @@ def main(argv=None) -> int:
                    output_bytes=m.output_size_in_bytes, alias_bytes=m.alias_size_in_bytes, temp_bytes=m.temp_size_in_bytes,
                    generated_code_bytes=m.generated_code_size_in_bytes,
                    arguments_plus_temporaries_gb=(m.argument_size_in_bytes + m.temp_size_in_bytes) / 1e9,
+                   # the compiler's own peak of what is live at once; the temporaries above are summed whole
+                   peak_gb=m.peak_memory_in_bytes / 1e9,
                    conditionals=program.count(" conditional("))
         spec = policy.cfg.routed_spec
         positions = policy.layout.length if hasattr(policy, "layout") else int(cfg.env.wrapper.prompt_len) + int(cfg.env.wrapper.response_len)
@@ -197,6 +201,7 @@ def main(argv=None) -> int:
         out.update(pair_arrays_long_branches=pairs["long"], pair_arrays_short_branches=pairs["short"])
     out.update({f"attention_{name}_kernels": kernel_calls(program, "splash_mqa_" + kernel)
                 for name, kernel in (("forward", "fwd"), ("dkv", "dkv"), ("dq", "dq"))})
+    out.update({f"qk_norm_{name}_kernels": kernel_calls(program, "qk_norm_rope_" + name) for name in ("fwd", "bwd")})
     print(json.dumps(out))
     return 0
 

@@ -40,7 +40,7 @@ from sheeprl_tpu.config import instantiate
 from sheeprl_tpu.config.compose import _locate
 from sheeprl_tpu.data.buffers import ReplayBuffer
 from sheeprl_tpu.obs import setup_observability, trace_scope
-from sheeprl_tpu.ops.block_sparse_attention import take_engaged
+from sheeprl_tpu.ops import block_sparse_attention, qk_norm_rope
 from sheeprl_tpu.parallel.pipeline import OnPolicyCollector, PipelinedCollector, detach_copy, resolve_overlap_setting
 from sheeprl_tpu.resilience import CheckpointManager
 from sheeprl_tpu.resilience.sentinel import guard_update, restore_like
@@ -798,9 +798,11 @@ def main(runtime, cfg: Dict[str, Any]):
                 logger.log_metrics({"Info/clip_coef": current_clip, "Info/ent_coef": current_ent}, policy_step)
                 if policy_step - last_log >= cfg.metric.log_every or iter_num == total_iters:
                     sections: Dict[str, Any] = dict(policy_counters)
-                    attention_kernels = take_engaged()
-                    if attention_kernels:
-                        sections["attention"] = attention_kernels
+                    # how the blocked attention's kernels and the q/k norms were built, once a shape
+                    for section, op in (("attention", block_sparse_attention), ("qk_norm", qk_norm_rope)):
+                        engaged = op.take_engaged()
+                        if engaged:
+                            sections[section] = engaged
                     observability.on_log(policy_step, train_step, extra=sections or None)
                     if aggregator and not aggregator.disabled:
                         logger.log_metrics(aggregator.compute(), policy_step)

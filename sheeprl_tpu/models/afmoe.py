@@ -13,7 +13,8 @@ What is particular here:
   ``SegmentMask.causal(n, n, window=W)`` (the library's computed ``LocalMask``),
   a full layer under the causal mask (its ``CausalMask``).  Sliding layers
   rotate q and k (rotate-half, ``sdar_moe.rope``); full layers have no
-  positional embedding at all (NoPE).
+  positional embedding at all (NoPE).  The per-head norm and the rotation are
+  one op (``ops/qk_norm_rope.py``), as in SDAR.
 - the attention's output is gated, head by head and number by number, by
   ``sigmoid(a W_out_gate)`` from a projection of its own, before ``W_o``.
 - sandwich norms: an RMSNorm before and after attention and before and after
@@ -46,8 +47,9 @@ import jax
 import jax.numpy as jnp
 
 from sheeprl_tpu.models.mla_moe import SwiGLU, _stack, head_logits, head_terms
-from sheeprl_tpu.models.sdar_moe import RoutedExperts, RoutedSpec, remat_block, rms_norm, rope
+from sheeprl_tpu.models.sdar_moe import RoutedExperts, RoutedSpec, remat_block, rms_norm
 from sheeprl_tpu.ops.block_sparse_attention import SegmentMask, block_sparse_flash_attention
+from sheeprl_tpu.ops.qk_norm_rope import qk_norm_rope
 
 Dtype = Any
 _INIT = nn.initializers.normal(0.02)
@@ -84,7 +86,7 @@ class AfmoeConfig:
     # tiles of the attention (the TPU's block-sparse flash kernel): None, by the call's shapes and mask
     # (``ops.block_sparse_attention._tiles``); an integer makes every tile of every kernel that wide
     attention_block: Optional[int] = None
-    attention_interpret: bool = False  # run that kernel through Pallas' interpreter: off a TPU, for the tests
+    attention_interpret: bool = False  # run that kernel and the q/k norm's through Pallas' interpreter: off a TPU, for the tests
 
     @classmethod
     def from_mapping(cls, cfg: Mapping[str, Any]) -> "AfmoeConfig":
@@ -139,12 +141,13 @@ class GatedAttention(nn.Module):
         c, dt = self.cfg, self.dtype
         a = a.astype(dt)
         lead = a.shape[:-1]
-        q = rms_norm((a @ self.wq.astype(dt)).reshape(*lead, c.num_attention_heads, c.head_dim), self.q_norm, c.rms_norm_eps)
-        k = rms_norm((a @ self.wk.astype(dt)).reshape(*lead, c.num_key_value_heads, c.head_dim), self.k_norm, c.rms_norm_eps)
+        rotary = pos if self.window else None  # a full layer has no positional embedding
+        q, k = ((a @ w.astype(dt)).reshape(*lead, heads, c.head_dim)
+                for w, heads in ((self.wq, c.num_attention_heads), (self.wk, c.num_key_value_heads)))
+        q, k = (qk_norm_rope(x, g, c.rms_norm_eps, rotary, c.rope_theta, interpret=c.attention_interpret)
+                for x, g in ((q, self.q_norm), (k, self.k_norm)))
         v = (a @ self.wv.astype(dt)).reshape(*lead, c.num_key_value_heads, c.head_dim)
-        if self.window:
-            q, k = rope(q, pos, c.rope_theta), rope(k, pos, c.rope_theta)
-        return q.astype(dt), k.astype(dt), v
+        return q, k, v
 
     def out(self, o: jax.Array, a: jax.Array) -> jax.Array:
         """The heads' outputs (..., H, head) gated by ``sigmoid(a W_out_gate)``, then ``W_o``."""

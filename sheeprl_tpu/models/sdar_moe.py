@@ -30,6 +30,9 @@ What is particular here:
   packed episode holds the clean sequence and every denoising step's noised
   copy of its block (``EpisodeLayout``), so that one forward pass scores a whole
   denoising trajectory.
+- the per-head RMSNorm and rotary of q and k are one op with a backward of its
+  own (``ops/qk_norm_rope.py``): it reads the q and k products in float32 and
+  writes ``dtype``; ``rms_norm`` and ``rope`` below are its plain form.
 - ``SdarMoE.block`` is the cached pass collection makes: a block of
   ``block_length`` tokens against the clean keys and values of the finished
   blocks (a denoising pass, or the pass that writes a finished block).
@@ -52,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from sheeprl_tpu.ops.block_sparse_attention import KERNEL_RESIDUALS, SegmentMask, block_sparse_flash_attention
+from sheeprl_tpu.ops.qk_norm_rope import qk_norm_rope
 
 Dtype = Any
 _INIT = nn.initializers.normal(0.02)
@@ -82,7 +86,7 @@ class SdarConfig:
     # tiles of the masked attention (the TPU's block-sparse flash kernel): None, by the call's shapes and mask
     # (``ops.block_sparse_attention._tiles``); an integer makes every tile of every kernel that wide
     attention_block: Optional[int] = None
-    attention_interpret: bool = False  # run that kernel through Pallas' interpreter: off a TPU, for the tests
+    attention_interpret: bool = False  # run that kernel and the q/k norm's through Pallas' interpreter: off a TPU, for the tests
 
     @classmethod
     def from_mapping(cls, cfg: Mapping[str, Any]) -> "SdarConfig":
@@ -511,11 +515,13 @@ class GroupedQueryAttention(nn.Module):
         c = self.cfg
         a = a.astype(self.dtype)
         lead = a.shape[:-1]
-        q = (a @ self.wq.astype(self.dtype)).reshape(*lead, c.num_attention_heads, c.head_dim)
-        k = (a @ self.wk.astype(self.dtype)).reshape(*lead, c.num_key_value_heads, c.head_dim)
+        # q and k leave the product in float32, the norm's input precision: rounded to bf16 before the norm they
+        # tipped routing enough to take a sound run's gradient comparison to its limit (PERF.md)
+        q, k = (jnp.matmul(a, w.astype(self.dtype), preferred_element_type=jnp.float32).reshape(*lead, heads, c.head_dim)
+                for w, heads in ((self.wq, c.num_attention_heads), (self.wk, c.num_key_value_heads)))
         v = (a @ self.wv.astype(self.dtype)).reshape(*lead, c.num_key_value_heads, c.head_dim)
-        q = rope(rms_norm(q, self.q_norm, c.rms_norm_eps), pos, c.rope_theta).astype(self.dtype)
-        k = rope(rms_norm(k, self.k_norm, c.rms_norm_eps), pos, c.rope_theta).astype(self.dtype)
+        q, k = (qk_norm_rope(x, g, c.rms_norm_eps, pos, c.rope_theta, dtype=self.dtype, interpret=c.attention_interpret)
+                for x, g in ((q, self.q_norm), (k, self.k_norm)))
         return q, k, v
 
     def out(self, o: jax.Array) -> jax.Array:

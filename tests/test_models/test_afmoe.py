@@ -244,12 +244,16 @@ def lm_overrides(tmp_path, precision="32-true", envs=2):
 def test_cli_runs_a_ppo_iteration(tmp_path):
     """``sheeprl.py exp=ppo_trinity_mini`` at tiny widths: one iteration through ``ppo.main``, the fused
     causal collector and ``make_episode_update_fn``; the telemetry carries the routed layers' counters and
-    how both kinds of kernel engaged (the prefill's and the update's, banded and causal)."""
+    how both kinds of kernel engaged (the prefill's and the update's, banded and causal) and which form the q/k
+    norms took (heads of 16 numbers fill no lane tile: the composed form everywhere)."""
     from sheeprl_tpu.cli import run
     from sheeprl_tpu.ops import block_sparse_attention as op
+    from sheeprl_tpu.ops import qk_norm_rope
 
     op._splash_kernel.cache_clear()
     op.take_engaged()
+    qk_norm_rope.take_engaged()
+    qk_norm_rope._SEEN.clear()
     run(lm_overrides(tmp_path))
     records = [json.loads(line) for path in glob.glob(f"{tmp_path}/trinity/*/telemetry.jsonl") for line in open(path)]
     moe = [r["moe"] for r in records if "moe" in r]
@@ -259,6 +263,11 @@ def test_cli_runs_a_ppo_iteration(tmp_path):
     got = sorted((k["s_q"], k["window"], k["pairs"]) for k in attention)
     n = P + RESP
     assert got == [(P, 0, P * (P + 1) // 2), (P, WINDOW, 33), (n, 0, n * (n + 1) // 2), (n, WINDOW, 129)], got
+    (qk,) = [r["qk_norm"] for r in records if "qk_norm" in r]
+    # the prefill's, the cached passes' and the update's q (4 heads) and k (2), rotated in the sliding layers only
+    assert {(k["batch"], k["tokens"], k["heads"], k["rotate"]) for k in qk} == {
+        (b, t, h, r) for b, t in ((2, P), (2, 1), (1, n)) for h in (4, 2) for r in (True, False)}, qk
+    assert {k["form"] for k in qk} == {"composed"}
     assert records[-1]["jaxenv"]["env_steps"] == 2 * RESP
 
 

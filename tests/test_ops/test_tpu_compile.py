@@ -309,3 +309,29 @@ def test_rematerialised_block_calls_the_forward_kernel_once_on_v5e(chip, model):
     for kernel, calls in (("splash_mqa_fwd", 1), ("splash_mqa_dkv", 1), ("splash_mqa_dq", 0 if model == "mla_moe" else 1)):
         assert kernel_calls(text, kernel) == calls, kernel
     assert compiled.memory_analysis().temp_size_in_bytes <= REMAT_BLOCK_TEMP_BYTES[model] * 1.02
+
+
+@pytest.mark.parametrize("heads", [32, 4], ids=["q", "k"])
+@pytest.mark.parametrize("model", ["sdar_update", "trinity_update", "trinity_update_nope"])
+def test_qk_norm_rope_compiles_for_v5e(chip, model, heads):
+    """The fused q/k norm and rotary, forward and backward, at the update's shapes: SDAR's 3 packed episodes of
+    5,632 positions (the product's output in float32, as the model hands it over) and Trinity's one of 8,192 (bf16;
+    a full layer without rotation).  One kernel each way, each writing the projection's size in bf16; Trinity's
+    read nothing of it in float32."""
+    from benchmarks.lm_update_aot import kernel_calls
+    from sheeprl_tpu.ops.qk_norm_rope import qk_norm_rope
+
+    rows, length, dtype = (3, 5632, jnp.float32) if model == "sdar_update" else (1, 8192, jnp.bfloat16)
+    pos = None if model.endswith("nope") else jnp.arange(length)
+
+    def loss(x, g):
+        return qk_norm_rope(x, g, 1e-6, pos, 1e6, dtype=jnp.bfloat16).astype(jnp.float32).sum()
+
+    x = jax.ShapeDtypeStruct((rows, length, heads, 128), dtype, sharding=chip)
+    g = jax.ShapeDtypeStruct((128,), jnp.float32, sharding=chip)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, g).compile().as_text()
+    assert kernel_calls(text, "qk_norm_rope_fwd") == 1 and kernel_calls(text, "qk_norm_rope_bwd") == 1
+    calls = [line for line in text.splitlines() if "qk_norm_rope_" in line and "custom-call(" in line]
+    sized = f"[{rows},{length},{heads * 128}]"
+    assert all(line.split(" custom-call(")[0].split(" = ")[1].lstrip("(").startswith(f"bf16{sized}") for line in calls)
+    assert dtype == jnp.float32 or not any(f"f32{sized}" in line for line in calls)
