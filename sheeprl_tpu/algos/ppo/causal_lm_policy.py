@@ -1,10 +1,12 @@
 """The causal language-model policy kinds of PPO: a sparse-expert model that
 acts by appending one token per environment step (``envs/jax/tokens.py`` at
-block length 1).  Two kinds share this policy and differ in their model
+block length 1).  Three kinds share this policy and differ in their model
 (``lm_policy.py`` names each kind's model class and the ``algo`` section of
 its keys): ``algo.policy=mla_moe``, latent attention (``models/mla_moe.py``,
-keys under ``algo.mla``), and ``algo.policy=afmoe``, sliding-window and global
-attention mixed (``models/afmoe.py``, keys under ``algo.afmoe``).
+keys under ``algo.mla``), ``algo.policy=afmoe``, sliding-window and global
+attention mixed (``models/afmoe.py``, keys under ``algo.afmoe``), and
+``algo.policy=qwen3_next``, linear attention with a recurrent state and full
+attention mixed (``models/qwen3_next.py``, keys under ``algo.qwen3_next``).
 
 ``evaluate_episodes`` scores a whole episode in one causal pass over its
 ``P + R`` tokens: response token ``i`` was drawn from the distribution at
@@ -26,7 +28,7 @@ import jax.numpy as jnp
 
 class CausalLmPolicy:
     """The model (a flax module with ``hidden`` / ``step`` / ``logits`` / ``evaluate``: ``MlaMoE``,
-    ``Afmoe``), the episode's lengths and the functions PPO calls on them."""
+    ``Afmoe``, ``Qwen3Next``), the episode's lengths and the functions PPO calls on them."""
 
     is_continuous = False
 

@@ -3,7 +3,7 @@ the kinds.  A kind is a builder of the policy (what ``build_agent`` returns in
 place of the MLP/CNN module) and the fused collector that acts with it, and for
 the causal kinds the model class and the ``algo`` section of its keys; the
 update (``ppo.make_episode_update_fn``, minibatches of whole episodes) and the
-loop (``ppo.main``) are the same for all of them.  Three kinds:
+loop (``ppo.main``) are the same for all of them.  Four kinds:
 
 - ``sdar_moe``: block diffusion (``models/sdar_moe.py``); its cache holds every
   layer's grouped keys and values of the finished blocks;
@@ -11,7 +11,10 @@ loop (``ppo.main``) are the same for all of them.  Three kinds:
   every block's latent and shared rotary key, never per-head keys or values;
 - ``afmoe``: causal, sliding-window (``sliding_window`` positions) and global
   attention mixed (``models/afmoe.py``); its cache holds every layer's grouped
-  keys and values, and a sliding layer reads only the window's slice of it.
+  keys and values, and a sliding layer reads only the window's slice of it;
+- ``qwen3_next``: causal, Gated DeltaNet linear attention and gated full
+  attention mixed 3:1 (``models/qwen3_next.py``); its cache holds a linear
+  layer's fixed-size recurrent state beside a full layer's keys and values.
 
 ``howto/language_model_policy.md`` has the kinds side by side."""
 
@@ -58,6 +61,10 @@ KINDS = {
         # layer's keys and values (a sliding layer reads the window's slice)
         LmPolicyKind("afmoe", "sheeprl_tpu.algos.ppo.causal_lm_policy:build_causal_lm_agent", "FusedCausalCollector",
                      "sheeprl_tpu.models.afmoe:Afmoe", "afmoe"),
+        # causal, linear attention with a recurrent state and full attention mixed: an env step appends one token,
+        # decoded against each linear layer's state and each full layer's keys and values
+        LmPolicyKind("qwen3_next", "sheeprl_tpu.algos.ppo.causal_lm_policy:build_causal_lm_agent", "FusedCausalCollector",
+                     "sheeprl_tpu.models.qwen3_next:Qwen3Next", "qwen3_next"),
     )
 }
 

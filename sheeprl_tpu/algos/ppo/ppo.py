@@ -40,7 +40,7 @@ from sheeprl_tpu.config import instantiate
 from sheeprl_tpu.config.compose import _locate
 from sheeprl_tpu.data.buffers import ReplayBuffer
 from sheeprl_tpu.obs import setup_observability, trace_scope
-from sheeprl_tpu.ops import block_sparse_attention, qk_norm_rope
+from sheeprl_tpu.ops import block_sparse_attention, gated_delta_rule, qk_norm_rope
 from sheeprl_tpu.parallel.pipeline import OnPolicyCollector, PipelinedCollector, detach_copy, resolve_overlap_setting
 from sheeprl_tpu.resilience import CheckpointManager
 from sheeprl_tpu.resilience.sentinel import guard_update, restore_like
@@ -121,7 +121,7 @@ def make_update_fn(
     itself and minibatches are rank-striped, so no rollout data ever
     crosses devices — exactly DDP semantics.
 
-    A language-model policy (``algo.policy=sdar_moe``, ``mla_moe`` or ``afmoe``:
+    A language-model policy (``algo.policy=sdar_moe``, ``mla_moe``, ``afmoe`` or ``qwen3_next``:
     ``lm_policy.py``) takes the episode update instead:
     ``make_episode_update_fn``."""
     if language_model_policy(cfg) is not None:
@@ -798,8 +798,9 @@ def main(runtime, cfg: Dict[str, Any]):
                 logger.log_metrics({"Info/clip_coef": current_clip, "Info/ent_coef": current_ent}, policy_step)
                 if policy_step - last_log >= cfg.metric.log_every or iter_num == total_iters:
                     sections: Dict[str, Any] = dict(policy_counters)
-                    # how the blocked attention's kernels and the q/k norms were built, once a shape
-                    for section, op in (("attention", block_sparse_attention), ("qk_norm", qk_norm_rope)):
+                    # how the blocked attention's kernels, the q/k norms and the delta rule were built, once a shape
+                    for section, op in (("attention", block_sparse_attention), ("qk_norm", qk_norm_rope),
+                                        ("gdn", gated_delta_rule)):
                         engaged = op.take_engaged()
                         if engaged:
                             sections[section] = engaged

@@ -440,9 +440,10 @@ class _FusedEpisodeCollector(_FusedCollectorBase):
 
 class FusedCausalCollector(_FusedEpisodeCollector):
     """Fused collection for the causal language-model policies
-    (``algos/ppo/causal_lm_policy.py``: the kinds ``mla_moe`` and ``afmoe``): one
-    env step appends one token.  It calls the policy's own model class
-    (``hidden`` with the prefill's cache entries, ``step``, ``logits``).
+    (``algos/ppo/causal_lm_policy.py``: the kinds ``mla_moe``, ``afmoe`` and
+    ``qwen3_next``): one env step appends one token.  It calls the policy's own
+    model class (``hidden`` with the prefill's cache entries, ``prefill_cache``,
+    ``step``, ``logits``).
 
     One pass over the prompt under the model's masks (the full-sequence form of
     the attention) fills the cache; then the scan runs over response tokens,
@@ -452,7 +453,12 @@ class FusedCausalCollector(_FusedEpisodeCollector):
     position so far (576 numbers a position and block at the published sizes,
     never per-head keys or values); for ``Afmoe`` per layer the grouped keys and
     values (4 heads x 128 each, a position), of which a sliding layer's pass
-    reads the fixed-width slice of the last ``sliding_window`` positions.  A step
+    reads the fixed-width slice of the last ``sliding_window`` positions; for
+    ``Qwen3Next`` two kinds side by side: a linear-attention layer's recurrent
+    state and its convolution's last inputs, of fixed size, and a full layer's
+    grouped keys and values.  The model class lays the cache out from the
+    prefill's entries (``prefill_cache``: the entries a position each are padded
+    to the episode's length, fixed-size ones pass as they are).  A step
     samples its token at temperature 1 from the full vocabulary slice, so that
     the recorded log-probability is exactly the model's, steps the env, and
     makes ONE cached pass over the token it has just appended.  Latent
@@ -466,15 +472,12 @@ class FusedCausalCollector(_FusedEpisodeCollector):
         policy, env = self.module, self.jax_env
         model = policy.model
         model_cls = type(model)
-        n_env, p_len, r_len = self.total_envs, env.prompt_len, env.response_len
+        p_len, r_len = env.prompt_len, env.response_len
 
         with jax.named_scope("collect_prefill"):
             prompt = vstate["obs"]["tokens"][:, :p_len]
-            u, _, latents = model.apply(params, prompt, True, method=model_cls.hidden)
-            cache = [
-                tuple(jnp.zeros((n_env, p_len + r_len) + x.shape[2:], x.dtype).at[:, :p_len].set(x) for x in lat)
-                for lat in latents
-            ]
+            u, _, entries = model.apply(params, prompt, True, method=model_cls.hidden)
+            cache = model_cls.prefill_cache(entries, p_len + r_len)
 
         def step_fn(carry, xs):
             vstate, cache, last, reach = carry

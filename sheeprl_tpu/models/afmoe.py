@@ -46,7 +46,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from sheeprl_tpu.models.mla_moe import SwiGLU, _stack, head_logits, head_terms
+from sheeprl_tpu.models.mla_moe import SwiGLU, _stack, head_logits, head_terms, padded_cache
 from sheeprl_tpu.models.sdar_moe import RoutedExperts, RoutedSpec, remat_block, rms_norm
 from sheeprl_tpu.ops.block_sparse_attention import SegmentMask, block_sparse_flash_attention
 from sheeprl_tpu.ops.qk_norm_rope import qk_norm_rope
@@ -250,6 +250,7 @@ class Afmoe(nn.Module):
     dtype: Dtype = jnp.float32
     remat: bool = True
     config_class = AfmoeConfig  # what ``from_mapping`` builds the ``cfg`` from (``algos/ppo/lm_policy.py``)
+    prefill_cache = staticmethod(padded_cache)  # the collector's cache from the prefill's keys and values
 
     def setup(self) -> None:
         c = self.cfg

@@ -296,6 +296,13 @@ def head_terms(at: jax.Array, head: jax.Array, taken: jax.Array, dtype: Dtype, r
     return tuple(x.reshape(taken.shape) for x in out)
 
 
+def padded_cache(entries: List[Tuple[jax.Array, ...]], total_len: int) -> List[Tuple[jax.Array, ...]]:
+    """The cache a collector carries, from the per-position entries a prefill hands over (per layer a
+    tuple of (B, P, ...) arrays): each padded with zeros to ``total_len`` positions."""
+    return [tuple(jnp.zeros((x.shape[0], total_len) + x.shape[2:], x.dtype).at[:, :x.shape[1]].set(x) for x in entry)
+            for entry in entries]
+
+
 def head_logits(at: jax.Array, head: jax.Array, value: jax.Array, dtype: Dtype) -> Tuple[jax.Array, jax.Array]:
     """Hidden states (..., hidden) -> log-probabilities over the vocabulary slice (float32) and the value."""
     with jax.named_scope("lm_head"):
@@ -312,6 +319,7 @@ class MlaMoE(nn.Module):
     dtype: Dtype = jnp.float32
     remat: bool = True
     config_class = MlaMoeConfig  # what ``from_mapping`` builds the ``cfg`` from (``algos/ppo/lm_policy.py``)
+    prefill_cache = staticmethod(padded_cache)  # the collector's cache from the prefill's latents
 
     def setup(self) -> None:
         c = self.cfg

@@ -512,10 +512,10 @@ def test_policy_kinds_are_chosen_in_one_place():
     from sheeprl_tpu.algos.ppo.lm_policy import KINDS, language_model_policy
     from sheeprl_tpu.config import compose
 
-    assert set(KINDS) == {"sdar_moe", "mla_moe", "afmoe"}
+    assert set(KINDS) == {"sdar_moe", "mla_moe", "afmoe", "qwen3_next"}
     assert language_model_policy(compose(overrides=["exp=ppo"])) is None
     assert language_model_policy(compose(overrides=["exp=ppo_sdar_moe"])).collector == "FusedDiffusionCollector"
-    for exp, model in (("ppo_joyai_flash", M.MlaMoE), ("ppo_trinity_mini", None)):
+    for exp, model in (("ppo_joyai_flash", M.MlaMoE), ("ppo_trinity_mini", None), ("ppo_qwen3_next", None)):
         kind = language_model_policy(compose(overrides=[f"exp={exp}"]))
         assert kind.collector == "FusedCausalCollector" and kind.model_class.config_class.__name__.endswith("Config")
         assert model is None or kind.model_class is model

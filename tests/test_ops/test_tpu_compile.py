@@ -335,3 +335,44 @@ def test_qk_norm_rope_compiles_for_v5e(chip, model, heads):
     sized = f"[{rows},{length},{heads * 128}]"
     assert all(line.split(" custom-call(")[0].split(" = ")[1].lstrip("(").startswith(f"bf16{sized}") for line in calls)
     assert dtype == jnp.float32 or not any(f"f32{sized}" in line for line in calls)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "grad"])
+def test_gated_delta_rule_compiles_for_v5e(chip, direction):
+    """The gated delta rule at the Qwen3-Next cell's shapes (one episode of 8,192 positions, 16 key heads
+    serving 32 value heads of 128): the chunked form and its gradient through the scan over 128 chunks
+    compile, and their temporaries stay far below the 8.6 GB a materialised (N, N) decay a head would
+    take (``ops/gated_delta_rule.py``: plain JAX, no Pallas kernel)."""
+    from sheeprl_tpu.ops.gated_delta_rule import gated_delta_rule
+
+    n, hk, hv, d = 8192, 16, 32, 128
+
+    def fwd(q, k, v, g, beta):
+        o, state = gated_delta_rule(q, k, v, g, beta)
+        return o.sum() + state.sum()
+
+    shapes = ((1, n, hk, d), (1, n, hk, d), (1, n, hv, d), (1, n, hv), (1, n, hv))
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=chip) for s in shapes]
+    compiled = jax.jit(jax.grad(fwd, argnums=(0, 1, 2, 3, 4)) if direction == "grad" else fwd).lower(*args).compile()
+    assert "while" in compiled.as_text()  # the chunks' state passes through one scan
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
+@pytest.mark.parametrize("direction", ["fwd", "grad"])
+def test_wide_head_causal_attention_compiles_for_v5e(chip, direction):
+    """Qwen3-Next's full layer: 16 query heads sharing 2 key-value heads of 256 under the causal mask at
+    8,192 positions, at the tiles ``_tiles`` keeps for values over 128 lanes (one tile of 512 for every
+    kernel), forward and backward within the compiler's scoped VMEM."""
+    from sheeprl_tpu.ops import block_sparse_attention as op
+
+    n = 8192
+    mask = op.SegmentMask.causal(n, n)
+    sizes, computed = op._tiles(n, n, 256, 256, 8, mask)
+    assert (sizes.block_q, sizes.block_kv, sizes.block_q_dkv, sizes.block_q_dq, computed) == (512, 512, 512, 512, True)
+
+    def fwd(q, k, v):
+        return op.block_sparse_flash_attention(q, k, v, mask).astype(jnp.float32).sum()
+
+    q, k, v = (jax.ShapeDtypeStruct((1, n, heads, 256), jnp.bfloat16, sharding=chip) for heads in (16, 2, 2))
+    text = jax.jit(jax.grad(fwd, argnums=(0, 1, 2)) if direction == "grad" else fwd).lower(q, k, v).compile().as_text()
+    assert "tpu_custom_call" in text
